@@ -100,6 +100,7 @@ def _build(kind, params, cfg, batch, mesh, rec):
 
 
 def run(requests: int = 8, max_new: int = 8) -> None:
+    from repro.launch.mesh import make_mesh
     from repro.models import model as MD
     from repro.serving import Recorder
 
@@ -109,7 +110,7 @@ def run(requests: int = 8, max_new: int = 8) -> None:
 
     n_dev = len(jax.devices())
     meshes = [None] + [
-        jax.make_mesh((d, m), ("data", "model"))
+        make_mesh((d, m), ("data", "model"))
         for d, m in MESH_SHAPES
         if d * m <= n_dev
     ]

@@ -15,15 +15,9 @@ import re
 from typing import Dict
 
 def cost_analysis_dict(compiled) -> Dict:
-    """Normalise ``Compiled.cost_analysis()`` across jax versions.
-
-    jax ≤ 0.4.x returns a one-element list of dicts (one per program);
-    newer jax returns the dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
+    """``Compiled.cost_analysis()`` as a plain dict (empty when XLA gives
+    no analysis)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 _DTYPE_BYTES = {

@@ -130,11 +130,13 @@ def _optimal_split(rows: np.ndarray, dim: int) -> Tuple[float, float]:
     which ignores how well the cut separates the other dims; on cascaded
     LUT-MUs that gap compounds per layer.)  O(n·(log n + d_sub)).
 
-    Returns ``(loss, threshold)``.
+    Returns ``(loss, threshold)``.  A bucket no threshold can cut (one
+    row, or one value on ``dim``) gets ``-inf``: every row goes right with
+    no value on the boundary.
     """
     m = rows.shape[0]
     if m <= 1:
-        return 0.0, float(rows[0, dim]) if m else 0.0
+        return 0.0, -np.inf
     v = rows[np.argsort(rows[:, dim], kind="stable")]
     csum = np.cumsum(v, axis=0)
     csq = np.cumsum(v * v, axis=0)
@@ -147,6 +149,14 @@ def _optimal_split(rows: np.ndarray, dim: int) -> Tuple[float, float]:
     right_cnt = m - cnt
     sse = ((left_sq - left_sum**2 / cnt)
            + (right_sq - right_sum**2 / right_cnt)).sum(axis=1)
+    # a threshold can only cut between distinct values: a cut inside a run
+    # of ties scores a partition no threshold produces, and its midpoint
+    # lands exactly on calibration values, where any perturbation of the
+    # input (e.g. a quantised upstream layer) flips the decision
+    gap = v[1:, dim] > v[:-1, dim]
+    if not gap.any():  # one value: no cut, the bucket stays whole
+        return float((total_sq - total_sum**2 / m).sum()), -np.inf
+    sse = np.where(gap, sse, np.inf)
     best = int(np.argmin(sse))
     # threshold midway between the two straddling sorted values
     thr = 0.5 * (v[best, dim] + v[best + 1, dim])
@@ -183,7 +193,7 @@ def _learn_hash_tree_one_codebook(
             for b in range(n_buckets):
                 rows = rows_by_bucket[b]
                 if rows.size == 0:
-                    thr_per_bucket[b] = 0.0
+                    thr_per_bucket[b] = -np.inf
                     continue
                 l, t = _optimal_split(rows, dim)
                 loss += l

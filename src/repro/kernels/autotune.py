@@ -3,13 +3,17 @@
 The fused kernel's grid is ``(B/B_t, N/N_t, C/C_t)`` and its per-step VMEM
 footprint (see ``docs/kernels.md`` for the full table) is
 
-    x    tile  B_t · C_t · I · 4        bytes (f32 split values)
-    thr  tile  C_t · (G-1) · 4          bytes
-    lut  tile  C_t · G · N_t · itemsize bytes
-    out  tile  B_t · N_t · 4            bytes (f32/i32 accumulator)
+    x    tile  I · B_t · C_t · 4                 bytes (f32 split values)
+    thr  tile  (G-1) · C_t · 4                   bytes
+    lut  tile  C_t · G' · N_t · itemsize         bytes (G' = G rounded up to
+                                                 the dtype's sublane tile)
+    out  tile  B_t · N_t · 4                     bytes (f32/i32 accumulator)
 
-Every candidate tiling must fit inside ``VMEM_FRACTION`` of the ~16 MiB/core
-budget so the pipeline can double-buffer.  Two selection modes:
+each double-buffered by the Pallas pipeline, plus the leaf masks and the
+one-hot the kernel builds.  Every candidate tiling must fit inside
+``VMEM_FRACTION`` of the 16 MiB scoped-VMEM default.  ``C_t`` sits on
+lanes, so it is a multiple of 128 that divides ``C``, or ``C`` itself.
+Two selection modes:
 
   * **heuristic** (default, free): the candidate that minimises grid steps —
     i.e. the largest tiles that fit — with ties broken toward fewer N-tiles
@@ -37,12 +41,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-VMEM_BUDGET_BYTES = 16 * 1024 * 1024  # per-core VMEM (TPU v4/v5 class)
-VMEM_FRACTION = 0.5  # headroom for double buffering
+VMEM_BUDGET_BYTES = 16 * 1024 * 1024  # Mosaic's default scoped VMEM limit
+VMEM_FRACTION = 0.75  # headroom for Mosaic's own temporaries
 
 _BLOCK_B_CHOICES = (64, 128, 256, 512)
 _BLOCK_N_CHOICES = (128, 256, 512)
-_BLOCK_C_CHOICES = (4, 8, 16)
+_BLOCK_C_CHOICES = (128, 256, 512)
+
+
+def default_interpret() -> bool:
+    """Pallas interpret mode: on for every platform except real TPUs."""
+    return jax.default_backend() != "tpu"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,7 +60,7 @@ class TileConfig:
 
     block_b: int = 256
     block_n: int = 256
-    block_c: int = 8
+    block_c: int = 128
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -72,20 +81,22 @@ def _ceil_div(x: int, m: int) -> int:
 def fused_vmem_bytes(tiles: TileConfig, depth: int, lut_itemsize: int) -> int:
     """Per-grid-step VMEM footprint of the fused kernel (docstring formula).
 
-    Besides the x/thr/lut/out blocks the kernel materialises intermediates
-    in VMEM: the ``(B_t, C_t·G)`` one-hot it contracts (int8 on the int8
-    path, else the LUT dtype) and the level-by-level bool leaf-mask pyramid
-    (Σ_l B_t·C_t·2^l ≈ 2·B_t·C_t·G bools).  Negligible at the default
-    I = 4, dominant for deep trees — so they are counted here.
+    The x/thr/lut/out blocks count twice (the pipeline double-buffers
+    them).  The LUT block's ``G`` axis is the second-minor one, so it pads
+    to the dtype's sublane tile (32 rows for int8, 16 for bf16, 8 for
+    f32).  Besides the blocks the kernel materialises the leaf-mask
+    pyramid (Σ_l B_t·C_t·2^l ≈ 2·B_t·C_t·G mask words) and one
+    ``(B_t, C_t)`` one-hot per leaf — negligible at the default I = 4,
+    dominant for deep trees, so they are counted here.
     """
     g = 2**depth
+    g_pad = _ceil_to(g, 32 // lut_itemsize)
     x = tiles.block_b * tiles.block_c * depth * 4
-    thr = tiles.block_c * (g - 1) * 4
-    lut = tiles.block_c * g * tiles.block_n * lut_itemsize
+    thr = _ceil_to(g - 1, 8) * tiles.block_c * 4
+    lut = tiles.block_c * g_pad * tiles.block_n * lut_itemsize
     out = tiles.block_b * tiles.block_n * 4
-    onehot_itemsize = 1 if lut_itemsize == 1 else lut_itemsize
-    interm = tiles.block_b * tiles.block_c * g * (onehot_itemsize + 2)
-    return x + thr + lut + out + interm
+    interm = tiles.block_b * tiles.block_c * g * (4 + lut_itemsize)
+    return 2 * (x + thr + lut + out) + interm
 
 
 def _effective(tiles: TileConfig, b: int, c: int, n: int) -> TileConfig:
@@ -95,6 +106,12 @@ def _effective(tiles: TileConfig, b: int, c: int, n: int) -> TileConfig:
         block_n=min(tiles.block_n, _ceil_to(n, 128)),
         block_c=min(tiles.block_c, c),
     )
+
+
+def _c_tiles(c: int) -> List[int]:
+    """Lane-legal C tiles: multiples of 128 that divide ``C`` (no padded
+    copy of the LUT per call), and ``C`` itself."""
+    return sorted({bc for bc in _BLOCK_C_CHOICES if c % bc == 0} | {c})
 
 
 def candidate_tiles(
@@ -110,14 +127,14 @@ def candidate_tiles(
     seen: Dict[TileConfig, TileConfig] = {}
     for bb in _BLOCK_B_CHOICES:
         for bn in _BLOCK_N_CHOICES:
-            for bc in _BLOCK_C_CHOICES:
+            for bc in _c_tiles(c):
                 t = _effective(TileConfig(bb, bn, bc), b, c, n)
                 if fused_vmem_bytes(t, depth, lut_itemsize) <= budget:
                     seen.setdefault(t, t)
     out = list(seen)
     out.sort(key=lambda t: _grid_score(t, b, c, n, depth, lut_itemsize))
     if not out:  # degenerate budget: fall back to the smallest tiling
-        out = [_effective(TileConfig(64, 128, 4), b, c, n)]
+        out = [_effective(TileConfig(64, 128, _c_tiles(c)[0]), b, c, n)]
     return out
 
 
@@ -273,7 +290,7 @@ def measure_fused_tiles(
     depth: int,
     lut_dtype=jnp.float32,
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     candidates: Optional[Sequence[TileConfig]] = None,
     iters: int = 3,
 ) -> Tuple[TileConfig, Dict[TileConfig, float]]:
@@ -285,6 +302,8 @@ def measure_fused_tiles(
     """
     from repro.kernels.fused_lutmu import fused_lutmu_pallas
 
+    if interpret is None:
+        interpret = default_interpret()
     lut_itemsize = jnp.dtype(lut_dtype).itemsize
     if candidates is None:
         candidates = candidate_tiles(b, c, n, depth, lut_itemsize)
@@ -324,7 +343,7 @@ def get_tiles(
     platform: Optional[str] = None,
     backend: str = "fused",
     allow_measure: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     cache: Optional[AutotuneCache] = None,
 ) -> TileConfig:
     """Resolve the tiling for one shape: cache hit → measured → heuristic.
@@ -397,9 +416,10 @@ def verify_vmem_bytes(tiles: VerifyTileConfig, s: int, w: int, nkv: int,
     ceiling for long contexts, and shapes over budget fall back to the
     portable XLA lowering.
     """
-    staging = 2 * tiles.block_s * nkv * hd * kv_itemsize
+    nkv_pad = _ceil_to(nkv, 32 // kv_itemsize)  # n_kv is the sublane axis
+    staging = 2 * tiles.block_s * nkv_pad * hd * kv_itemsize
     logits = w * nkv * g * s * 4
-    qio = 2 * w * nkv * g * hd * 4  # q block (f32) + out block (f32)
+    qio = 2 * 2 * w * nkv * g * hd * 4  # q + out blocks (f32), 2 buffers
     return staging + logits + qio
 
 
@@ -414,13 +434,15 @@ def verify_candidate_tiles(
     budget_bytes: Optional[int] = None,
 ) -> List[VerifyTileConfig]:
     """In-budget stagings, largest (fewest DMA round-trips) first.  Empty
-    when even ``block_s = page_size`` cannot fit — callers then use the
-    portable lowering."""
+    when no staging fits — callers then use the portable lowering.
+
+    The kernel joins the per-block logits along lanes, so a staging is a
+    multiple of 128 positions, or the whole view."""
     budget = int((budget_bytes or VMEM_BUDGET_BYTES) * VMEM_FRACTION)
     out = []
     blk = page_size
     while blk <= s:
-        if s % blk == 0:
+        if s % blk == 0 and (blk % 128 == 0 or blk == s):
             t = VerifyTileConfig(blk)
             if verify_vmem_bytes(t, s, w, nkv, g, hd, kv_itemsize) <= budget:
                 out.append(t)
@@ -454,13 +476,15 @@ def measure_verify_tiles(
     kv_dtype=jnp.float32,
     *,
     page_size: int = 16,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     candidates: Optional[Sequence[VerifyTileConfig]] = None,
     iters: int = 3,
 ) -> Tuple[VerifyTileConfig, Dict[VerifyTileConfig, float]]:
     """Time candidate stagings on synthetic pages of the real shape."""
     from repro.kernels.fused_verify import verify_window_attend_pallas
 
+    if interpret is None:
+        interpret = default_interpret()
     kv_itemsize = jnp.dtype(kv_dtype).itemsize
     if candidates is None:
         candidates = verify_candidate_tiles(
@@ -506,7 +530,7 @@ def get_verify_tiles(
     page_size: int = 16,
     platform: Optional[str] = None,
     allow_measure: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     cache: Optional[AutotuneCache] = None,
 ) -> Optional[VerifyTileConfig]:
     """Resolve the verify-window staging: cache hit → measured → heuristic.

@@ -36,6 +36,7 @@ from repro.core.maddness import (HashTree, MaddnessParams, contract_onehot,
 from repro.core.maddness import encode_onehot as _encode_onehot_xla
 from repro.core.pruning import PruningPlan, pruned_to_split_values
 from repro.kernels import autotune as AT
+from repro.kernels.autotune import default_interpret
 from repro.kernels.fused_lutmu import fused_lutmu_pallas
 from repro.kernels.lut_aggregate import lut_aggregate_pallas
 from repro.kernels.maddness_encode import encode_onehot_pallas
@@ -79,11 +80,6 @@ def params_from_arrays(split_dims: Array, thresholds: Array, lut: Array,
     tree = HashTree(split_dims, thresholds)
     protos = jnp.zeros(lut.shape[:2] + (0,), jnp.float32)
     return MaddnessParams(tree, protos, lut, lut_scale, lut_offset)
-
-
-def default_interpret() -> bool:
-    """Pallas interpret mode: on for every platform except real TPUs."""
-    return jax.default_backend() != "tpu"
 
 
 def select_backend(
@@ -268,7 +264,6 @@ def lutmu_matmul_sharded(
     codebook count does not divide by it (the sharding rules replicate such
     tables anyway).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if interpret is None:
@@ -319,14 +314,14 @@ def lutmu_matmul_sharded(
         acc = _run_backend(xs_l, p_l, backend, tiles, interpret)
         return jax.lax.psum(acc, axis)
 
-    # check_rep=False: shard_map's replication checker has no rule for
+    # check_vma=False: shard_map's replication checker has no rule for
     # pallas_call, so the fused/unfused backends would fail at trace time;
     # the psum + out_specs make replication over ``axis`` explicit anyway.
-    out = shard_map(
+    out = jax.shard_map(
         local_shard, mesh=mesh,
         in_specs=(P(batch_ax, axis, None), P(axis, None), P(axis, None),
                   P(axis, None, None)),
         out_specs=P(batch_ax),
-        check_rep=False,
+        check_vma=False,
     )(xs, params.tree.split_dims, params.tree.thresholds, params.lut)
     return out * params.lut_scale + params.lut_offset

@@ -43,10 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:  # pallas TPU helpers import cleanly on CPU jaxlibs, but guard anyway
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - ancient jaxlib
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
@@ -55,11 +52,6 @@ NEG_INF = -1e30
 KV_INT8_SCALE = 0.05
 
 VERIFY_IMPLS = ("xla", "pallas")
-
-
-def default_interpret() -> bool:
-    """Pallas interpret mode everywhere but real TPU (mirrors dispatch)."""
-    return jax.default_backend() != "tpu"
 
 
 def resolve_impl(impl: str = "auto") -> str:
@@ -165,94 +157,122 @@ def verify_window_attend(qg: Array, k_view: Array, v_view: Array,
 # ---------------------------------------------------------------------------
 
 
+def _window_offsets(rows: int, g: int, s_len: int) -> Array:
+    """``(rows, s_len)`` int32: window slot ``r // g`` of each q row.
+
+    A compare-sum instead of an integer division, which the TPU vector
+    unit lacks."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, s_len), 0)
+    off = jnp.zeros((rows, s_len), jnp.int32)
+    for j in range(1, rows // g):
+        off = off + jnp.where(r >= j * g, 1, 0)
+    return off
+
+
 def _verify_window_kernel(pos_ref, win_ref, pt_ref, q_ref, kp_ref, vp_ref,
                           out_ref, k_s, v_s, sem, *, page_size: int,
-                          block_s: int, int8_kv: bool):
-    """One grid step = one batch row.
+                          max_pages: int, block_s: int, g: int,
+                          int8_kv: bool):
+    """One grid step = one batch row, every KV head.
+
+    ``pos_ref``/``win_ref``/``pt_ref`` are scalar-prefetch (SMEM) refs: the
+    row's first window position, the layer's window flag and the flattened
+    ``(B·max_pages,)`` page table.  ``q_ref`` is ``(n_kv, W·g, hd)``.
 
     Stage 1 DMAs the row's K pages ``block_s`` positions at a time into
-    ``k_s`` and computes the window logits blockwise; after a flat masked
-    softmax over the full row (the oracle's reduction shape), stage 2
-    re-stages the V pages and accumulates the weighted sum blockwise —
+    ``k_s`` and computes each head's window logits blockwise; after a flat
+    masked softmax over the full row (the oracle's reduction shape), stage
+    2 re-stages the V pages and accumulates the weighted sum blockwise —
     int32 on the int8 path, so the block decomposition is exact.
     """
-    n_pages = pt_ref.shape[1]
-    s_len = n_pages * page_size
+    row = pl.program_id(0)
+    s_len = max_pages * page_size
     n_blocks = s_len // block_s
     pages_per_block = block_s // page_size
-    w = q_ref.shape[1]
-    hd = q_ref.shape[-1]
+    nkv, rows, hd = q_ref.shape
     scale = 1.0 / np.sqrt(hd)
-    int8 = int8_kv
+    acc_t = jnp.int32 if int8_kv else jnp.float32
 
     def stage(pages_ref, scratch, blk):
-        def cp(p, _):
-            phys = pt_ref[0, blk * pages_per_block + p]
-            c = pltpu.make_async_copy(
+        def copy(p):
+            phys = pt_ref[row * max_pages + blk * pages_per_block + p]
+            return pltpu.make_async_copy(
                 pages_ref.at[phys],
                 scratch.at[pl.ds(p * page_size, page_size)], sem)
-            c.start()
-            c.wait()
-            return 0
-        jax.lax.fori_loop(0, pages_per_block, cp, 0)
 
-    q = q_ref[0]  # (W, n_kv, g, hd) f32
-    if int8:
-        sq = jnp.max(jnp.abs(q), axis=-1, keepdims=True) / 127.0 + 1e-9
-        q_c = jnp.clip(jnp.round(q / sq), -127, 127).astype(jnp.int8)
-        sq_t = jnp.transpose(sq, (1, 0, 2, 3))  # (n_kv, W, g, 1)
-    else:
-        q_c = q
+        def start(p, c):
+            copy(p).start()
+            return c
+
+        def wait(p, c):
+            copy(p).wait()
+            return c
+
+        jax.lax.fori_loop(0, pages_per_block, start, 0)
+        jax.lax.fori_loop(0, pages_per_block, wait, 0)
+
+    qs, sqs = [], []
+    for h in range(nkv):
+        q = q_ref[h]  # (W·g, hd) f32
+        if int8_kv:
+            sq = jnp.max(jnp.abs(q), axis=-1, keepdims=True) / 127.0 + 1e-9
+            qs.append(jnp.clip(jnp.round(q / sq), -127, 127).astype(jnp.int8))
+            sqs.append(sq)
+        else:
+            qs.append(q.astype(k_s.dtype))
 
     # -- QK: blockwise over the staged view, logits kept whole ------------
-    parts = []
+    parts = [[] for _ in range(nkv)]
     for blk in range(n_blocks):
         stage(kp_ref, k_s, blk)
-        kb = k_s[...]
-        # contract hd; batch n_kv → (n_kv, W, g, block_s)
-        lg = jax.lax.dot_general(
-            q_c, kb, (((3,), (2,)), ((1,), (1,))),
-            preferred_element_type=jnp.int32 if int8 else jnp.float32)
-        if int8:
-            lg = lg.astype(jnp.float32)
-            lg = lg * (sq_t * KV_INT8_SCALE * scale)
-        else:
-            lg = lg * scale
-        parts.append(lg)
-    logits = jnp.concatenate(parts, axis=-1) if n_blocks > 1 else parts[0]
+        for h in range(nkv):
+            lg = jax.lax.dot_general(
+                qs[h], k_s[:, h, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=acc_t)  # (W·g, block_s)
+            if int8_kv:
+                lg = lg.astype(jnp.float32) * (sqs[h] * KV_INT8_SCALE * scale)
+            else:
+                lg = lg * scale
+            parts[h].append(lg)
 
     # -- flat masked softmax over the full row (oracle reduction shape) ---
-    pos = pos_ref[0, 0]
-    win = win_ref[0, 0]
-    kv_pos = jax.lax.broadcasted_iota(jnp.int32, (w, s_len), 1)
-    pj = pos + jax.lax.broadcasted_iota(jnp.int32, (w, s_len), 0)
-    valid = (kv_pos <= pj) & (kv_pos > pj - win)  # (W, S)
-    logits = jnp.where(valid[None, :, None, :], logits, NEG_INF)
-    wgt = jax.nn.softmax(logits, axis=-1)
-    if int8:
-        wgt = jnp.clip(jnp.round(wgt * 127.0), 0, 127).astype(jnp.int8)
+    pos = pos_ref[row]
+    win = win_ref[0]
+    kv_pos = jax.lax.broadcasted_iota(jnp.int32, (rows, s_len), 1)
+    pj = pos + _window_offsets(rows, g, s_len)
+    valid = (kv_pos <= pj) & (kv_pos > pj - win)  # (W·g, S)
+    wgts = []
+    for h in range(nkv):
+        logits = (jnp.concatenate(parts[h], axis=-1) if n_blocks > 1
+                  else parts[h][0])
+        wgt = jax.nn.softmax(jnp.where(valid, logits, NEG_INF), axis=-1)
+        if int8_kv:
+            wgt = jnp.clip(jnp.round(wgt * 127.0), 0, 127).astype(jnp.int8)
+        else:
+            wgt = wgt.astype(v_s.dtype)
+        wgts.append(wgt)
 
     # -- AV: blockwise, int32/f32 accumulate ------------------------------
-    acc = None
+    accs = [None] * nkv
     for blk in range(n_blocks):
         stage(vp_ref, v_s, blk)
-        vb = v_s[...]
-        wb = wgt[:, :, :, blk * block_s:(blk + 1) * block_s]
-        part = jax.lax.dot_general(
-            wb if int8 else wb.astype(vb.dtype), vb,
-            (((3,), (0,)), ((0,), (1,))),  # contract block; batch n_kv
-            preferred_element_type=jnp.int32 if int8 else jnp.float32)
-        acc = part if acc is None else acc + part
-    if int8:
-        acc = acc.astype(jnp.float32) * (KV_INT8_SCALE / 127.0)
-    out_ref[0] = jnp.transpose(acc, (1, 0, 2, 3))  # (W, n_kv, g, hd)
+        for h in range(nkv):
+            part = jax.lax.dot_general(
+                wgts[h][:, blk * block_s:(blk + 1) * block_s], v_s[:, h, :],
+                (((1,), (0,)), ((), ())), preferred_element_type=acc_t)
+            accs[h] = part if accs[h] is None else accs[h] + part
+    for h in range(nkv):
+        acc = accs[h]
+        if int8_kv:
+            acc = acc.astype(jnp.float32) * (KV_INT8_SCALE / 127.0)
+        out_ref[h] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def verify_window_attend_pallas(qg: Array, k_pages: Array, v_pages: Array,
                                 page_table: Array, pos: Array,
                                 window: Array, *, block_s: int,
-                                interpret: bool = True) -> Array:
+                                interpret: bool = False) -> Array:
     """TPU lowering: gather + all W attends in one kernel per batch row.
 
     qg: (B, W, n_kv, g, hd); k_pages/v_pages: (P, page_size, n_kv, hd)
@@ -261,10 +281,10 @@ def verify_window_attend_pallas(qg: Array, k_pages: Array, v_pages: Array,
     layer's window flag, ``2**30`` sentinel = global).  Returns
     (B, W, n_kv, g, hd) f32.  ``block_s`` (a multiple of ``page_size``
     dividing the view length) sets how many KV positions are resident in
-    VMEM at once — resolved via ``autotune.get_verify_tiles``.
+    VMEM at once — resolved via ``autotune.get_verify_tiles``.  The
+    position, window and page table ride in as scalar prefetch (SMEM), so
+    the page DMAs index HBM from scalar registers.
     """
-    if pltpu is None:  # pragma: no cover
-        raise NotImplementedError("pallas TPU helpers unavailable")
     b, w, nkv, g, hd = qg.shape
     ps = k_pages.shape[1]
     max_pages = page_table.shape[1]
@@ -273,29 +293,34 @@ def verify_window_attend_pallas(qg: Array, k_pages: Array, v_pages: Array,
         raise ValueError(
             f"block_s={block_s} must be a page_size={ps} multiple dividing "
             f"the view length {s_len}")
-    pos2 = jnp.asarray(pos, jnp.int32).reshape(b, 1)
-    win2 = jnp.asarray(window, jnp.int32).reshape(1, 1)
+    # head-major q rows: row r of head h is window slot r // g, group r % g
+    q_rows = jnp.transpose(qg.astype(jnp.float32), (0, 2, 1, 3, 4)).reshape(
+        b, nkv, w * g, hd)
     kernel = functools.partial(
-        _verify_window_kernel, page_size=ps, block_s=block_s,
-        int8_kv=k_pages.dtype == jnp.int8)
-    return pl.pallas_call(
+        _verify_window_kernel, page_size=ps, max_pages=max_pages,
+        block_s=block_s, g=g, int8_kv=k_pages.dtype == jnp.int8)
+    out = pl.pallas_call(
         kernel,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),           # pos
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),           # window
-            pl.BlockSpec((1, max_pages), lambda i: (i, 0)),   # page table
-            pl.BlockSpec((1, w, nkv, g, hd),
-                         lambda i: (i, 0, 0, 0, 0)),          # q
-            pl.BlockSpec(memory_space=pltpu.ANY),             # k pages
-            pl.BlockSpec(memory_space=pltpu.ANY),             # v pages
-        ],
-        out_specs=pl.BlockSpec((1, w, nkv, g, hd), lambda i: (i, 0, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, w, nkv, g, hd), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((block_s, nkv, hd), k_pages.dtype),
-            pltpu.VMEM((block_s, nkv, hd), v_pages.dtype),
-            pltpu.SemaphoreType.DMA,
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((None, nkv, w * g, hd),
+                             lambda i, *_: (i, 0, 0, 0)),      # q
+                pl.BlockSpec(memory_space=pl.ANY),          # k pages
+                pl.BlockSpec(memory_space=pl.ANY),          # v pages
+            ],
+            out_specs=pl.BlockSpec((None, nkv, w * g, hd),
+                                   lambda i, *_: (i, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((block_s, nkv, hd), k_pages.dtype),
+                pltpu.VMEM((block_s, nkv, hd), v_pages.dtype),
+                pltpu.SemaphoreType.DMA,
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, nkv, w * g, hd), jnp.float32),
         interpret=interpret,
-    )(pos2, win2, page_table, qg.astype(jnp.float32), k_pages, v_pages)
+    )(jnp.asarray(pos, jnp.int32).reshape(b),
+      jnp.asarray(window, jnp.int32).reshape(1),
+      page_table.astype(jnp.int32).reshape(-1), q_rows, k_pages, v_pages)
+    return jnp.transpose(out.reshape(b, nkv, w, g, hd), (0, 2, 1, 3, 4))

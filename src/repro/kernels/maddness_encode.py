@@ -7,14 +7,16 @@ one VPU pass and derive the one-hot leaf indicator by a level-by-level
 valid-mask expansion.  No gathers, no loop-carried state — the exact shape
 the paper's comparator arrays give in hardware.
 
-The kernel emits the **one-hot** form ``(B, C, G)`` because the downstream
-aggregation is a one-hot MXU contraction (see ``lut_aggregate.py``); integer
-codes, when needed, are an argmax the wrapper provides.
+The kernel emits the **one-hot** form because the downstream aggregation is
+a one-hot MXU contraction (see ``lut_aggregate.py``); integer codes, when
+needed, are an argmax the wrapper provides.
 
-Layout notes (TPU):
-  * the one-hot output's last dim is G (=16 for I=4) — we tile C so that the
-    trailing (C_t · G) axis the aggregation consumes is a multiple of 128;
-  * thresholds live in VMEM once per C-tile and are reused across the B grid.
+Layout notes (TPU): codebooks sit on lanes, as in ``fused_lutmu.py`` (whose
+:func:`~repro.kernels.fused_lutmu.leaf_masks` this kernel shares).  The
+kernel reads ``(I, B_t, C_t)`` split values and ``(G-1, C_t)`` thresholds
+and writes a leaf-major ``(G, B_t, C_t)`` one-hot; the wrapper transposes
+that to the ``(B, C, G)`` form callers consume.  Thresholds live in VMEM
+once per C-tile and are reused across the B grid.
 """
 from __future__ import annotations
 
@@ -24,32 +26,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.fused_lutmu import _ceil_to, leaf_masks, split_lanes
+
 Array = jax.Array
 
 
 def _encode_kernel(x_ref, thr_ref, out_ref, *, depth: int):
     """One (B_t, C_t) tile: comparisons → one-hot over G = 2**depth leaves.
 
-    x_ref:   (B_t, C_t, I)      split-dim values
-    thr_ref: (C_t, 2**I - 1)    heap-ordered node thresholds
-    out_ref: (B_t, C_t, 2**I)   one-hot (x's dtype)
+    x_ref:   (I, B_t, C_t)      split-dim values
+    thr_ref: (2**I - 1, C_t)    heap-ordered node thresholds
+    out_ref: (2**I, B_t, C_t)   leaf-major one-hot
     """
-    x = x_ref[...]
-    thr = thr_ref[...]
-    b_t = x.shape[0]
-    c_t = x.shape[1]
-    # valid[b, c, j]: the walk is consistent with reaching within-level node j
-    valid = jnp.ones((b_t, c_t, 1), dtype=jnp.bool_)
-    for level in range(depth):
-        lo = 2**level - 1
-        n_nodes = 2**level
-        # cmp_l[b, c, j] = x[b, c, level] >= thr[c, heap node (level, j)]
-        cmp_l = x[:, :, level][:, :, None] >= thr[None, :, lo : lo + n_nodes]
-        # children interleave: node j → (2j: left/!cmp, 2j+1: right/cmp)
-        left = jnp.logical_and(valid, jnp.logical_not(cmp_l))
-        right = jnp.logical_and(valid, cmp_l)
-        valid = jnp.stack([left, right], axis=-1).reshape(b_t, c_t, 2 * n_nodes)
-    out_ref[...] = valid.astype(out_ref.dtype)
+    for g, mask in enumerate(leaf_masks(x_ref, thr_ref, depth)):
+        out_ref[g] = jnp.where(mask, 1, 0).astype(out_ref.dtype)
 
 
 @functools.partial(
@@ -62,15 +52,14 @@ def encode_onehot_pallas(
     *,
     depth: int,
     block_b: int = 256,
-    block_c: int = 8,
+    block_c: int = 128,
     out_dtype=jnp.float32,
     interpret: bool = False,
 ) -> Array:
     """(B, C, I), (C, 2**I - 1) → one-hot (B, C, 2**I).
 
     Pads B and C up to block multiples; padded codebooks produce garbage
-    one-hots that the caller never reads (and that hit zero LUT columns in
-    the fused pipeline).
+    one-hots that are sliced off before returning.
     """
     b, c, i = x_split.shape
     g = 2**depth
@@ -79,22 +68,17 @@ def encode_onehot_pallas(
     bc = min(block_c, c)
     bp = _ceil_to(b, bb)
     cp = _ceil_to(c, bc)
-    x_p = jnp.pad(x_split, ((0, bp - b), (0, cp - c), (0, 0)))
-    t_p = jnp.pad(thresholds, ((0, cp - c), (0, 0)))
+    x_t, t_t = split_lanes(x_split, thresholds, bp, cp)
 
     out = pl.pallas_call(
         functools.partial(_encode_kernel, depth=depth),
         grid=(bp // bb, cp // bc),
         in_specs=[
-            pl.BlockSpec((bb, bc, depth), lambda ib, ic: (ib, ic, 0)),
-            pl.BlockSpec((bc, g - 1), lambda ib, ic: (ic, 0)),
+            pl.BlockSpec((depth, bb, bc), lambda ib, ic: (0, ib, ic)),
+            pl.BlockSpec((g - 1, bc), lambda ib, ic: (0, ic)),
         ],
-        out_specs=pl.BlockSpec((bb, bc, g), lambda ib, ic: (ib, ic, 0)),
-        out_shape=jax.ShapeDtypeStruct((bp, cp, g), out_dtype),
+        out_specs=pl.BlockSpec((g, bb, bc), lambda ib, ic: (0, ib, ic)),
+        out_shape=jax.ShapeDtypeStruct((g, bp, cp), out_dtype),
         interpret=interpret,
-    )(x_p, t_p)
-    return out[:b, :c]
-
-
-def _ceil_to(x: int, m: int) -> int:
-    return (x + m - 1) // m * m
+    )(x_t, t_t)
+    return jnp.transpose(out[:, :b, :c], (1, 2, 0))

@@ -22,7 +22,7 @@ Array = jax.Array
 
 
 def encode_onehot(x_split: Array, tree: HashTree, *,
-                  block_b: int = 256, block_c: int = 8,
+                  block_b: int = 256, block_c: int = 128,
                   interpret: Optional[bool] = None) -> Array:
     """(B, C, I) split values → (B, C, G) one-hot via the encode kernel."""
     if interpret is None:
@@ -54,7 +54,7 @@ def lut_aggregate(onehot: Array, lut: Array, lut_scale: Array,
 
 
 def fused_lutmu(x_split: Array, params: MaddnessParams, *,
-                block_b: int = 256, block_n: int = 256, block_c: int = 8,
+                block_b: int = 256, block_n: int = 256, block_c: int = 128,
                 interpret: Optional[bool] = None) -> Array:
     """Fused encode+aggregate from split values.  → (B, N) f32."""
     if interpret is None:

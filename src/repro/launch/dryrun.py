@@ -41,7 +41,7 @@ from repro.analysis.hlo_stats import (collective_bytes_from_hlo,
 from repro.configs import ARCH_IDS, get_config
 from repro.distributed.sharding import (batch_spec, cache_shardings,
                                         make_constrainer, param_shardings)
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.shapes import SHAPES, ShapeCell, cell_is_applicable, input_specs
 from repro.models import model as MD
 from repro.optim import cosine_schedule
@@ -227,8 +227,7 @@ def _batch_shardings(specs, mesh):
 def smoke() -> int:
     """Tiny end-to-end dry-run over reduced configs on a small host mesh."""
     n = len(jax.devices())
-    mesh = (jax.make_mesh((2, n // 2), ("data", "model")) if n >= 4
-            else jax.make_mesh((1, n), ("data", "model")))
+    mesh = make_mesh((2, n // 2) if n >= 4 else (1, n), ("data", "model"))
     failures = 0
     for arch in ARCH_IDS:
         cfg = get_config(arch, reduced=True)

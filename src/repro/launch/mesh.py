@@ -6,12 +6,25 @@ touches jax device state (the dry-run must set XLA_FLAGS before first init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    The sharding rules (``distributed/sharding.py``) place arrays with
+    ``NamedSharding`` and ``with_sharding_constraint`` and leave the rest
+    to the partitioner, which ``Explicit`` axes (``jax.make_mesh``'s
+    default) refuse.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -19,7 +32,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     if data * model > n:
         data, model = n, 1
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def parse_mesh_spec(spec: str):
@@ -52,4 +65,4 @@ def make_serve_mesh(spec: str):
             f"mesh {data}x{model} needs {data * model} devices, have {n} "
             "(hint: XLA_FLAGS=--xla_force_host_platform_device_count=N "
             "fakes N host devices)")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config
 from repro.data import TokenStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_serve_mesh
 from repro.models import model as MD
 from repro.serving import (AsyncServer, KernelProfiler, QualityProbe,
@@ -383,4 +384,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

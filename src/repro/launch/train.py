@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from repro.configs import ARCH_IDS, get_config
 from repro.data import TokenStream
 from repro.distributed.sharding import make_constrainer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.runtime.trainer import Trainer, TrainerConfig
 
@@ -61,4 +62,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

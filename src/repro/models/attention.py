@@ -13,12 +13,15 @@ Design notes:
 """
 from __future__ import annotations
 
+import functools
+import logging
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import autotune as AT
 from repro.kernels import fused_verify as FV
 from repro.models import layers as L
 from repro.models.config import ModelConfig
@@ -34,6 +37,17 @@ NEG_INF = FV.NEG_INF
 # is calibrated offline per (layer, head) like the LUT quantisation scales;
 # a single constant keeps the dry-run program shape identical.
 KV_INT8_SCALE = FV.KV_INT8_SCALE
+
+_log = logging.getLogger(__name__)
+
+
+@functools.lru_cache(maxsize=None)
+def _note_verify_fallback(s: int, w: int, nkv: int, g: int, hd: int,
+                          kv_dtype: str) -> None:
+    """Say once per shape that the verify window left the Pallas kernel."""
+    _log.warning(
+        "verify window S=%d W=%d n_kv=%d g=%d hd=%d %s: no staging fits the "
+        "VMEM budget; using the XLA lowering", s, w, nkv, g, hd, kv_dtype)
 
 
 def init_attn_params(cfg: ModelConfig, key, dtype=jnp.float32) -> dict:
@@ -416,15 +430,17 @@ def paged_verify_window(params: dict, x: Array, cfg: ModelConfig,
     impl = FV.resolve_impl(attend_impl)
     tiles = None
     if impl == "pallas":
-        from repro.kernels import autotune as AT
-        tiles = AT.get_verify_tiles(
-            page_table.shape[1] * ps, w, nkv, nq // nkv, hd, k_pages.dtype,
-            page_size=ps)
+        s_len = page_table.shape[1] * ps
+        tiles = AT.get_verify_tiles(s_len, w, nkv, nq // nkv, hd,
+                                    k_pages.dtype, page_size=ps)
+        if tiles is None:
+            _note_verify_fallback(s_len, w, nkv, nq // nkv, hd,
+                                  jnp.dtype(k_pages.dtype).name)
     if tiles is not None:
         win = jnp.asarray(2**30, jnp.int32) if window is None else window
         out = FV.verify_window_attend_pallas(
             qg, k_pages, v_pages, page_table, pos_b, win,
-            block_s=tiles.block_s, interpret=FV.default_interpret())
+            block_s=tiles.block_s, interpret=AT.default_interpret())
     else:
         k_view, v_view = _paged_view(k_pages, v_pages, page_table, nkv, hd)
         out = FV.verify_window_attend(qg, k_view, v_view, pos_b, window)

@@ -19,7 +19,9 @@ Array = jax.Array
 
 
 def dense_init(key, d_in: int, d_out: int, dtype=jnp.float32) -> Array:
-    scale = 1.0 / np.sqrt(d_in)
+    # a Python float keeps the product in ``dtype`` (a numpy scalar would
+    # promote bf16 weights to f32)
+    scale = float(1.0 / np.sqrt(d_in))
     return jax.random.normal(key, (d_in, d_out), dtype) * scale
 
 

@@ -132,7 +132,6 @@ def _moe_apply_shard_map(params: dict, x: Array, cfg: ModelConfig,
     collective volume per layer = one (B_loc, S, D) all-reduce — versus
     GSPMD's re-sharding of the (B, E, cap, D) bin tensor.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = constrain.mesh
@@ -187,12 +186,12 @@ def _moe_apply_shard_map(params: dict, x: Array, cfg: ModelConfig,
         partial = (gathered * topv[..., None].astype(dtype)).sum(axis=2)
         return jax.lax.psum(partial, tp)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(b_spec, P(), P(tp, None, None), P(tp, None, None),
                   P(tp, None, None)),
         out_specs=b_spec,
-        check_rep=False)
+        check_vma=False)
     return fn(x, params["router"], params["w_gate"], params["w_up"],
               params["w_down"])
 

@@ -748,7 +748,6 @@ class Recorder:
             "spec_emitted_total", "Tokens emitted by speculative rounds")
         # compiled-program cache
         self._jit_miss: Dict[str, Counter] = {}
-        self._jit_disabled: set = set()
         # deep-observability attachments (PR 10): a QualityProbe /
         # KernelProfiler set by the launcher; None keeps the recorder
         # jax-free and the hooks no-ops.
@@ -778,9 +777,7 @@ class Recorder:
         self._req.clear()
         self.slo.reset()
         for site in self._jit_sites:
-            size = self._cache_size(site[1])
-            if size is not None:
-                site[2] = size
+            site[2] = site[1]._cache_size()
 
     def _state(self, req) -> _ReqState:
         st = self._req.get(req.uid)
@@ -980,37 +977,11 @@ class Recorder:
         self.slo.note_acceptance(self.now(), proposed, accepted)
 
     # -- compiled-program cache misses --------------------------------------
-    @staticmethod
-    def _cache_size(fn) -> Optional[int]:
-        """Compile-cache entry count of a jitted callable, or ``None``
-        when this jax version exposes no usable probe.
-
-        ``PjitFunction._cache_size`` is a private jax surface — a jax
-        upgrade may rename or drop it.  ``None`` (rather than a silent
-        0) lets the caller mark the site *disabled* so miss counters
-        degrade to absent instead of lying or crashing the recorder."""
-        get = getattr(fn, "_cache_size", None)
-        if get is None or not callable(get):
-            return None
-        try:
-            return int(get())
-        except Exception:
-            return None
-
     def register_jit_site(self, site: str, fn) -> None:
-        """Track a jitted callable's compile cache around the engine's
+        """Track a ``jax.jit`` callable's compile cache around the engine's
         dispatch sites; growth between polls is a compile-cache miss
-        (re-tracing — e.g. an unexpected new shape on the hot path).
-        Sites whose callable has no cache probe register as disabled:
-        they are skipped by :meth:`poll_jit` (one debug log, no crash,
-        no counter samples)."""
-        baseline = self._cache_size(fn)
-        if baseline is None:
-            if site not in self._jit_disabled:
-                self._jit_disabled.add(site)
-                log("obs", f"jit cache probe unavailable for site "
-                    f"{site!r}; miss counter disabled", level="debug")
-            return
+        (re-tracing — e.g. an unexpected new shape on the hot path)."""
+        baseline = fn._cache_size()
         self._jit_miss.setdefault(site, self.registry.counter(
             "jit_cache_misses_total",
             "Compile-cache misses at instrumented dispatch sites",
@@ -1022,9 +993,7 @@ class Recorder:
 
     def poll_jit(self) -> None:
         for entry in self._jit_sites:
-            size = self._cache_size(entry[1])
-            if size is None:
-                continue  # probe vanished mid-flight: degrade, don't crash
+            size = entry[1]._cache_size()
             if size > entry[2]:
                 self._jit_miss[entry[0]].inc(size - entry[2])
                 entry[2] = size

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.mesh import make_mesh
+
 
 def _random_params(b, c, n, depth, *, int8, seed=0):
     from repro.core import maddness as M
@@ -99,7 +101,7 @@ def check_engine_parity(amm):
         return [r.generated for r in reqs]
 
     single = run(None)
-    sharded = run(jax.make_mesh((2, 2), ("data", "model")))
+    sharded = run(make_mesh((2, 2), ("data", "model")))
     assert single == sharded, (amm, single, sharded)
     print(f"[sharded_check] engine parity OK (amm={amm})")
 
@@ -107,7 +109,7 @@ def check_engine_parity(amm):
 def main():
     n = len(jax.devices())
     assert n >= 8, f"need 8 faked host devices, got {n} (set XLA_FLAGS)"
-    check_dispatch_parity(jax.make_mesh((2, 4), ("data", "model")))
+    check_dispatch_parity(make_mesh((2, 4), ("data", "model")))
     check_engine_parity(amm=False)
     check_engine_parity(amm=True)
     print("[sharded_check] all OK")

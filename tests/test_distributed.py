@@ -11,14 +11,9 @@ SRC = str(Path(__file__).resolve().parents[1] / "src")
 
 
 def _abstract_mesh_16x16():
-    """AbstractMesh across jax versions: ≤0.4.x takes ((name, size), ...)
-    pairs; newer jax takes (sizes, names)."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh((("data", 16), ("model", 16)))
-    except TypeError:
-        return AbstractMesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_sharding_rules_unit():

@@ -249,14 +249,17 @@ def test_verify_shape_key_namespaced_and_batch_free():
 
 
 def test_verify_vmem_budget_gates_candidates():
-    # generous budget: every power-of-2 page multiple dividing S, largest
-    # first (fewest DMA round-trips)
-    cands = AT.verify_candidate_tiles(128, 4, 2, 4, 64, 1, 16,
+    # generous budget: every lane-legal power-of-2 page multiple dividing S
+    # (a multiple of 128, or S itself), largest first (fewest DMA
+    # round-trips)
+    cands = AT.verify_candidate_tiles(512, 4, 2, 4, 64, 1, 16,
                                       budget_bytes=1 << 30)
-    assert [t.block_s for t in cands] == [128, 64, 32, 16]
+    assert [t.block_s for t in cands] == [512, 256, 128]
     for t in cands:
-        assert AT.verify_vmem_bytes(t, 128, 4, 2, 4, 64, 1) <= (
+        assert AT.verify_vmem_bytes(t, 512, 4, 2, 4, 64, 1) <= (
             (1 << 30) * AT.VMEM_FRACTION)
+    assert [t.block_s for t in AT.verify_candidate_tiles(
+        64, 4, 2, 4, 64, 1, 16, budget_bytes=1 << 30)] == [64]
     # the logits term (W·n_kv·g·S·4) alone blows a tiny budget: no staging
     # fits and the caller must take the portable lowering
     assert AT.verify_candidate_tiles(128, 4, 2, 4, 64, 1, 16,

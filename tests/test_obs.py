@@ -400,19 +400,23 @@ def test_histogram_quantile_edge_cases():
     assert top.mean == 50.0  # sum/count still carry the true value
 
 
-def test_jit_site_without_cache_size_degrades():
-    """A dispatch site whose callable exposes no ``_cache_size`` (plain
-    function, or a jax that dropped the private API) must disable its
-    miss counter — register, poll and reset all stay no-ops instead of
-    crashing the recorder."""
+def test_jit_site_counts_each_new_shape_once():
+    """Every new input shape at a registered site is one compile-cache
+    miss; a repeated shape is none, and ``reset`` re-baselines."""
+    import jax
+    import jax.numpy as jnp
+
     rec = Recorder(trace=False)
-
-    def plain(x):
-        return x
-
-    rec.register_jit_site("weird.site", plain)
-    rec.poll_jit()   # must not raise
-    rec.reset()      # must not raise
+    fn = jax.jit(lambda x: x + 1)
+    rec.register_jit_site("toy.site", fn)
+    fn(jnp.zeros(3))
+    rec.poll_jit()
+    assert rec.registry.sum_values("jit_cache_misses_total") == 1
+    fn(jnp.zeros(3))
+    rec.poll_jit()
+    assert rec.registry.sum_values("jit_cache_misses_total") == 1
+    fn(jnp.zeros(4))
+    rec.reset()      # the new shape compiled before the reset: no miss
     rec.poll_jit()
     assert rec.registry.sum_values("jit_cache_misses_total") == 0
 

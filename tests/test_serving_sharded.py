@@ -9,7 +9,6 @@ import subprocess
 import sys
 from pathlib import Path
 
-import jax
 import jax.numpy as jnp
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -20,8 +19,9 @@ def test_sharded_fallback_single_device():
     """A 1x1 mesh must reproduce the unsharded dispatch result exactly."""
     from sharded_check import _random_params
     from repro.kernels.dispatch import lutmu_matmul, lutmu_matmul_sharded
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for int8 in (True, False):
         xs, params = _random_params(8, 4, 16, 3, int8=int8)
         ref = lutmu_matmul(xs, params, backend="ref", input_kind="split")
