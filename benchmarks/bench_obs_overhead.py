@@ -2,8 +2,8 @@
 
 The PR-7 contract (extended by PR 10) is that a live metrics recorder
 costs a few percent at most — every engine hook is ``if obs:``-guarded
-host bookkeeping, and the PR-10 layers (quality probes, kernel
-profiler) are sampling-based so their *default-off* path adds nothing.
+host bookkeeping, and the quality probe is sampling-based so its
+*default-off* path adds nothing.
 This bench pins the contract with a number: the same mixed-length
 workload drains through the paged engine with no recorder and with a
 metrics-only :class:`repro.serving.Recorder`, best-of-``REPEATS``

@@ -43,8 +43,8 @@ Checks, in order:
   9. **the overhead claim** — every ``serve/obs_overhead/...`` record
      shows the metrics-on engine at or above ``--min-obs-ratio`` × the
      recorder-less engine's tokens/s (default 0.95: a live metrics
-     registry may cost at most 5 % — PR 10's sampled probes and
-     profiler must keep the default-off path free).  Presence is
+     registry may cost at most 5 % — the sampled quality probe must
+     keep the default-off path free).  Presence is
      enforced by coverage against ``BENCH_PR10.json``.
 
 Absolute µs numbers are *not* compared — CI machines vary too much; the

@@ -46,7 +46,7 @@ Array = jax.Array
 BACKENDS = ("ref", "unfused", "fused")
 INPUT_KINDS = ("full", "split", "package")
 
-# Optional observability hook (serving/profiler.py): called with static
+# Optional observability hook (``attach_dispatch_hook``): called with static
 # call metadata after backend selection.  Fires at trace time — once per
 # compiled program, never per executed step — and only ever receives
 # python ints/strings (shapes/dtypes/backend), so it cannot leak tracers
@@ -59,6 +59,23 @@ def set_profile_hook(hook) -> None:
     """Install (or clear, with ``None``) the dispatch-metadata hook."""
     global _PROFILE_HOOK
     _PROFILE_HOOK = hook
+
+
+def attach_dispatch_hook(registry):
+    """Count LUT-MU backend selections in ``registry`` (a
+    ``serving.obs.MetricsRegistry``) as ``lutmu_dispatch_total{backend,
+    input_kind}``; returns a detach callable.  Counts on static metadata
+    at trace time — one event per compiled program, zero per-step cost."""
+
+    def hook(*, backend: str, input_kind: str, **_meta) -> None:
+        registry.counter(
+            "lutmu_dispatch_total",
+            "LUT-MU programs compiled per selected backend",
+            backend=backend, input_kind=input_kind).inc()
+
+    set_profile_hook(hook)
+    return lambda: set_profile_hook(None)
+
 
 # Below either threshold the MXU tiles are mostly padding — see docs/kernels.md.
 _MIN_MXU_ROWS = 8

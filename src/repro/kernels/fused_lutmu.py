@@ -151,6 +151,7 @@ def fused_lutmu_pallas(
         out_specs=pl.BlockSpec((bb, bn), lambda ib, jn, kc: (ib, jn)),
         out_shape=jax.ShapeDtypeStruct((bp, np_), acc_dtype),
         interpret=interpret,
+        name="fused_lutmu",
     )(x_t, t_t, l_p)
     out = out[:b, :n].astype(jnp.float32)
     return out * lut_scale + lut_offset

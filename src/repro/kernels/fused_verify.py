@@ -320,6 +320,7 @@ def verify_window_attend_pallas(qg: Array, k_pages: Array, v_pages: Array,
         ),
         out_shape=jax.ShapeDtypeStruct((b, nkv, w * g, hd), jnp.float32),
         interpret=interpret,
+        name="verify_window",
     )(jnp.asarray(pos, jnp.int32).reshape(b),
       jnp.asarray(window, jnp.int32).reshape(1),
       page_table.astype(jnp.int32).reshape(-1), q_rows, k_pages, v_pages)

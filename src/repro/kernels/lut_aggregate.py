@@ -103,6 +103,7 @@ def lut_aggregate_pallas(
         out_specs=pl.BlockSpec((bb, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, np_), acc_dtype),
         interpret=interpret,
+        name="lut_aggregate",
     )(lhs, rhs)
     out = out[:b, :n].astype(jnp.float32)
     return out * lut_scale + lut_offset

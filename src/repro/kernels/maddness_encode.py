@@ -80,5 +80,6 @@ def encode_onehot_pallas(
         out_specs=pl.BlockSpec((g, bb, bc), lambda ib, ic: (0, ib, ic)),
         out_shape=jax.ShapeDtypeStruct((g, bp, cp), out_dtype),
         interpret=interpret,
+        name="maddness_encode",
     )(x_t, t_t)
     return jnp.transpose(out[:, :b, :c], (1, 2, 0))

@@ -19,6 +19,11 @@ Examples:
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-14b --reduced \
       --artifact /tmp/lm_bundle --speculative --spec-k 3
 
+  # a jax.profiler trace of 8 engine steps: device ops beside the
+  # engine's serve.* step spans (docs/observability.md)
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-14b --reduced \
+      --requests 4 --max-new 16 --profile-dir /tmp/prof --profile-steps 8
+
   # async HTTP front-end: NDJSON token streaming on localhost:8080
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-14b --reduced \
       --http --port 8080 --metrics /tmp/serve.prom
@@ -39,9 +44,9 @@ from repro.data import TokenStream
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_serve_mesh
 from repro.models import model as MD
-from repro.serving import (AsyncServer, KernelProfiler, QualityProbe,
-                           Recorder, SamplingParams, attach_dispatch_hook,
-                           load_engine, log, slo_report, summary_table)
+from repro.serving import (AsyncServer, QualityProbe, Recorder,
+                           SamplingParams, attach_dispatch_hook, load_engine,
+                           log, slo_report, summary_table)
 
 
 def _artifact_kind(path):
@@ -99,6 +104,34 @@ def _cli_prompts(args, cfg):
     stream = TokenStream(vocab_size=cfg.vocab_size, batch_size=1, seq_len=16)
     return [[int(t) for t in stream.batch(i)["tokens"][0][:8]]
             for i in range(args.requests)]
+
+
+def drain_profiled(engine, handles, out_dir, n_steps: int) -> list:
+    """Step ``engine`` until it drains, writing a ``jax.profiler`` trace
+    of ``n_steps`` engine steps under ``out_dir``: the device's ops beside
+    the engine's ``serve.*`` step spans.  The trace starts after warm-up:
+    at the first step after one that decoded, when the prefill and decode
+    programs have compiled.  Returns the finished requests."""
+    done, left, tracing = [], n_steps, False
+    while engine.has_work:
+        if (not tracing and left
+                and any(len(h.generated) > 1 for h in handles)):
+            jax.profiler.start_trace(out_dir)
+            tracing = True
+        done.extend(engine.step())
+        if tracing:
+            left -= 1
+            if not left:
+                jax.profiler.stop_trace()
+                tracing = False
+    if tracing:
+        jax.profiler.stop_trace()
+    if left == n_steps:
+        log("serve", "--profile-dir: the run drained during warm-up; no "
+            "trace written")
+    else:
+        log("serve", f"profile of {n_steps - left} engine steps → {out_dir}")
+    return done
 
 
 def _serve_http(engine, args, rec) -> None:
@@ -243,17 +276,24 @@ def main() -> None:
                          "histograms, codebook utilisation and dequant "
                          "saturation (GET /debug/quality; emitted streams "
                          "are untouched — see docs/observability.md)")
-    ap.add_argument("--profile-every", type=int, default=0, metavar="N",
-                    help="profile every N-th engine step: per-site kernel "
-                         "latency histograms, XLA cost-analysis FLOPs/bytes "
-                         "and a 'kernels' trace lane (0 = off; profiled "
-                         "steps sync, all others keep the zero-overhead "
-                         "path)")
+    ap.add_argument("--profile-dir", metavar="DIR",
+                    help="write a jax.profiler trace of --profile-steps "
+                         "engine steps after warm-up under DIR: device ops "
+                         "beside the engine's serve.* step spans (read it "
+                         "with TensorBoard, Perfetto or "
+                         "jax.profiler.ProfileData)")
+    ap.add_argument("--profile-steps", type=int, default=8, metavar="N",
+                    help="engine steps the --profile-dir trace covers "
+                         "(default 8)")
     ap.add_argument("--slo-report", action="store_true",
                     help="print the sliding-window SLO health report "
                          "(tok/s, TTFT/TPOT p50/p99, acceptance, error "
                          "budgets) after serving; live snapshot at GET /slo")
     args = ap.parse_args()
+    if args.profile_dir and args.http:
+        ap.error("--profile-dir traces a CLI run; drop --http")
+    if args.profile_steps < 1:
+        ap.error(f"--profile-steps must be >= 1, got {args.profile_steps}")
 
     mesh = _resolve_mesh(args)
 
@@ -282,17 +322,14 @@ def main() -> None:
     # NullRecorder (zero-overhead-off — see docs/observability.md)
     rec = (Recorder(trace=bool(args.trace_out))
            if (args.metrics or args.trace_out or args.http
-               or args.quality_probe or args.profile_every
-               or args.slo_report) else None)
+               or args.quality_probe or args.slo_report) else None)
     if rec is not None and args.quality_probe:
         # `params` is the pre-splice tree: with a --ckpt/random dense model
         # it still carries the dense mlp weights the probe references
         # (pure-AMM params degrade to utilisation/saturation tracking)
         rec.quality = QualityProbe(rec.registry, rate=args.quality_probe,
                                    dense_params=params)
-    if rec is not None and args.profile_every:
-        rec.profiler = KernelProfiler(rec.registry, tracer=rec.tracer,
-                                      every=args.profile_every)
+    if rec is not None:
         attach_dispatch_hook(rec.registry)
     kwargs = dict(max_batch=max_batch, max_len=args.max_len,
                   page_size=args.page_size,
@@ -350,16 +387,20 @@ def main() -> None:
         return
 
     prompts = _cli_prompts(args, cfg)
-    for i, prompt in enumerate(prompts):
-        # per-request seed: streams stay reproducible (and distinct)
-        # however the batch interleaves them
-        engine.submit(prompt, max_new_tokens=args.max_new,
-                      sampling=SamplingParams(temperature=args.temperature,
-                                              top_k=args.top_k,
-                                              top_p=args.top_p,
-                                              seed=args.seed + i))
+    # per-request seed: streams stay reproducible (and distinct) however
+    # the batch interleaves them
+    handles = [engine.submit(prompt, max_new_tokens=args.max_new,
+                             sampling=SamplingParams(
+                                 temperature=args.temperature,
+                                 top_k=args.top_k, top_p=args.top_p,
+                                 seed=args.seed + i))
+               for i, prompt in enumerate(prompts)]
     t0 = time.time()
-    done = engine.run_until_drained()
+    if args.profile_dir:
+        done = drain_profiled(engine, handles, args.profile_dir,
+                              args.profile_steps)
+    else:
+        done = engine.run_until_drained()
     dt = time.time() - t0
     n_tok = sum(len(r.generated) for r in done)
     print(f"{len(done)} requests, {n_tok} tokens, {dt:.1f}s "

@@ -7,6 +7,7 @@ in ``__all__`` is covered by the API-stability tests in
 ``tests/test_api.py``; anything else is internal and may change without
 a deprecation cycle.
 """
+from repro.kernels.dispatch import attach_dispatch_hook  # noqa: F401
 from repro.serving.engine import (FixedSlotEngine, Request,  # noqa: F401
                                   ServeEngine, make_engine)
 from repro.serving.handle import RequestHandle  # noqa: F401
@@ -20,8 +21,6 @@ from repro.serving.obs import (NULL_RECORDER, MetricsRegistry,  # noqa: F401
                                summary_table, validate_chrome_trace,
                                validate_prometheus)
 from repro.serving.prefix import RadixPrefixIndex  # noqa: F401
-from repro.serving.profiler import (KernelProfiler,  # noqa: F401
-                                    attach_dispatch_hook)
 from repro.serving.quality import QualityProbe  # noqa: F401
 from repro.serving.sampling import SamplingParams  # noqa: F401
 from repro.serving.scheduler import Scheduler, StepPlan  # noqa: F401
@@ -58,7 +57,6 @@ __all__ = [
     "validate_chrome_trace",
     # deep observability (PR 10)
     "QualityProbe",
-    "KernelProfiler",
     "attach_dispatch_hook",
     "SloTracker",
     "SloThresholds",

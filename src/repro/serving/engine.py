@@ -62,7 +62,7 @@ from repro.serving import sampling as S
 from repro.serving import scheduler as SCH
 from repro.serving.handle import RequestHandle, _step_engine_async
 from repro.serving.kv_cache import PagedKVCache
-from repro.serving.obs import NULL_RECORDER, log
+from repro.serving.obs import NULL_RECORDER, STEP_SPAN, log
 from repro.serving.sampling import SamplingParams
 from repro.serving.scheduler import Request, Scheduler
 
@@ -143,17 +143,102 @@ def _artifact_params_cfg(artifact_path, params, cfg: ModelConfig, mesh):
     return _splice_artifact(load_artifact(artifact_path), params, cfg, mesh)
 
 
-def _sample_batch(logits, rows_reqs, batch: int) -> np.ndarray:
+class _Phase:
+    """One phase span of an engine step, entered once per use (the
+    phases of a step follow each other and never nest)."""
+
+    __slots__ = ("obs", "name", "after", "_kw", "_ann", "_t0")
+
+    def __init__(self, obs, name: str, after: Optional[str] = None):
+        self.obs, self.name, self.after = obs, name, after
+        self._kw = {"after": after} if after else {}
+        self._ann = self._t0 = None
+
+    def __enter__(self):
+        # no annotation at all while no profiler session collects: the
+        # check costs a fifth of an annotation
+        ann = jax.profiler.TraceAnnotation
+        self._ann = ann(self.name, **self._kw) if ann.is_enabled() else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self.obs:
+            self._t0 = self.obs.now()
+
+    def __exit__(self, *exc):
+        if self.obs:
+            self.obs.on_phase(self.name, self._t0, self.obs.now(),
+                              self.after)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+
+
+class _Step:
+    """The ``serve.step`` span around one engine step; ``count`` is the
+    engine's step counter."""
+
+    __slots__ = ("obs", "count", "_ann")
+
+    def __init__(self, obs):
+        self.obs, self.count, self._ann = obs, 0, None
+
+    def __enter__(self):
+        self.count += 1
+        ann = jax.profiler.StepTraceAnnotation
+        self._ann = (ann(STEP_SPAN, step_num=self.count)
+                     if ann.is_enabled() else None)
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self.obs:
+            self.obs.on_step_begin(self.count)
+
+    def __exit__(self, *exc):
+        if self.obs:
+            self.obs.on_step_end()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+
+
+class StepSpans:
+    """The spans of an engine's steps, shared by every engine so the
+    phase names cannot drift between them (``obs.STEP_PHASES``).
+
+    ``with spans.step:`` wraps one engine step; each other attribute is
+    one phase inside it.  Every span is a ``jax.profiler`` annotation on
+    the profiler's clock while a profiler session is collecting, and,
+    with a recorder attached, a phase of the recorder's step record
+    (``Recorder.steps``).  With neither, a span costs well under a
+    microsecond of host time."""
+
+    def __init__(self, obs):
+        self.step = _Step(obs)
+        self.schedule = _Phase(obs, "serve.schedule")
+        self.kv_move = _Phase(obs, "serve.kv_move")
+        self.prefill = _Phase(obs, "serve.prefill")
+        self.decode = _Phase(obs, "serve.decode")
+        self.sample = _Phase(obs, "serve.sample")
+        # the host blocked until the sampled tokens arrive: the only
+        # device wait of a step
+        self.tokens_after_prefill = _Phase(obs, "serve.tokens", "prefill")
+        self.tokens_after_decode = _Phase(obs, "serve.tokens", "decode")
+        self.retire = _Phase(obs, "serve.retire")
+
+
+def _sample_batch(logits, rows_reqs, batch: int, sample_span,
+                  tokens_span) -> np.ndarray:
     """Draw each row's next token through the per-request sampler.
 
     ``logits (batch, V)`` + ``(row, request)`` pairs → ``(batch,)`` int32
     on host.  Greedy requests (T=0, the default) reduce to ``argmax``
     bit-exactly inside the same jitted program; rows not listed default
     to greedy and their samples are discarded by the caller.  Shared by
-    every engine so sampling semantics cannot drift between them."""
-    seed, t, temp, top_k, top_p = S.batch_rows(rows_reqs, batch)
-    return np.asarray(
-        S.sample_tokens_jit(logits, seed, t, temp, top_k, top_p))
+    every engine so sampling semantics cannot drift between them.  The
+    dispatch runs in ``sample_span``, the wait for the tokens in
+    ``tokens_span``."""
+    with sample_span:
+        seed, t, temp, top_k, top_p = S.batch_rows(rows_reqs, batch)
+        toks = S.sample_tokens_jit(logits, seed, t, temp, top_k, top_p)
+    with tokens_span:
+        return np.asarray(toks)
 
 
 def _bind_quality(obs, params, cfg: ModelConfig) -> None:
@@ -164,17 +249,6 @@ def _bind_quality(obs, params, cfg: ModelConfig) -> None:
     quality = getattr(obs, "quality", None)
     if quality is not None:
         quality.bind(params, cfg)
-
-
-def _profiled_call(obs, site: str, fn, *args):
-    """Route one jitted dispatch through the kernel profiler on profiled
-    steps.  The off path (no recorder, no profiler, or an unprofiled
-    step) is one truthiness check plus one attribute read — no wrapper,
-    no sync — preserving the zero-overhead-off contract."""
-    prof = getattr(obs, "profiler", None) if obs else None
-    if prof is not None and prof.active:
-        return prof.timed(site, fn, *args)
-    return fn(*args)
 
 
 def _drain(engine, max_steps: int):
@@ -221,6 +295,7 @@ class ServeEngine:
         # ``if self.obs:``-guarded — the default NullRecorder is falsy, so
         # disabled cost is one host truthiness check and no device syncs.
         self.obs = recorder if recorder is not None else NULL_RECORDER
+        self.spans = StepSpans(self.obs)
         # ``slots`` is the fixed-slot engine's name for the same knob; keep
         # it as an alias so call sites migrate freely.
         self.max_batch = int(max_batch or slots or 4)
@@ -360,20 +435,39 @@ class ServeEngine:
     def step(self) -> List[Request]:
         """One engine iteration: execute the scheduler's plan — swap-outs,
         swap-ins, copy-on-write clones, at most one prefill chunk, one
-        batched decode — and retire finished requests."""
-        if self.obs:
-            prof = getattr(self.obs, "profiler", None)
-            if prof is not None:
-                prof.tick()
-        plan = self.sched.schedule()
+        batched decode — and retire finished requests, each part in its
+        phase span (:class:`StepSpans`)."""
+        sp = self.spans
+        finished: List[Request] = []
+        with sp.step:
+            with sp.schedule:
+                plan = self.sched.schedule()
+            if plan.swap_out or plan.swap_in or plan.cow:
+                with sp.kv_move:
+                    self._move_kv(plan)
+            if plan.prefill is not None:
+                self._run_prefill_chunk(plan.prefill, finished)
+            if plan.decode:
+                self._run_decode(plan.decode, finished)
+            if self.obs:
+                with sp.retire:
+                    self.obs.sample_pool(self.kv.allocator)
+                    self.obs.poll_jit()
+        return finished
+
+    def run_until_drained(self, max_steps: int = 10000) -> List[Request]:
+        return _drain(self, max_steps)
+
+    # -- internals ---------------------------------------------------------
+    def _move_kv(self, plan: SCH.StepPlan) -> None:
+        """The plan's swap-outs, swap-ins and copy-on-write clones."""
         resharded = False
         for req, old_pages in plan.swap_out:
             # the allocator already released these pages; copy them before
             # anything writes (the first writes happen below)
-            req.host_kv = self.kv.gather_host(old_pages)
+            self._swap_out(req, old_pages)
         for req in plan.swap_in:
-            self.kv.scatter_host(req.host_kv, req.pages)
-            req.host_kv = None
+            self._swap_in(req)
             resharded = True
         for clone in plan.cow:
             if clone.req.cow is None:
@@ -386,28 +480,22 @@ class ServeEngine:
             # the jitted calls' explicit in_shardings (and donation) line up
             self.kv.buffers = jax.device_put(self.kv.buffers, self._cache_sh)
 
-        finished: List[Request] = []
-        if plan.prefill is not None:
-            self._run_prefill_chunk(plan.prefill, finished)
-        if plan.decode:
-            self._run_decode(plan.decode, finished)
-        if self.obs:
-            self.obs.sample_pool(self.kv.allocator)
-            self.obs.poll_jit()
-        return finished
+    def _swap_out(self, req: Request, old_pages: List[int]) -> None:
+        """Copy a victim's pages to host (the speculative engine copies
+        its draft cache too)."""
+        req.host_kv = self.kv.gather_host(old_pages)
 
-    def run_until_drained(self, max_steps: int = 10000) -> List[Request]:
-        return _drain(self, max_steps)
+    def _swap_in(self, req: Request) -> None:
+        self.kv.scatter_host(req.host_kv, req.pages)
+        req.host_kv = None
 
-    # -- internals ---------------------------------------------------------
     def _prefill_call(self, toks, chunk: SCH.PrefillChunk, page_row):
         """Run the jitted prefill program(s) for one chunk and return the
         target logits.  The ONLY prefill behaviour subclasses may change
         (the speculative engine prefills its draft cache here too) — the
         chunk bookkeeping around it stays in :meth:`_run_prefill_chunk` so
         budget/eos fixes cannot drift between engines."""
-        logits, self.kv.buffers = _profiled_call(
-            self.obs, "serve.prefill", self._prefill,
+        logits, self.kv.buffers = self._prefill(
             self.params, jnp.asarray(toks),
             jnp.asarray(chunk.start, jnp.int32),
             jnp.asarray(chunk.n_valid, jnp.int32),
@@ -416,18 +504,29 @@ class ServeEngine:
 
     def _run_prefill_chunk(self, chunk: SCH.PrefillChunk,
                            finished: List[Request]) -> None:
-        req = chunk.req
-        toks = np.zeros((1, self.prefill_chunk), np.int32)
-        toks[0, : chunk.n_valid] = req.prompt[chunk.start:
-                                              chunk.start + chunk.n_valid]
-        page_row = self.kv.page_row(req.pages, self.max_pages_per_seq)
-        obs = self.obs
-        t0 = obs.now() if obs else 0.0
-        logits = self._prefill_call(toks, chunk, page_row)
-        req.pf_done += chunk.n_valid
-        if req.pf_done == len(req.prompt):
-            req.generated.append(
-                int(_sample_batch(logits[0, -1:], [(0, req)], 1)[0]))
+        req, obs, sp = chunk.req, self.obs, self.spans
+        with sp.prefill:
+            toks = np.zeros((1, self.prefill_chunk), np.int32)
+            toks[0, : chunk.n_valid] = req.prompt[chunk.start:
+                                                  chunk.start + chunk.n_valid]
+            page_row = self.kv.page_row(req.pages, self.max_pages_per_seq)
+            t0 = obs.now() if obs else 0.0
+            logits = self._prefill_call(toks, chunk, page_row)
+            req.pf_done += chunk.n_valid
+            final = req.pf_done == len(req.prompt)
+            if final:
+                logits = logits[0, -1:]
+            elif obs:
+                # non-final chunk: the dispatch window (no host sync
+                # happens here, so the span measures host+dispatch work)
+                obs.on_prefill(req, chunk.start // self.prefill_chunk,
+                               chunk.n_valid, t0, obs.now())
+        if not final:
+            return
+        nxt = _sample_batch(logits, [(0, req)], 1, sp.sample,
+                            sp.tokens_after_prefill)
+        with sp.retire:
+            req.generated.append(int(nxt[0]))
             if obs:
                 t1 = obs.now()
                 obs.on_prefill(req, chunk.start // self.prefill_chunk,
@@ -439,40 +538,38 @@ class ServeEngine:
             if req.budget_reached(self.max_len):
                 self.sched.retire(req)
                 finished.append(req)
-        elif obs:
-            # non-final chunk: the dispatch window (no host sync happens
-            # here, so the span measures host+dispatch work only)
-            obs.on_prefill(req, chunk.start // self.prefill_chunk,
-                           chunk.n_valid, t0, obs.now())
 
     def _run_decode(self, decode, finished: List[Request]) -> None:
-        token = np.zeros((self.max_batch, 1), np.int32)
-        pos = np.zeros((self.max_batch,), np.int32)
-        table = np.full((self.max_batch, self.max_pages_per_seq),
-                        self.kv.trash, np.int32)
-        for row, req in decode:
-            token[row, 0] = req.generated[-1]
-            pos[row] = req.next_pos
-            table[row, : len(req.pages)] = req.pages
-        obs = self.obs
-        t0 = obs.now() if obs else 0.0
-        logits, self.kv.buffers = _profiled_call(
-            self.obs, "serve.decode", self._decode,
-            self.params, jnp.asarray(token), jnp.asarray(pos),
-            jnp.asarray(table), self.kv.buffers)
-        nxt = _sample_batch(logits[:, 0], decode, self.max_batch)
-        if obs:
-            # _sample_batch pulled the tokens to host, so t1 covers the
-            # step's real wall time without adding a sync of our own
-            t1 = obs.now()
-            obs.on_decode(decode, t0, t1)
-        for row, req in decode:
-            req.generated.append(int(nxt[row]))
+        obs, sp = self.obs, self.spans
+        with sp.decode:
+            token = np.zeros((self.max_batch, 1), np.int32)
+            pos = np.zeros((self.max_batch,), np.int32)
+            table = np.full((self.max_batch, self.max_pages_per_seq),
+                            self.kv.trash, np.int32)
+            for row, req in decode:
+                token[row, 0] = req.generated[-1]
+                pos[row] = req.next_pos
+                table[row, : len(req.pages)] = req.pages
+            t0 = obs.now() if obs else 0.0
+            logits, self.kv.buffers = self._decode(
+                self.params, jnp.asarray(token), jnp.asarray(pos),
+                jnp.asarray(table), self.kv.buffers)
+            logits = logits[:, 0]
+        nxt = _sample_batch(logits, decode, self.max_batch, sp.sample,
+                            sp.tokens_after_decode)
+        with sp.retire:
             if obs:
-                obs.on_tokens(req, 1, t1)
-            if req.budget_reached(self.max_len):
-                self.sched.retire(req)
-                finished.append(req)
+                # _sample_batch pulled the tokens to host, so t1 covers
+                # the step's real wall time without a sync of our own
+                t1 = obs.now()
+                obs.on_decode(decode, t0, t1)
+            for row, req in decode:
+                req.generated.append(int(nxt[row]))
+                if obs:
+                    obs.on_tokens(req, 1, t1)
+                if req.budget_reached(self.max_len):
+                    self.sched.retire(req)
+                    finished.append(req)
 
 
 class FixedSlotEngine:
@@ -492,6 +589,7 @@ class FixedSlotEngine:
         # same zero-overhead-off observability contract as ServeEngine
         # (no scheduler here, so lifecycle hooks fire from the engine)
         self.obs = recorder if recorder is not None else NULL_RECORDER
+        self.spans = StepSpans(self.obs)
         self.queue: Deque[Request] = deque()
         self.active: Dict[int, Request] = {}  # slot -> request
         self.pos = np.zeros(slots, dtype=np.int64)  # per-slot next position
@@ -597,100 +695,108 @@ class FixedSlotEngine:
     async def _advance_async(self) -> None:
         await _step_engine_async(self)
 
-    def _admit(self) -> List[Request]:
+    def _admit(self, finished: List[Request]) -> None:
         """Fill free slots: per-request prefill (batch=1 rows of the cache)."""
-        finished: List[Request] = []
-        free = [s for s in range(self.slots) if s not in self.active]
+        obs, sp = self.obs, self.spans
+        with sp.schedule:
+            free = [s for s in range(self.slots) if s not in self.active]
         spliced = False
-        obs = self.obs
         while free and self.queue:
-            slot = free.pop(0)
-            req = self.queue.popleft()
-            req.state = SCH.RUNNING  # for RequestHandle.status
-            if obs:
-                obs.on_admit(req)
-                t0 = obs.now()
-            tokens = jnp.asarray(req.prompt, jnp.int32)[None]
-            logits, cache1 = MD.prefill(
-                self.params, tokens, self.cfg, self.max_len,
-                constrain=self._constrain, compute_dtype=self.cd)
-            # splice the single-row cache into this slot
-            self.cache = jax.tree.map(
-                lambda full, one: jax.lax.dynamic_update_index_in_dim(
-                    full, one[:, 0].astype(full.dtype), slot, 1)
-                if one.ndim >= 2 and full.shape[1] == self.slots else full,
-                self.cache, cache1)
-            spliced = True
-            req.generated.append(
-                int(_sample_batch(logits[0, -1:], [(0, req)], 1)[0]))
-            if obs:
-                t1 = obs.now()
-                obs.on_prefill(req, 0, len(req.prompt), t0, t1)
-                obs.on_tokens(req, 1, t1, source="prefill")
-            if req.budget_reached(self.max_len):
-                req.done = True
-                req.state = SCH.DONE
-                finished.append(req)
-                free.insert(0, slot)
+            with sp.prefill:
+                slot = free.pop(0)
+                req = self.queue.popleft()
+                req.state = SCH.RUNNING  # for RequestHandle.status
                 if obs:
-                    obs.on_finish(req)
-                continue
-            self.active[slot] = req
-            self.pos[slot] = len(req.prompt)
+                    obs.on_admit(req)
+                    t0 = obs.now()
+                tokens = jnp.asarray(req.prompt, jnp.int32)[None]
+                logits, cache1 = MD.prefill(
+                    self.params, tokens, self.cfg, self.max_len,
+                    constrain=self._constrain, compute_dtype=self.cd)
+                # splice the single-row cache into this slot
+                self.cache = jax.tree.map(
+                    lambda full, one: jax.lax.dynamic_update_index_in_dim(
+                        full, one[:, 0].astype(full.dtype), slot, 1)
+                    if one.ndim >= 2 and full.shape[1] == self.slots
+                    else full, self.cache, cache1)
+                spliced = True
+                logits = logits[0, -1:]
+            nxt = _sample_batch(logits, [(0, req)], 1, sp.sample,
+                                sp.tokens_after_prefill)
+            with sp.retire:
+                req.generated.append(int(nxt[0]))
+                if obs:
+                    t1 = obs.now()
+                    obs.on_prefill(req, 0, len(req.prompt), t0, t1)
+                    obs.on_tokens(req, 1, t1, source="prefill")
+                if req.budget_reached(self.max_len):
+                    req.done = True
+                    req.state = SCH.DONE
+                    finished.append(req)
+                    free.insert(0, slot)
+                    if obs:
+                        obs.on_finish(req)
+                    continue
+                self.active[slot] = req
+                self.pos[slot] = len(req.prompt)
         if spliced and self.mesh is not None:
             # the eager splice drifts leaf shardings off the rule-engine
             # placement; restore it so the sharded decode's explicit
             # in_shardings (and donation) line up.
-            self.cache = jax.device_put(self.cache, self._cache_sh)
-        return finished
+            with sp.kv_move:
+                self.cache = jax.device_put(self.cache, self._cache_sh)
 
     @property
     def has_work(self) -> bool:
         return bool(self.queue or self.active)
 
     def step(self) -> List[Request]:
-        """One engine iteration: admit, batched decode, retire."""
-        if self.obs:
-            prof = getattr(self.obs, "profiler", None)
-            if prof is not None:
-                prof.tick()
-        finished = self._admit()
-        if not self.active:
+        """One engine iteration: admit, batched decode, retire, each part
+        in its phase span (:class:`StepSpans`)."""
+        sp = self.spans
+        finished: List[Request] = []
+        with sp.step:
+            self._admit(finished)
+            if self.active:
+                self._run_decode(finished)
             if self.obs:
-                self.obs.poll_jit()
-            return finished
-        token = np.zeros((self.slots, 1), dtype=np.int32)
-        for slot, req in self.active.items():
-            token[slot, 0] = req.generated[-1] if req.generated else 0
-        obs = self.obs
-        t0 = obs.now() if obs else 0.0
-        logits, self.cache = _profiled_call(
-            self.obs, "fixed.decode", self._decode,
-            self.params, jnp.asarray(token),
-            jnp.asarray(self.pos, jnp.int32), self.cache)
-        nxt = _sample_batch(logits[:, 0], list(self.active.items()),
-                            self.slots)
-        if obs:
-            t1 = obs.now()
-            obs.on_decode(list(self.active.items()), t0, t1)
-        for slot, req in list(self.active.items()):
-            tok = int(nxt[slot])
-            req.generated.append(tok)
-            self.pos[slot] += 1
-            if obs:
-                obs.on_tokens(req, 1, t1)
-            if (len(req.generated) >= req.max_new_tokens
-                    or (req.eos_id is not None and tok == req.eos_id)
-                    or self.pos[slot] >= self.max_len - 1):
-                req.done = True
-                req.state = SCH.DONE
-                finished.append(req)
-                del self.active[slot]
-                if obs:
-                    obs.on_finish(req)
-        if obs:
-            obs.poll_jit()
+                with sp.retire:
+                    self.obs.poll_jit()
         return finished
+
+    def _run_decode(self, finished: List[Request]) -> None:
+        obs, sp = self.obs, self.spans
+        rows = list(self.active.items())
+        with sp.decode:
+            token = np.zeros((self.slots, 1), dtype=np.int32)
+            for slot, req in rows:
+                token[slot, 0] = req.generated[-1] if req.generated else 0
+            t0 = obs.now() if obs else 0.0
+            logits, self.cache = self._decode(
+                self.params, jnp.asarray(token),
+                jnp.asarray(self.pos, jnp.int32), self.cache)
+            logits = logits[:, 0]
+        nxt = _sample_batch(logits, rows, self.slots, sp.sample,
+                            sp.tokens_after_decode)
+        with sp.retire:
+            if obs:
+                t1 = obs.now()
+                obs.on_decode(rows, t0, t1)
+            for slot, req in rows:
+                tok = int(nxt[slot])
+                req.generated.append(tok)
+                self.pos[slot] += 1
+                if obs:
+                    obs.on_tokens(req, 1, t1)
+                if (len(req.generated) >= req.max_new_tokens
+                        or (req.eos_id is not None and tok == req.eos_id)
+                        or self.pos[slot] >= self.max_len - 1):
+                    req.done = True
+                    req.state = SCH.DONE
+                    finished.append(req)
+                    del self.active[slot]
+                    if obs:
+                        obs.on_finish(req)
 
     def run_until_drained(self, max_steps: int = 10000) -> List[Request]:
         return _drain(self, max_steps)

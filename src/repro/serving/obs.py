@@ -8,7 +8,8 @@ Three cooperating pieces (see docs/observability.md for the catalogue):
     text-format exposition snapshot (:meth:`MetricsRegistry.to_prometheus`).
   * **Tracer** — per-request lifecycle spans
     (``queued → prefill[chunk i] → decode/spec-round → swapped →
-    finish|cancel``) with monotonic timestamps, exported as Chrome
+    finish|cancel``) and the engine's step phases (``serve.*``, see
+    :data:`STEP_PHASES`) with monotonic timestamps, exported as Chrome
     trace-event JSON (:meth:`Tracer.to_chrome`) loadable in Perfetto /
     ``chrome://tracing``.
   * **Recorder** — the engine-facing facade both feed through.  Engines,
@@ -54,8 +55,9 @@ from typing import Dict, List, Optional, Tuple
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "Tracer",
     "Recorder", "NullRecorder", "NULL_RECORDER", "SloThresholds",
-    "SloTracker", "log", "log_enabled", "summary_table", "slo_report",
-    "validate_prometheus", "validate_chrome_trace",
+    "SloTracker", "StepRecord", "STEP_SPAN", "STEP_PHASES", "log",
+    "log_enabled", "summary_table", "slo_report", "validate_prometheus",
+    "validate_chrome_trace",
 ]
 
 
@@ -117,7 +119,7 @@ class Counter:
 
 
 class Gauge:
-    """Point-in-time value (pool occupancy, fragmentation, ...)."""
+    """Point-in-time value (pool occupancy, ...)."""
 
     __slots__ = ("name", "labels", "value")
 
@@ -320,13 +322,13 @@ class Tracer:
     ``ph: i`` instants) on a monotonic clock.  ``tid`` is the request
     uid, so Perfetto renders one lane per request; engine-wide events
     (batched decode dispatches) go to the reserved ``tid 0`` lane, and
-    sampled kernel-profiler spans go to a dedicated ``kernels`` lane
-    (``KERNEL_TID``) so per-lane span-overlap validation keeps holding:
-    a profiled kernel span always nests inside the engine step span on
-    ``tid 0`` and would otherwise trip the overlap check."""
+    the engine's step phases to a ``steps`` lane of their own
+    (``STEP_TID``) so per-lane span-overlap validation keeps holding:
+    a phase overlaps the decode span on ``tid 0`` and would otherwise
+    trip the overlap check."""
 
     ENGINE_TID = 0
-    KERNEL_TID = 1_000_000_000  # far above any request uid + 1
+    STEP_TID = 1_000_000_000  # far above any request uid + 1
 
     def __init__(self, clock=time.perf_counter):
         self._clock = clock
@@ -342,8 +344,8 @@ class Tracer:
             self._named_tids.add(tid)
             if tid == self.ENGINE_TID:
                 name = "engine"
-            elif tid == self.KERNEL_TID:
-                name = "kernels"
+            elif tid == self.STEP_TID:
+                name = "steps"
             else:
                 name = f"req {tid - 1}"
             self.events.append({"ph": "M", "name": "thread_name",
@@ -636,15 +638,42 @@ class _ReqState:
     tokens: int
 
 
+STEP_SPAN = "serve.step"
+# the phases of an engine step, in the order they run; ``serve.tokens``
+# (the host blocked until a step's sampled tokens arrive) follows a final
+# prefill chunk and the decode, and says which in ``after``
+STEP_PHASES = ("serve.schedule", "serve.kv_move", "serve.prefill",
+               "serve.decode", "serve.sample", "serve.tokens",
+               "serve.retire")
+STEP_RING = 16384  # step records a recorder keeps, newest last
+
+
+@dataclasses.dataclass
+class StepRecord:
+    """One engine step on the recorder's clock: its start and end, and
+    each phase as ``(name, t0, t1, after)`` in the order they ran."""
+    __slots__ = ("num", "t0", "t1", "phases")
+    num: int
+    t0: float
+    t1: float
+    phases: List[Tuple[str, float, float, Optional[str]]]
+
+
 class Recorder:
     """Live recorder: every hook updates the registry and (when tracing
     is on) the tracer.  Pure host work around compiled programs — no
-    device syncs, no array reads, no effect on batch composition."""
+    device syncs, no array reads, no effect on batch composition.
+
+    ``steps`` is a ring of the last :data:`STEP_RING` engine steps
+    (:class:`StepRecord`), kept in memory for whoever reads them after
+    the run."""
 
     def __init__(self, *, trace: bool = True, clock=time.perf_counter):
         self._clock = clock
         self.registry = MetricsRegistry()
         self.tracer = Tracer(clock=clock) if trace else None
+        self.steps: deque = deque(maxlen=STEP_RING)
+        self._open_step: Optional[StepRecord] = None
         self._req: Dict[int, _ReqState] = {}
         self._jit_sites: List[list] = []  # [site, fn, last_cache_size]
         r = self.registry
@@ -672,9 +701,6 @@ class Recorder:
             "serve_pool_pages_used", "Page-pool pages in use")
         self._g_pool_free = r.gauge(
             "serve_pool_pages_free", "Page-pool pages free")
-        self._g_pool_frag = r.gauge(
-            "serve_pool_fragmentation",
-            "1 - longest contiguous free run / free pages")
         self._c_rollback = r.counter(
             "serve_pages_rollback_total",
             "Pages freed by speculative rollback")
@@ -748,11 +774,10 @@ class Recorder:
             "spec_emitted_total", "Tokens emitted by speculative rounds")
         # compiled-program cache
         self._jit_miss: Dict[str, Counter] = {}
-        # deep-observability attachments (PR 10): a QualityProbe /
-        # KernelProfiler set by the launcher; None keeps the recorder
-        # jax-free and the hooks no-ops.
+        # deep-observability attachment: a QualityProbe set by the
+        # launcher; None keeps the recorder jax-free and the hooks
+        # no-ops.
         self.quality = None
-        self.profiler = None
         self.slo = SloTracker(self.registry, clock=clock)
 
     # -- plumbing ----------------------------------------------------------
@@ -774,6 +799,7 @@ class Recorder:
         self.registry.reset()
         if self.tracer is not None:
             self.tracer.reset()
+        self.steps.clear()
         self._req.clear()
         self.slo.reset()
         for site in self._jit_sites:
@@ -858,6 +884,26 @@ class Recorder:
             self.tracer.instant(req.uid + 1, "cancel", ts)
 
     # -- step phases -------------------------------------------------------
+    def on_step_begin(self, num: int) -> None:
+        self._open_step = StepRecord(num, self.now(), 0.0, [])
+
+    def on_phase(self, name: str, t0: float, t1: float,
+                 after: Optional[str] = None) -> None:
+        """One phase of the open step (``serve.*``, :data:`STEP_PHASES`);
+        with tracing on, also a span on the ``steps`` lane."""
+        st = self._open_step
+        st.phases.append((name, t0, t1, after))
+        if self.tracer is not None:
+            args = {"step": st.num}
+            if after:
+                args["after"] = after
+            self.tracer.span(Tracer.STEP_TID, name, t0, t1, **args)
+
+    def on_step_end(self) -> None:
+        st, self._open_step = self._open_step, None
+        st.t1 = self.now()
+        self.steps.append(st)
+
     def on_prefill(self, req, chunk_index: int, n_tokens: int,
                    t0: float, t1: float) -> None:
         self._c_steps_prefill.inc()
@@ -909,22 +955,9 @@ class Recorder:
 
     # -- pool / allocator --------------------------------------------------
     def sample_pool(self, allocator) -> None:
-        """Gauge snapshot of the page pool: used/free and a fragmentation
-        score (1 - longest contiguous free run / free pages — 0 when the
-        free set is one run or empty)."""
-        free = allocator.free_pages()
+        """Gauge snapshot of the page pool: pages used and free."""
         self._g_pool_used.set(allocator.in_use)
-        self._g_pool_free.set(len(free))
-        frag = 0.0
-        if free:
-            longest = run = 1
-            prev = None
-            for p in sorted(free):
-                run = run + 1 if prev is not None and p == prev + 1 else 1
-                longest = max(longest, run)
-                prev = p
-            frag = 1.0 - longest / len(free)
-        self._g_pool_frag.set(frag)
+        self._g_pool_free.set(allocator.available)
 
     def on_alloc(self, n: int) -> None:
         self.registry.counter("alloc_pages_alloc_total",
@@ -1057,7 +1090,7 @@ _SUMMARY_CURATED = frozenset({
     "serve_decode_tokens_total", "serve_generated_tokens_total",
     "serve_ttft_seconds", "serve_tpot_seconds", "serve_itl_seconds",
     "serve_batch_occupancy", "serve_pool_pages_used",
-    "serve_pool_pages_free", "serve_pool_fragmentation",
+    "serve_pool_pages_free",
     "serve_swap_bytes_total", "serve_evicted_total",
     "serve_prefix_lookups_total", "serve_cached_prefix_tokens",
     "serve_prefix_reused_tokens_total", "serve_cow_clones_total",
@@ -1109,8 +1142,7 @@ def summary_table(registry: MetricsRegistry) -> str:
                      f"{occ.mean:.2f}  over {occ.count} steps"))
     rows.append(("page pool used/free",
                  f"{v('serve_pool_pages_used'):.0f} / "
-                 f"{v('serve_pool_pages_free'):.0f} "
-                 f"(frag {v('serve_pool_fragmentation'):.2f})"))
+                 f"{v('serve_pool_pages_free'):.0f}"))
     swap = (registry.value("serve_swap_bytes_total", direction="out")
             + registry.value("serve_swap_bytes_total", direction="in"))
     if swap:

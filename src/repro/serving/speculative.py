@@ -79,8 +79,7 @@ import numpy as np
 from repro.models import model as MD
 from repro.models.config import ModelConfig
 from repro.serving import sampling as S
-from repro.serving.engine import (ServeEngine, _profiled_call,
-                                  _splice_artifact)
+from repro.serving.engine import ServeEngine, _splice_artifact
 from repro.serving.kv_cache import HostKV, PagedKVCache
 from repro.serving.obs import Recorder
 from repro.serving.scheduler import Request
@@ -299,41 +298,20 @@ class SpeculativeEngine(ServeEngine):
             self._draft_host.pop(uid, None)
         return ok
 
-    def step(self) -> List[Request]:
-        """One engine iteration: swaps (both caches), copy-on-write clones
-        (both caches), at most one prefill chunk (both models), one
-        speculative draft+verify round."""
-        if self.obs:
-            prof = getattr(self.obs, "profiler", None)
-            if prof is not None:
-                prof.tick()
-        plan = self.sched.schedule()
-        for req, old_pages in plan.swap_out:
-            req.host_kv = self.kv.gather_host(old_pages)
-            self._draft_host[req.uid] = self.kv_draft.gather_host(old_pages)
-        for req in plan.swap_in:
-            self.kv.scatter_host(req.host_kv, req.pages)
-            req.host_kv = None
-            host_d = self._draft_host.pop(req.uid, None)
-            if host_d is not None:
-                self.kv_draft.scatter_host(host_d, req.pages)
-        for clone in plan.cow:
-            if clone.req.cow is None:
-                continue  # dropped: its request was evicted in this plan
-            self._clone_pages(clone.src, clone.dst)
-            self.sched.cow_executed(clone)
-
-        finished: List[Request] = []
-        if plan.prefill is not None:
-            self._run_prefill_chunk(plan.prefill, finished)
-        if plan.decode:
-            self._run_spec_round(plan.decode, finished)
-        if self.obs:
-            self.obs.sample_pool(self.kv.allocator)
-            self.obs.poll_jit()
-        return finished
-
     # -- internals ---------------------------------------------------------
+    # ``ServeEngine.step`` drives a round: swaps and copy-on-write clones
+    # (both caches), at most one prefill chunk (both models), and one
+    # speculative draft+verify round in place of the decode.
+    def _swap_out(self, req: Request, old_pages: List[int]) -> None:
+        super()._swap_out(req, old_pages)
+        self._draft_host[req.uid] = self.kv_draft.gather_host(old_pages)
+
+    def _swap_in(self, req: Request) -> None:
+        super()._swap_in(req)
+        host_d = self._draft_host.pop(req.uid, None)
+        if host_d is not None:
+            self.kv_draft.scatter_host(host_d, req.pages)
+
     def _clone_pages(self, src: int, dst: int) -> None:
         """COW must cover BOTH caches: target and draft share one page
         table, so a cloned page id must carry both models' prefix KV
@@ -347,100 +325,106 @@ class SpeculativeEngine(ServeEngine):
         first token comes from the target logits — the same computation,
         on the same arguments, as the plain engine's prefill, so it is
         bit-identical."""
-        logits, self.kv.buffers, self.kv_draft.buffers = _profiled_call(
-            self.obs, "spec.prefill_pair", self._prefill_pair,
+        logits, self.kv.buffers, self.kv_draft.buffers = self._prefill_pair(
             self.params, self.draft_params, jnp.asarray(toks),
             jnp.asarray(chunk.start, jnp.int32),
             jnp.asarray(chunk.n_valid, jnp.int32),
             jnp.asarray(page_row), self.kv.buffers, self.kv_draft.buffers)
         return logits
 
-    def _run_spec_round(self, decode, finished: List[Request]) -> None:
+    def _run_decode(self, decode, finished: List[Request]) -> None:
         """Draft k proposals, verify k+1 positions, rejection-sample the
         accepted prefix + correction/bonus token — all in one dispatch."""
-        k = self.spec_k
-        token = np.zeros((self.max_batch, 1), np.int32)
-        pos = np.zeros((self.max_batch,), np.int32)
-        n_valid = np.zeros((self.max_batch,), np.int32)
-        table = np.full((self.max_batch, self.max_pages_per_seq),
-                        self.kv.trash, np.int32)
-        for row, req in decode:
-            token[row, 0] = req.generated[-1]
-            pos[row] = req.next_pos
-            # window size: never verify past the request's token budget or
-            # the engine's max_len (position next_pos+n_valid-1 must stay
-            # a legal cache index AND every emitted token must be one the
-            # plain engine could also have emitted)
-            n_valid[row] = min(
-                k + 1,
-                req.max_new_tokens - len(req.generated),
-                self.max_len - len(req.prompt) - len(req.generated))
-            table[row, : len(req.pages)] = req.pages
-        seed, t0, temp, top_k, top_p = S.batch_rows(decode, self.max_batch)
-
-        obs = self.obs
-        tw0 = obs.now() if obs else 0.0
-        greedy = bool(np.all(temp <= 0.0))
-        if greedy:
-            # all-greedy batch (inactive rows default to T=0): the fast
-            # path skips the sampling machinery — same accepted/emit
-            # contract, bit-identical tokens
-            (accepted, emit, self.kv.buffers,
-             self.kv_draft.buffers) = _profiled_call(
-                self.obs, "spec.round_greedy", self._round_greedy,
-                self.params, self.draft_params, jnp.asarray(token),
-                jnp.asarray(pos), jnp.asarray(n_valid), jnp.asarray(table),
-                self.kv.buffers, self.kv_draft.buffers)
-        else:
-            (accepted, emit, self.kv.buffers,
-             self.kv_draft.buffers) = _profiled_call(
-                self.obs, "spec.round", self._round,
-                self.params, self.draft_params, jnp.asarray(token),
-                jnp.asarray(pos), jnp.asarray(n_valid), jnp.asarray(table),
-                jnp.asarray(seed), jnp.asarray(t0), jnp.asarray(temp),
-                jnp.asarray(top_k), jnp.asarray(top_p),
-                self.kv.buffers, self.kv_draft.buffers)
-        accepted = np.asarray(accepted)  # (B,)    accepted-prefix lengths
-        emit = np.asarray(emit)          # (B, k+1) tokens to emit per row
-        if obs:
-            # np.asarray above already pulled the round to host: tw1
-            # covers the real wall window without adding a sync
-            tw1 = obs.now()
-            obs.on_decode(decode, tw0, tw1, name="spec-round")
-            obs.on_spec_round("greedy" if greedy else "sampled")
-
-        for row, req in decode:
-            w = int(n_valid[row])
-            a = int(accepted[row])
-            req.spec_rounds += 1
-            req.spec_proposed += w - 1
-            # emit accepted proposals + the correction/bonus token,
-            # re-checking the budget after every token exactly like the
-            # plain engine's one-token steps (eos truncates the window)
-            emitted_n = 0
-            for tok in emit[row, : a + 1]:
-                req.generated.append(int(tok))
-                emitted_n += 1
-                if req.budget_reached(self.max_len):
-                    break
-            # truncation-aware accounting: an eos inside the window stops
-            # emission early, and only tokens that actually landed count —
-            # so `emitted == accepted + corrections + bonuses` holds by
-            # construction (the window's final token is the correction on
-            # rejection, the bonus draw on full acceptance)
-            acc_emitted = min(emitted_n, a)
-            final_emitted = emitted_n == a + 1
-            correction = 1 if final_emitted and a < w - 1 else 0
-            bonus = 1 if final_emitted and a == w - 1 else 0
-            req.spec_accepted += acc_emitted
-            if obs:
-                obs.on_spec_row(w - 1, acc_emitted, correction, bonus,
-                                emitted_n)
-                obs.on_tokens(req, emitted_n, tw1)
-            if req.budget_reached(self.max_len):
-                self.sched.retire(req)
-                finished.append(req)
+        obs, sp = self.obs, self.spans
+        with sp.decode:
+            k = self.spec_k
+            token = np.zeros((self.max_batch, 1), np.int32)
+            pos = np.zeros((self.max_batch,), np.int32)
+            n_valid = np.zeros((self.max_batch,), np.int32)
+            table = np.full((self.max_batch, self.max_pages_per_seq),
+                            self.kv.trash, np.int32)
+            for row, req in decode:
+                token[row, 0] = req.generated[-1]
+                pos[row] = req.next_pos
+                # window size: never verify past the request's token
+                # budget or the engine's max_len (position
+                # next_pos+n_valid-1 must stay a legal cache index AND
+                # every emitted token must be one the plain engine could
+                # also have emitted)
+                n_valid[row] = min(
+                    k + 1,
+                    req.max_new_tokens - len(req.generated),
+                    self.max_len - len(req.prompt) - len(req.generated))
+                table[row, : len(req.pages)] = req.pages
+            seed, t0, temp, top_k, top_p = S.batch_rows(decode,
+                                                        self.max_batch)
+            tw0 = obs.now() if obs else 0.0
+            greedy = bool(np.all(temp <= 0.0))
+            if greedy:
+                # all-greedy batch (inactive rows default to T=0): the
+                # fast path skips the sampling machinery — same
+                # accepted/emit contract, bit-identical tokens
+                (accepted, emit, self.kv.buffers,
+                 self.kv_draft.buffers) = self._round_greedy(
+                    self.params, self.draft_params, jnp.asarray(token),
+                    jnp.asarray(pos), jnp.asarray(n_valid),
+                    jnp.asarray(table), self.kv.buffers,
+                    self.kv_draft.buffers)
             else:
-                # positions past the new next_pos hold rejected-draft KV
-                # in both caches — free the pages backing only garbage
-                self.sched.rollback(req)
+                (accepted, emit, self.kv.buffers,
+                 self.kv_draft.buffers) = self._round(
+                    self.params, self.draft_params, jnp.asarray(token),
+                    jnp.asarray(pos), jnp.asarray(n_valid),
+                    jnp.asarray(table), jnp.asarray(seed),
+                    jnp.asarray(t0), jnp.asarray(temp), jnp.asarray(top_k),
+                    jnp.asarray(top_p), self.kv.buffers,
+                    self.kv_draft.buffers)
+        with sp.tokens_after_decode:
+            accepted = np.asarray(accepted)  # (B,) accepted-prefix lengths
+            emit = np.asarray(emit)          # (B, k+1) tokens to emit a row
+        with sp.retire:
+            if obs:
+                # np.asarray above already pulled the round to host: tw1
+                # covers the real wall window without adding a sync
+                tw1 = obs.now()
+                obs.on_decode(decode, tw0, tw1, name="spec-round")
+                obs.on_spec_round("greedy" if greedy else "sampled")
+
+            for row, req in decode:
+                w = int(n_valid[row])
+                a = int(accepted[row])
+                req.spec_rounds += 1
+                req.spec_proposed += w - 1
+                # emit accepted proposals + the correction/bonus token,
+                # re-checking the budget after every token exactly like
+                # the plain engine's one-token steps (eos truncates the
+                # window)
+                emitted_n = 0
+                for tok in emit[row, : a + 1]:
+                    req.generated.append(int(tok))
+                    emitted_n += 1
+                    if req.budget_reached(self.max_len):
+                        break
+                # truncation-aware accounting: an eos inside the window
+                # stops emission early, and only tokens that actually
+                # landed count — so `emitted == accepted + corrections +
+                # bonuses` holds by construction (the window's final token
+                # is the correction on rejection, the bonus draw on full
+                # acceptance)
+                acc_emitted = min(emitted_n, a)
+                final_emitted = emitted_n == a + 1
+                correction = 1 if final_emitted and a < w - 1 else 0
+                bonus = 1 if final_emitted and a == w - 1 else 0
+                req.spec_accepted += acc_emitted
+                if obs:
+                    obs.on_spec_row(w - 1, acc_emitted, correction, bonus,
+                                    emitted_n)
+                    obs.on_tokens(req, emitted_n, tw1)
+                if req.budget_reached(self.max_len):
+                    self.sched.retire(req)
+                    finished.append(req)
+                else:
+                    # positions past the new next_pos hold rejected-draft
+                    # KV in both caches — free the pages backing only
+                    # garbage
+                    self.sched.rollback(req)

@@ -4,10 +4,11 @@ The load-bearing property: recording is **observation only** — engines
 driven with a live :class:`~repro.serving.obs.Recorder` must emit token
 streams bit-identical to the same engines with recording off, through
 the paged, fixed-slot and speculative paths, including under
-page-pressure eviction.  PR 10 extends the same guarantee to the
-sampled deep-observability layers: the approximation-quality probe
-(``serving/quality.py``), the kernel profiler (``serving/profiler.py``)
-and the SLO health tracker must all leave streams bit-exact.  Plus the
+page-pressure eviction.  The same holds for the deep-observability
+layers — the approximation-quality probe (``serving/quality.py``) and
+the SLO health tracker — and for the engines' step spans with a
+``jax.profiler`` session collecting; the step records themselves must be
+well-formed.  Plus the
 subsystem's own contracts: the Prometheus exposition parses (hostile
 label values included), the Chrome trace is schema-valid with sorted
 non-overlapping spans per request lane, the ``NullRecorder`` default is
@@ -15,6 +16,7 @@ a guaranteed no-op, and ``REPRO_LOG`` drives the leveled logger.
 """
 import dataclasses
 import json
+from collections import deque
 
 import jax
 import numpy as np
@@ -22,12 +24,13 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import model as MD
-from repro.serving import (NULL_RECORDER, FixedSlotEngine, KernelProfiler,
+from repro.serving import (NULL_RECORDER, FixedSlotEngine,
                            MetricsRegistry, NullRecorder, QualityProbe,
                            Recorder, ServeEngine, SloThresholds, SloTracker,
                            SpeculativeEngine, load_engine, slo_report,
                            validate_chrome_trace, validate_prometheus)
-from repro.serving.obs import (Counter, Histogram, Tracer, log, log_enabled,
+from repro.serving.obs import (STEP_PHASES, STEP_RING, STEP_SPAN, Counter,
+                               Histogram, Tracer, log, log_enabled,
                                summary_table)
 
 PROMPTS = [[1, 2, 3], [7, 5], [9, 9, 9, 2], [4, 4, 1, 1, 5, 6, 7],
@@ -290,6 +293,12 @@ def test_trace_schema_from_engine_run(setup):
     assert {f"req {i}" for i in range(len(PROMPTS))} <= lanes
     names = {e["name"] for e in events if e["ph"] != "M"}
     assert {"queued", "prefill[0]", "decode", "finish"} <= names
+    # the step phases, on a lane of their own
+    assert "steps" in lanes
+    phases = {e["name"] for e in events
+              if e["ph"] == "X" and e["tid"] == Tracer.STEP_TID}
+    assert {"serve.schedule", "serve.kv_move", "serve.prefill",
+            "serve.decode", "serve.tokens", "serve.retire"} <= phases
     # the eviction workload leaves evict/swap marks in the trace
     assert any(n.startswith("evict[") for n in names)
     # Prometheus artifact from the same run parses too
@@ -327,6 +336,7 @@ def test_recorder_reset(setup):
     assert rec.registry.value("serve_requests_finished_total") == 0
     assert rec.registry.find("serve_ttft_seconds")[0].count == 0
     assert rec.to_chrome()["traceEvents"] == []
+    assert not rec.steps
     # warm-up compiles must not re-count as misses after the reset
     eng.submit([1, 2, 3], max_new_tokens=4)
     eng.run_until_drained()
@@ -545,85 +555,134 @@ def test_request_id_trace_instant():
 
 
 # ---------------------------------------------------------------------------
-# Kernel profiler: bit-exactness + artifacts.
+# Step spans: bit-exactness, well-formed step records, profiler trace.
 # ---------------------------------------------------------------------------
 
-
-def _rec_with_profiler(every=2, trace=True):
-    rec = Recorder(trace=trace)
-    rec.profiler = KernelProfiler(rec.registry, tracer=rec.tracer,
-                                  every=every)
-    return rec
+ENGINES = ["paged", "fixed", "speculative"]
 
 
-def test_profiler_rejects_bad_every():
-    with pytest.raises(ValueError, match="every"):
-        KernelProfiler(MetricsRegistry(), every=0)
-
-
-def test_paged_bitexact_with_profiler(setup):
-    """Profiler on (sampling every 2nd step, with tracer) vs off on the
-    eviction workload — streams bit-identical, and the profiled run
-    leaves per-site latency histograms, cost gauges and a ``kernels``
-    trace lane."""
-    cfg, params = setup
-    off, _ = _streams(lambda: ServeEngine(params, cfg, max_len=64,
-                                          **EVICT_KWARGS))
-    rec = _rec_with_profiler()
-    on, _ = _streams(lambda: ServeEngine(params, cfg, max_len=64,
-                                         recorder=rec, **EVICT_KWARGS))
-    assert on == off
-    assert rec.registry.value("kernel_profiled_steps_total") > 0
-    hists = rec.registry.find("kernel_latency_seconds")
-    assert hists and sum(h.count for h in hists) > 0
-    sites = {dict(h.labels)["site"] for h in hists}
-    assert "serve.decode" in sites
-    # cost analysis attributed FLOPs/bytes to the compiled decode program
-    assert rec.registry.value("kernel_flops", site="serve.decode") > 0
-    assert rec.registry.value("kernel_bytes", site="serve.decode") > 0
-    obj = rec.to_chrome()
-    assert validate_chrome_trace(obj) == []
-    lanes = {e["args"]["name"] for e in obj["traceEvents"]
-             if e["ph"] == "M"}
-    assert "kernels" in lanes
-    snap = rec.profiler.snapshot()
-    assert snap["sites"]["serve.decode"]["count"] > 0
-    assert snap["sites"]["serve.decode"]["p99_s"] >= 0
-
-
-def test_fixed_and_speculative_bitexact_with_profiler(setup):
-    cfg, params = setup
-    off_f, _ = _streams(lambda: FixedSlotEngine(params, cfg, slots=2,
-                                                max_len=64))
-    rec_f = _rec_with_profiler(trace=False)
-    on_f, _ = _streams(lambda: FixedSlotEngine(params, cfg, slots=2,
-                                               max_len=64, recorder=rec_f))
-    assert on_f == off_f
-    assert {dict(h.labels)["site"]
-            for h in rec_f.registry.find("kernel_latency_seconds")} \
-        == {"fixed.decode"}
-
+def _engine_factory(kind, params, cfg):
     def mk(recorder=None):
-        kw = dict(spec_k=3, max_batch=3, max_len=64, page_size=16,
-                  prefill_chunk=4)
-        if recorder is not None:
-            kw["recorder"] = recorder
-        return SpeculativeEngine(params, cfg, params, **kw)
+        kw = {} if recorder is None else {"recorder": recorder}
+        if kind == "paged":
+            return ServeEngine(params, cfg, max_len=64, **EVICT_KWARGS, **kw)
+        if kind == "fixed":
+            return FixedSlotEngine(params, cfg, slots=2, max_len=64, **kw)
+        return SpeculativeEngine(params, cfg, params, spec_k=3, max_batch=3,
+                                 max_len=64, page_size=16, prefill_chunk=4,
+                                 **kw)
+    return mk
 
-    off_s, _ = _streams(mk)
-    rec_s = _rec_with_profiler(trace=False)
-    on_s, _ = _streams(lambda: mk(rec_s))
-    assert on_s == off_s
-    sites = {dict(h.labels)["site"]
-             for h in rec_s.registry.find("kernel_latency_seconds")}
-    assert "spec.round_greedy" in sites
+
+@pytest.mark.parametrize("kind", ENGINES)
+def test_streams_bitexact_with_step_spans(setup, kind, tmp_path):
+    """Step spans recorded (a tracing recorder) with a CPU
+    ``jax.profiler`` session collecting, against spans off (the default
+    recorder, no session): streams bit-identical through each engine."""
+    cfg, params = setup
+    mk = _engine_factory(kind, params, cfg)
+    off, _ = _streams(mk)
+    rec = Recorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        on, _ = _streams(lambda: mk(rec))
+    finally:
+        jax.profiler.stop_trace()
+    assert on == off
+    assert rec.steps
+    assert validate_chrome_trace(rec.to_chrome()) == []
+
+
+@pytest.mark.parametrize("kind", ENGINES)
+def test_step_records_are_well_formed(setup, kind):
+    """Every step's phases are known, in order, inside their step; a
+    step's self time is never negative; a decode step waits for its
+    tokens exactly once after the decode; the ring keeps the newest."""
+    cfg, params = setup
+    rec = Recorder(trace=False)
+    assert rec.steps.maxlen == STEP_RING
+    rec.steps = deque(maxlen=6)  # a short ring, so the run overflows it
+    eng = _engine_factory(kind, params, cfg)(rec)
+    for p in PROMPTS:
+        eng.submit(p, max_new_tokens=8)
+    records = []
+    while eng.has_work:
+        eng.step()
+        records.append(rec.steps[-1])
+    n = len(records)
+    assert n > 6 and [s.num for s in rec.steps] == list(range(n - 5, n + 1))
+    assert [s.num for s in records] == list(range(1, n + 1))
+    seen = set()
+    for s in records:
+        names = [p[0] for p in s.phases]
+        seen.update(names)
+        assert set(names) <= set(STEP_PHASES)
+        assert names[0] == "serve.schedule"
+        prev = s.t0
+        for i, (name, t0, t1, after) in enumerate(s.phases):
+            assert prev <= t0 <= t1 <= s.t1, (s.num, name)
+            prev = t1
+            if name == "serve.tokens":
+                # the wait follows the dispatch it waits for
+                assert names[i - 1] in ("serve.sample", f"serve.{after}")
+            else:
+                assert after is None
+        waits = [(t1 - t0, after) for name, t0, t1, after in s.phases
+                 if name == "serve.tokens"]
+        assert s.t1 - s.t0 - sum(w for w, _ in waits) >= 0
+        assert [a for _, a in waits].count("decode") == (
+            "serve.decode" in names)
+    want = {"serve.schedule", "serve.prefill", "serve.decode",
+            "serve.tokens", "serve.retire"}
+    if kind != "speculative":
+        want.add("serve.sample")  # the round samples inside its program
+    if kind == "paged":
+        want.add("serve.kv_move")  # the eviction workload swaps
+    assert want <= seen
+
+
+def test_profiler_trace_holds_step_spans(setup, tmp_path):
+    """A CPU ``jax.profiler`` trace of a few engine steps, taken as
+    ``launch/serve.py --profile-dir`` takes it and read back with
+    ``ProfileData``: ``serve.step`` host events numbered by the engine's
+    step counter, each holding ``serve.*`` phases, and no phase outside
+    a step."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from repro.launch.serve import drain_profiled
+
+    cfg, params = setup
+    eng = ServeEngine(params, cfg, max_len=64, **EVICT_KWARGS)
+    handles = [eng.submit(p, max_new_tokens=8) for p in PROMPTS]
+    done = drain_profiled(eng, handles, str(tmp_path), 4)
+    assert len(done) == len(PROMPTS)
+    (pb,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    steps, phases = [], []
+    for plane in ProfileData.from_file(pb).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                span = (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                if ev.name == STEP_SPAN:
+                    steps.append((dict(ev.stats)["step_num"],) + span)
+                elif ev.name.startswith("serve."):
+                    phases.append(span)
+    nums = sorted(n for n, *_ in steps)
+    assert len(nums) == 4 and nums == list(range(nums[0], nums[0] + 4))
+    assert {"serve.schedule", "serve.decode", "serve.sample",
+            "serve.tokens", "serve.retire"} <= {n for n, _, _ in phases}
+    for name, s, e in phases:
+        assert any(s0 <= s and e <= e0 for _, _, s0, e0 in steps), name
+    for _, _, s0, e0 in steps:
+        assert any(s0 <= s and e <= e0 for _, s, e in phases)
 
 
 def test_dispatch_hook_counts_compiled_programs():
     """``attach_dispatch_hook`` counts LUT-MU backend selections on
     static call metadata; detach stops the counting."""
     from repro.kernels import dispatch as D
-    from repro.serving.profiler import attach_dispatch_hook
+    from repro.kernels.dispatch import attach_dispatch_hook
 
     rng = np.random.default_rng(0)
     c, depth, d_sub, n = 2, 2, 4, 3
