@@ -223,20 +223,29 @@ class StepSpans:
         self.retire = _Phase(obs, "serve.retire")
 
 
-def _sample_batch(logits, rows_reqs, batch: int, sample_span,
+def _sample_batch(obs, logits, rows_reqs, batch: int, sample_span,
                   tokens_span) -> np.ndarray:
     """Draw each row's next token through the per-request sampler.
 
     ``logits (batch, V)`` + ``(row, request)`` pairs → ``(batch,)`` int32
-    on host.  Greedy requests (T=0, the default) reduce to ``argmax``
-    bit-exactly inside the same jitted program; rows not listed default
-    to greedy and their samples are discarded by the caller.  Shared by
-    every engine so sampling semantics cannot drift between them.  The
-    dispatch runs in ``sample_span``, the wait for the tokens in
-    ``tokens_span``."""
+    on host.  A batch whose every row is greedy (T=0, the default; rows
+    not listed default to greedy and their samples are discarded by the
+    caller) takes the argmax program ``greedy_tokens_jit``; any other
+    batch takes ``sample_tokens_jit``, whose T=0 rows reduce to the same
+    argmax bit-exactly.  The choice is made on the host arrays
+    ``batch_rows`` builds, with no device sync, as the speculative
+    engine chooses its round.  Shared by every engine so sampling
+    semantics cannot drift between them.  The dispatch runs in
+    ``sample_span``, the wait for the tokens in ``tokens_span``."""
     with sample_span:
         seed, t, temp, top_k, top_p = S.batch_rows(rows_reqs, batch)
-        toks = S.sample_tokens_jit(logits, seed, t, temp, top_k, top_p)
+        greedy = bool(np.all(temp <= 0.0))
+        if greedy:
+            toks = S.greedy_tokens_jit(logits)
+        else:
+            toks = S.sample_tokens_jit(logits, seed, t, temp, top_k, top_p)
+        if obs:
+            obs.on_sample("greedy" if greedy else "sampled")
     with tokens_span:
         return np.asarray(toks)
 
@@ -364,6 +373,8 @@ class ServeEngine:
             self.obs.register_jit_site("serve.prefill", self._prefill)
             self.obs.register_jit_site("sampling.sample_tokens",
                                        S.sample_tokens_jit)
+            self.obs.register_jit_site("sampling.greedy_tokens",
+                                       S.greedy_tokens_jit)
             _bind_quality(self.obs, self.params, self.cfg)
 
     # -- construction ------------------------------------------------------
@@ -523,7 +534,7 @@ class ServeEngine:
                                chunk.n_valid, t0, obs.now())
         if not final:
             return
-        nxt = _sample_batch(logits, [(0, req)], 1, sp.sample,
+        nxt = _sample_batch(obs, logits, [(0, req)], 1, sp.sample,
                             sp.tokens_after_prefill)
         with sp.retire:
             req.generated.append(int(nxt[0]))
@@ -555,7 +566,7 @@ class ServeEngine:
                 self.params, jnp.asarray(token), jnp.asarray(pos),
                 jnp.asarray(table), self.kv.buffers)
             logits = logits[:, 0]
-        nxt = _sample_batch(logits, decode, self.max_batch, sp.sample,
+        nxt = _sample_batch(obs, logits, decode, self.max_batch, sp.sample,
                             sp.tokens_after_decode)
         with sp.retire:
             if obs:
@@ -632,6 +643,8 @@ class FixedSlotEngine:
             self.obs.register_jit_site("fixed.decode", self._decode)
             self.obs.register_jit_site("sampling.sample_tokens",
                                        S.sample_tokens_jit)
+            self.obs.register_jit_site("sampling.greedy_tokens",
+                                       S.greedy_tokens_jit)
             _bind_quality(self.obs, self.params, self.cfg)
 
     # -- construction ------------------------------------------------------
@@ -721,7 +734,7 @@ class FixedSlotEngine:
                     else full, self.cache, cache1)
                 spliced = True
                 logits = logits[0, -1:]
-            nxt = _sample_batch(logits, [(0, req)], 1, sp.sample,
+            nxt = _sample_batch(obs, logits, [(0, req)], 1, sp.sample,
                                 sp.tokens_after_prefill)
             with sp.retire:
                 req.generated.append(int(nxt[0]))
@@ -776,7 +789,7 @@ class FixedSlotEngine:
                 self.params, jnp.asarray(token),
                 jnp.asarray(self.pos, jnp.int32), self.cache)
             logits = logits[:, 0]
-        nxt = _sample_batch(logits, rows, self.slots, sp.sample,
+        nxt = _sample_batch(obs, logits, rows, self.slots, sp.sample,
                             sp.tokens_after_decode)
         with sp.retire:
             if obs:

@@ -741,6 +741,12 @@ class Recorder:
             "serve_steps_total", "Engine step phases", kind="decode")
         self._c_steps_spec = r.counter(
             "serve_steps_total", "Engine step phases", kind="spec")
+        self._c_sample_greedy = r.counter(
+            "serve_sample_calls_total", "Sampler dispatches by program",
+            path="greedy")
+        self._c_sample_sampled = r.counter(
+            "serve_sample_calls_total", "Sampler dispatches by program",
+            path="sampled")
         self._h_occupancy = r.histogram(
             "serve_batch_occupancy", "Decode rows active per batched step",
             buckets=OCCUPANCY_BUCKETS)
@@ -926,6 +932,12 @@ class Recorder:
                              rows=len(rows_reqs))
             for _row, req in rows_reqs:
                 self.tracer.span(req.uid + 1, name, t0, t1)
+
+    def on_sample(self, path: str) -> None:
+        """One sampler dispatch of a plain engine: ``path="greedy"`` for
+        the argmax program of an all-greedy batch, else ``"sampled"``."""
+        (self._c_sample_greedy if path == "greedy"
+         else self._c_sample_sampled).inc()
 
     def on_tokens(self, req, n: int, ts: float, *,
                   source: str = "decode") -> None:

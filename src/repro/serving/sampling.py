@@ -13,11 +13,14 @@ distributional harness in ``tests/dist_check.py``):
     not on batch composition, admission order, or page-fault
     eviction/host-swap (the counter is just ``len(req.generated)``,
     which swaps trivially);
-  * **greedy is the T=0 special case** — ``temperature == 0`` routes
-    through the same code path but produces a one-hot distribution at
+  * **greedy is the T=0 special case** — a ``temperature == 0`` row of
+    :func:`sample_tokens` gets a one-hot distribution at
     ``argmax(logits)``, and the exact inverse-CDF sampler maps *any*
     uniform to that argmax, so T=0 streams are bit-identical to the
-    historical argmax engines (``tests/test_serving_golden.py``);
+    historical argmax engines (``tests/test_serving_golden.py``) even
+    beside sampled rows.  A batch whose every row is greedy gets the
+    same tokens from :func:`greedy_tokens`, one argmax with none of the
+    transforms, which is what the engines dispatch for it;
   * **speculative correctness** — :func:`speculative_accept` implements
     the standard rejection-sampling correction (accept draft token ``x``
     with probability ``min(1, p(x)/q(x))``, resample from the normalised
@@ -205,6 +208,18 @@ def sample_tokens(logits: Array, seed: Array, t: Array, temperature: Array,
 
 
 sample_tokens_jit = jax.jit(sample_tokens)
+
+
+def greedy_tokens(logits: Array) -> Array:
+    """The tokens of an all-greedy batch: ``logits (B, V)`` → ``(B,)``
+    int32 ``argmax``, ties to the lower vocab id.  Bit-identical to
+    :func:`sample_tokens` when every row has T=0, whatever the rows'
+    seed, top-k and top-p: that is the same argmax, taken without the
+    sorts and the softmax whose result a T=0 row discards."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+greedy_tokens_jit = jax.jit(greedy_tokens)
 
 
 def batch_rows(rows_reqs: List[Tuple[int, object]], batch: int):

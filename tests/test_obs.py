@@ -26,9 +26,10 @@ from repro.configs import get_config
 from repro.models import model as MD
 from repro.serving import (NULL_RECORDER, FixedSlotEngine,
                            MetricsRegistry, NullRecorder, QualityProbe,
-                           Recorder, ServeEngine, SloThresholds, SloTracker,
-                           SpeculativeEngine, load_engine, slo_report,
-                           validate_chrome_trace, validate_prometheus)
+                           Recorder, SamplingParams, ServeEngine,
+                           SloThresholds, SloTracker, SpeculativeEngine,
+                           load_engine, slo_report, validate_chrome_trace,
+                           validate_prometheus)
 from repro.serving.obs import (STEP_PHASES, STEP_RING, STEP_SPAN, Counter,
                                Histogram, Tracer, log, log_enabled,
                                summary_table)
@@ -591,6 +592,78 @@ def test_streams_bitexact_with_step_spans(setup, kind, tmp_path):
     assert on == off
     assert rec.steps
     assert validate_chrome_trace(rec.to_chrome()) == []
+
+
+SAMPLED = SamplingParams(temperature=0.8, top_k=8, top_p=0.9, seed=77)
+
+
+@pytest.mark.parametrize("workload", ["greedy", "one_sampled"])
+@pytest.mark.parametrize("kind", ENGINES[:2])
+def test_sample_calls_by_path(setup, kind, workload):
+    """Every sampler call of a plain engine counts once, by program: an
+    all-greedy workload only ``path="greedy"``; a sampled request
+    ``path="sampled"`` on each step it is in the batch (its final prefill
+    chunk and each decode step after it, one token each), and the greedy
+    streams beside it stay those of the all-greedy run."""
+    cfg, params = setup
+    mk = _engine_factory(kind, params, cfg)
+    greedy_streams, _ = _streams(mk)
+    rec = Recorder(trace=False)
+    eng = mk(rec)
+    sreq = (eng.submit([2, 7, 1], max_new_tokens=8, sampling=SAMPLED)
+            if workload == "one_sampled" else None)
+    reqs = [eng.submit(p, max_new_tokens=8) for p in PROMPTS]
+    eng.run_until_drained()
+    assert [list(r.generated) for r in reqs] == greedy_streams
+    v = rec.registry.value
+    greedy = v("serve_sample_calls_total", path="greedy")
+    sampled = v("serve_sample_calls_total", path="sampled")
+    n_reqs = len(PROMPTS) + (sreq is not None)
+    # one call a decode step, one after each request's final prefill chunk
+    assert greedy + sampled == (v("serve_steps_total", kind="decode")
+                                + n_reqs)
+    assert sampled == (0 if sreq is None else len(sreq.generated))
+    assert greedy > 0
+
+
+@pytest.mark.parametrize("temps", [(0.0, 0.0, 0.0), (0.0, 0.7, 0.0)],
+                         ids=["all_greedy", "one_sampled"])
+def test_sample_batch_dispatch(monkeypatch, temps):
+    """``_sample_batch`` dispatches the argmax program, with the logits
+    alone, on an all-greedy batch (an unlisted row included), and
+    otherwise ``sample_tokens_jit`` with the arrays ``batch_rows`` makes."""
+    from contextlib import nullcontext
+    from types import SimpleNamespace
+
+    from repro.serving import engine as E
+    from repro.serving import sampling as S
+
+    calls = []
+    for name in ("greedy_tokens_jit", "sample_tokens_jit"):
+        real = getattr(S, name)
+        monkeypatch.setattr(S, name, lambda *a, _n=name, _f=real: (
+            calls.append((_n, a)) or _f(*a)))
+    logits = jax.random.normal(jax.random.PRNGKey(3), (4, 64))
+    rows = [(row, SimpleNamespace(
+        generated=[1] * row,
+        sampling=SamplingParams(temperature=temp, top_k=row, top_p=0.5,
+                                seed=row + 9)))
+        for row, temp in enumerate(temps)]
+    rec = Recorder(trace=False)
+    toks = E._sample_batch(rec, logits, rows, 4, nullcontext(),
+                           nullcontext())
+    (name, args), = calls
+    if max(temps) == 0:
+        assert name == "greedy_tokens_jit" and args == (logits,)
+        np.testing.assert_array_equal(toks, np.argmax(logits, axis=-1))
+    else:
+        assert name == "sample_tokens_jit" and args[0] is logits
+        for got, want in zip(args[1:], S.batch_rows(rows, 4)):
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(got, want)
+    path = "greedy" if max(temps) == 0 else "sampled"
+    assert rec.registry.value("serve_sample_calls_total", path=path) == 1
+    assert rec.registry.sum_values("serve_sample_calls_total") == 1
 
 
 @pytest.mark.parametrize("kind", ENGINES)

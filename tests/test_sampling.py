@@ -280,6 +280,58 @@ def test_t0_is_argmax_with_ties():
         assert int(tok[0]) == 1
 
 
+def _greedy_case(name):
+    """``(logits, seed, t, top_k, top_p)`` of one all-greedy batch."""
+    rng = np.random.default_rng(14)
+    b, v = (1, 4099) if name == "batch1" else (32, 4099)
+    logits = rng.standard_normal((b, v)).astype(np.float32)
+    seed = np.zeros(b, np.uint32)
+    t = np.zeros(b, np.int32)
+    top_k = np.zeros(b, np.int32)
+    top_p = np.ones(b, np.float32)
+    if name == "ties":  # the top logit repeated, lowest id wins
+        logits[:, [5, 9, 4000]] = 50.0
+        logits[1, 3] = 50.0
+    elif name == "equal_rows":  # every logit of a row equal
+        logits[0] = 0.0
+        logits[1] = -3.5
+        logits[2] = np.finfo(np.float32).max
+    elif name == "inf_and_large":
+        logits[0, ::2] = -np.inf  # half the vocab masked
+        logits[1] = -np.inf       # a whole row masked
+        logits[2, 17] = np.inf
+        logits[3, [7, 70]] = np.finfo(np.float32).max
+        logits[4] *= 1e30
+        logits[5, 100] = -np.inf
+    elif name == "inactive_rows":  # rows a caller left with stale params
+        seed[:] = rng.integers(1, 2**32, b, dtype=np.uint32)
+        t[:] = rng.integers(0, 1000, b)
+        top_k[:] = rng.integers(1, 50, b)
+        top_p[:] = rng.uniform(0.05, 0.95, b).astype(np.float32)
+    return logits, seed, t, top_k, top_p
+
+
+@pytest.mark.parametrize("name", ["batch1", "batch32", "ties", "equal_rows",
+                                  "inf_and_large", "inactive_rows"])
+def test_greedy_tokens_match_sample_tokens(name):
+    """The engines' all-greedy program (one argmax) returns, bit for bit,
+    what the full sampler returns when every row has T=0, whatever the
+    rows' seed, emission index, top-k and top-p."""
+    logits, seed, t, top_k, top_p = _greedy_case(name)
+    temp = np.zeros(len(logits), np.float32)
+    full = np.asarray(S.sample_tokens_jit(logits, seed, t, temp, top_k,
+                                          top_p))
+    fast = np.asarray(S.greedy_tokens_jit(logits))
+    assert fast.dtype == full.dtype == np.int32
+    assert fast.shape == full.shape == (len(logits),)
+    np.testing.assert_array_equal(fast, full)
+    np.testing.assert_array_equal(fast, np.asarray(S.greedy_tokens(logits)))
+    if name == "ties":
+        assert fast[0] == 5 and fast[1] == 3
+    if name == "equal_rows":
+        assert list(fast[:3]) == [0, 0, 0]
+
+
 def test_top_k_corner_grid():
     for k in range(0, 9):
         out = np.asarray(S.apply_top_k(jnp.asarray(TIE_LOGITS), jnp.int32(k)))

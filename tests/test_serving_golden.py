@@ -28,11 +28,12 @@ PROMPTS = [[1, 2, 3], [7, 5], [9, 9, 9, 2], [4, 4, 1, 1, 5, 6, 7],
 MAX_NEW = 8
 
 
-def _decode_streams(tmp_path):
+def _tiny_artifact(out):
+    """Compile the deterministic tiny LM artifact into ``out``; returns
+    ``(params, cfg, compile result)``."""
     from repro.compiler import compile_lm_amm
     from repro.configs import get_config
     from repro.models import model as MD
-    from repro.serving import ServeEngine
 
     cfg = get_config("qwen3-14b", reduced=True)
     cfg = dataclasses.replace(cfg, num_layers=2, d_model=64, d_ff=128,
@@ -42,9 +43,15 @@ def _decode_streams(tmp_path):
         cfg, amm=dataclasses.replace(cfg.amm, enabled=True))  # int8 LUTs
     params = MD.init_params(cfg, jax.random.PRNGKey(0))
     calib_tokens = np.random.default_rng(0).integers(0, 64, (4, 16))
-    out = tmp_path / "lm_art"
-    compile_lm_amm(params, cfg, calib_tokens, out=str(out))
+    res = compile_lm_amm(params, cfg, calib_tokens, out=str(out))
+    return params, cfg, res
 
+
+def _decode_streams(tmp_path):
+    from repro.serving import ServeEngine
+
+    out = tmp_path / "lm_art"
+    params, cfg, _ = _tiny_artifact(out)
     eng = ServeEngine.from_artifact(out, params, cfg, max_batch=2,
                                     max_len=64, page_size=16,
                                     prefill_chunk=4)
@@ -70,14 +77,11 @@ def test_golden_token_streams(tmp_path):
 
 
 def test_golden_t0_bitexact_across_all_engines(tmp_path):
-    """Greedy is the T=0 special case of sampling, not a separate code
-    path — so an explicit ``SamplingParams(temperature=0)`` (with a
-    non-zero seed and active-looking top-k/top-p, all of which greedy
-    must ignore) has to reproduce the golden streams bit-identically
-    through ALL three engines: paged, fixed-slot, and speculative."""
-    from repro.compiler import compile_lm_amm
-    from repro.configs import get_config
-    from repro.models import model as MD
+    """Greedy is the T=0 special case of sampling — so an explicit
+    ``SamplingParams(temperature=0)`` (with a non-zero seed and
+    active-looking top-k/top-p, all of which greedy must ignore) has to
+    reproduce the golden streams bit-identically through ALL three
+    engines: paged, fixed-slot, and speculative."""
     from repro.serving import (FixedSlotEngine, SamplingParams, ServeEngine,
                                SpeculativeEngine)
 
@@ -85,16 +89,8 @@ def test_golden_t0_bitexact_across_all_engines(tmp_path):
         pytest.skip("golden file not generated yet")
     golden = json.loads(GOLDEN_PATH.read_text())
 
-    cfg = get_config("qwen3-14b", reduced=True)
-    cfg = dataclasses.replace(cfg, num_layers=2, d_model=64, d_ff=128,
-                              vocab_size=64, num_heads=2, num_kv_heads=1,
-                              head_dim=32)
-    cfg = dataclasses.replace(
-        cfg, amm=dataclasses.replace(cfg.amm, enabled=True))
-    params = MD.init_params(cfg, jax.random.PRNGKey(0))
-    calib_tokens = np.random.default_rng(0).integers(0, 64, (4, 16))
     out = tmp_path / "lm_art"
-    res = compile_lm_amm(params, cfg, calib_tokens, out=str(out))
+    params, cfg, res = _tiny_artifact(out)
 
     # T=0 must make seed/top_k/top_p inert: give them loud values
     t0 = SamplingParams(temperature=0.0, top_k=3, top_p=0.5, seed=1234)
@@ -116,3 +112,37 @@ def test_golden_t0_bitexact_across_all_engines(tmp_path):
         assert streams == golden, (
             f"{name} engine at temperature=0 drifted from the golden "
             f"greedy streams")
+
+
+@pytest.mark.parametrize("kind", ["paged", "fixed"])
+def test_golden_streams_through_both_sampler_programs(tmp_path, kind):
+    """The plain engines sample an all-greedy batch with one argmax
+    (``greedy_tokens``) and any other batch with the full sampler: the
+    golden greedy streams come out of both.  Run alone, the golden
+    prompts take only the argmax program; beside one sampled request,
+    their rows also go through the full sampler and must not move."""
+    from repro.serving import Recorder, SamplingParams, load_engine
+
+    if not GOLDEN_PATH.is_file():
+        pytest.skip("golden file not generated yet")
+    golden = json.loads(GOLDEN_PATH.read_text())
+    out = tmp_path / "lm_art"
+    params, cfg, _ = _tiny_artifact(out)
+    sampled = SamplingParams(temperature=0.9, top_k=5, top_p=0.8, seed=4)
+
+    for with_sampled in (False, True):
+        rec = Recorder(trace=False)
+        opts = (dict(max_batch=2, page_size=16, prefill_chunk=4)
+                if kind == "paged" else dict(slots=2))
+        eng = load_engine(out, params, cfg, engine=kind, max_len=64,
+                          recorder=rec, **opts)
+        if with_sampled:
+            eng.submit([3, 1, 4], max_new_tokens=MAX_NEW, sampling=sampled)
+        reqs = [eng.submit(p, max_new_tokens=MAX_NEW) for p in PROMPTS]
+        eng.run_until_drained()
+        streams = {",".join(map(str, r.prompt)): r.generated for r in reqs}
+        assert streams == golden, (kind, with_sampled)
+        v = rec.registry.value
+        assert v("serve_sample_calls_total", path="greedy") > 0
+        assert (v("serve_sample_calls_total", path="sampled") > 0) == \
+            with_sampled
