@@ -41,6 +41,7 @@ stochastic regime is pinned distributionally by ``tests/test_sampling.py``
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import warnings
@@ -67,6 +68,7 @@ from repro.serving.sampling import SamplingParams
 from repro.serving.scheduler import Request, Scheduler
 
 Array = jax.Array
+_NO_SPAN = contextlib.nullcontext()
 
 # loose sampling kwargs `submit` still accepts one release behind a
 # DeprecationWarning (pass a frozen SamplingParams instead)
@@ -223,20 +225,20 @@ class StepSpans:
         self.retire = _Phase(obs, "serve.retire")
 
 
-def _sample_batch(obs, logits, rows_reqs, batch: int, sample_span,
-                  tokens_span) -> np.ndarray:
+def _sample_batch(obs, logits, rows_reqs, batch: int, sample_span=_NO_SPAN):
     """Draw each row's next token through the per-request sampler.
 
-    ``logits (batch, V)`` + ``(row, request)`` pairs → ``(batch,)`` int32
-    on host.  A batch whose every row is greedy (T=0, the default; rows
-    not listed default to greedy and their samples are discarded by the
-    caller) takes the argmax program ``greedy_tokens_jit``; any other
-    batch takes ``sample_tokens_jit``, whose T=0 rows reduce to the same
-    argmax bit-exactly.  The choice is made on the host arrays
-    ``batch_rows`` builds, with no device sync, as the speculative
-    engine chooses its round.  Shared by every engine so sampling
-    semantics cannot drift between them.  The dispatch runs in
-    ``sample_span``, the wait for the tokens in ``tokens_span``."""
+    ``logits (batch, V)`` + ``(row, request)`` pairs → ``(batch,)`` int32,
+    left on the device.  A batch whose every row is greedy (T=0, the
+    default; rows not listed default to greedy and their samples are
+    discarded by the caller) takes the argmax program
+    ``greedy_tokens_jit``; any other batch takes ``sample_tokens_jit``,
+    whose T=0 rows reduce to the same argmax bit-exactly.  The choice is
+    made on the host arrays ``batch_rows`` builds, with no device sync,
+    as the speculative engine chooses its round.  Shared by every engine
+    so sampling semantics cannot drift between them.  The dispatch runs
+    in ``sample_span``; the caller reads the tokens in its own
+    ``serve.tokens`` span."""
     with sample_span:
         seed, t, temp, top_k, top_p = S.batch_rows(rows_reqs, batch)
         greedy = bool(np.all(temp <= 0.0))
@@ -246,8 +248,25 @@ def _sample_batch(obs, logits, rows_reqs, batch: int, sample_span,
             toks = S.sample_tokens_jit(logits, seed, t, temp, top_k, top_p)
         if obs:
             obs.on_sample("greedy" if greedy else "sampled")
-    with tokens_span:
-        return np.asarray(toks)
+    return toks
+
+
+@dataclasses.dataclass
+class _Decoded:
+    """A batched decode whose sampled tokens are still on the device:
+    its ``(row, request)`` pairs, the ``(max_batch,)`` tokens, and the
+    host stamp of its dispatch."""
+    rows: List
+    tokens: Array
+    t0: float
+
+
+@dataclasses.dataclass
+class _FirstToken:
+    """A final prefill chunk whose first token is still on the device."""
+    chunk: SCH.PrefillChunk
+    tokens: Array
+    t0: float
 
 
 def _bind_quality(obs, params, cfg: ModelConfig) -> None:
@@ -339,7 +358,7 @@ class ServeEngine:
         if mesh is None:
             self._constrain = MD._id
             self.params = params
-            jit_d, jit_p = {}, {}
+            jit_d, jit_p, jit_t = {}, {}, {}
         else:
             self._constrain = make_constrainer(cfg, mesh)
             p_sh = param_shardings(_shape_tree(params), cfg, mesh)
@@ -354,6 +373,7 @@ class ServeEngine:
                      "out_shardings": (None, c_sh)}
             jit_p = {"in_shardings": (p_sh, rep, rep, rep, rep, c_sh),
                      "out_shardings": (None, c_sh)}
+            jit_t = {"out_shardings": tok_sh}
         constrain = self._constrain
 
         def _decode(params, token, pos_vec, page_table, cache):
@@ -366,10 +386,23 @@ class ServeEngine:
                 params, tokens, start, n_valid, page_row, cache, cfg,
                 constrain=constrain, compute_dtype=compute_dtype)
 
+        def _merge_tokens(sampled, host):
+            # a row whose host token is -1 continues from the decode in
+            # flight: its input is that decode's sampled token.  (Named
+            # apart from ``_decode``: device-trace readers find the decode
+            # program by the substring ``jit__decode``.)
+            return jnp.where(host < 0, sampled[:, None], host)
+
         self._decode = jax.jit(_decode, donate_argnums=(4,), **jit_d)
         self._prefill = jax.jit(_prefill, donate_argnums=(5,), **jit_p)
+        self._merge_tokens = jax.jit(_merge_tokens, **jit_t)
+        # the decode whose tokens are still on the device
+        self._inflight: Optional[_Decoded] = None
+        self._landed_at = 0.0  # host stamp of the last decode that landed
         if self.obs:
             self.obs.register_jit_site("serve.decode", self._decode)
+            self.obs.register_jit_site("serve.decode_tokens",
+                                       self._merge_tokens)
             self.obs.register_jit_site("serve.prefill", self._prefill)
             self.obs.register_jit_site("sampling.sample_tokens",
                                        S.sample_tokens_jit)
@@ -432,7 +465,8 @@ class ServeEngine:
 
     @property
     def has_work(self) -> bool:
-        return bool(self.sched.live())
+        # a decode in flight is work: its tokens have yet to land
+        return bool(self.sched.live()) or self._inflight is not None
 
     async def _advance_async(self) -> None:
         await _step_engine_async(self)
@@ -446,24 +480,36 @@ class ServeEngine:
     def step(self) -> List[Request]:
         """One engine iteration: execute the scheduler's plan — swap-outs,
         swap-ins, copy-on-write clones, at most one prefill chunk, one
-        batched decode — and retire finished requests, each part in its
-        phase span (:class:`StepSpans`)."""
+        batched decode — then land the tokens sampled before this step's
+        decode and retire finished requests, each part in its phase span
+        (:class:`StepSpans`).
+
+        One decode stays in flight: the decode queued here takes the
+        previous step's sampled tokens on the device, and the host reads
+        those tokens only once this decode is queued behind them, so the
+        host's work overlaps the device's."""
         sp = self.spans
         finished: List[Request] = []
         with sp.step:
             with sp.schedule:
                 plan = self.sched.schedule()
             if plan.swap_out or plan.swap_in or plan.cow:
+                # the device orders these after the decode in flight:
+                # they act on the cache buffers it returns
                 with sp.kv_move:
                     self._move_kv(plan)
-            if plan.prefill is not None:
-                self._run_prefill_chunk(plan.prefill, finished)
+            landing, self._inflight = self._inflight, None
+            first = (self._run_prefill_chunk(plan.prefill, finished)
+                     if plan.prefill is not None else None)
             if plan.decode:
-                self._run_decode(plan.decode, finished)
-            if self.obs:
-                with sp.retire:
-                    self.obs.sample_pool(self.kv.allocator)
-                    self.obs.poll_jit()
+                self._inflight = self._run_decode(plan.decode, landing,
+                                                  finished)
+            self._land(landing, first, finished)
+            if self._inflight is not None:
+                # counted only now, so the landing's hooks saw each
+                # request as the landing decode left it
+                for _, req in self._inflight.rows:
+                    req.inflight += 1
         return finished
 
     def run_until_drained(self, max_steps: int = 10000) -> List[Request]:
@@ -504,8 +550,9 @@ class ServeEngine:
         """Run the jitted prefill program(s) for one chunk and return the
         target logits.  The ONLY prefill behaviour subclasses may change
         (the speculative engine prefills its draft cache here too) — the
-        chunk bookkeeping around it stays in :meth:`_run_prefill_chunk` so
-        budget/eos fixes cannot drift between engines."""
+        chunk bookkeeping around it stays in :meth:`_run_prefill_chunk` and
+        :meth:`_retire_first` so budget/eos fixes cannot drift between
+        engines."""
         logits, self.kv.buffers = self._prefill(
             self.params, jnp.asarray(toks),
             jnp.asarray(chunk.start, jnp.int32),
@@ -514,7 +561,13 @@ class ServeEngine:
         return logits
 
     def _run_prefill_chunk(self, chunk: SCH.PrefillChunk,
-                           finished: List[Request]) -> None:
+                           finished: List[Request]
+                           ) -> Optional[_FirstToken]:
+        """Dispatch one prefill chunk; after a prompt's final chunk, also
+        its first token's sampler, and return that token for
+        :meth:`_land` to read behind this step's decode.  (``finished``
+        serves an engine that lands the token at once, as the
+        speculative engine does.)"""
         req, obs, sp = chunk.req, self.obs, self.spans
         with sp.prefill:
             toks = np.zeros((1, self.prefill_chunk), np.int32)
@@ -524,33 +577,24 @@ class ServeEngine:
             t0 = obs.now() if obs else 0.0
             logits = self._prefill_call(toks, chunk, page_row)
             req.pf_done += chunk.n_valid
-            final = req.pf_done == len(req.prompt)
-            if final:
-                logits = logits[0, -1:]
-            elif obs:
+            if req.pf_done == len(req.prompt):
+                return _FirstToken(chunk, _sample_batch(
+                    obs, logits[0, -1:], [(0, req)], 1), t0)
+            if obs:
                 # non-final chunk: the dispatch window (no host sync
                 # happens here, so the span measures host+dispatch work)
                 obs.on_prefill(req, chunk.start // self.prefill_chunk,
                                chunk.n_valid, t0, obs.now())
-        if not final:
-            return
-        nxt = _sample_batch(obs, logits, [(0, req)], 1, sp.sample,
-                            sp.tokens_after_prefill)
-        with sp.retire:
-            req.generated.append(int(nxt[0]))
-            if obs:
-                t1 = obs.now()
-                obs.on_prefill(req, chunk.start // self.prefill_chunk,
-                               chunk.n_valid, t0, t1)
-                obs.on_tokens(req, 1, t1, source="prefill")
-            # prefill_finished first — it indexes the prompt pages for
-            # prefix reuse, which a budget-limited request still provides
-            self.sched.prefill_finished(req)
-            if req.budget_reached(self.max_len):
-                self.sched.retire(req)
-                finished.append(req)
+        return None
 
-    def _run_decode(self, decode, finished: List[Request]) -> None:
+    def _run_decode(self, decode, landing: Optional[_Decoded],
+                    finished: List[Request]) -> Optional[_Decoded]:
+        """Dispatch one batched decode and its sampler, and return it with
+        the sampled tokens still on the device: the next step lands them
+        (:meth:`_land`) once its own decode is queued behind this one.
+        A row whose last token is still in flight takes it from the
+        ``landing`` decode's device output (``serve.decode_tokens``): a
+        request keeps its row while it runs, so the row is the same."""
         obs, sp = self.obs, self.spans
         with sp.decode:
             token = np.zeros((self.max_batch, 1), np.int32)
@@ -558,29 +602,90 @@ class ServeEngine:
             table = np.full((self.max_batch, self.max_pages_per_seq),
                             self.kv.trash, np.int32)
             for row, req in decode:
-                token[row, 0] = req.generated[-1]
+                token[row, 0] = -1 if req.inflight else req.generated[-1]
                 pos[row] = req.next_pos
                 table[row, : len(req.pages)] = req.pages
             t0 = obs.now() if obs else 0.0
+            if token.min() < 0:
+                token = self._merge_tokens(landing.tokens, token)
             logits, self.kv.buffers = self._decode(
                 self.params, jnp.asarray(token), jnp.asarray(pos),
                 jnp.asarray(table), self.kv.buffers)
             logits = logits[:, 0]
-        nxt = _sample_batch(obs, logits, decode, self.max_batch, sp.sample,
-                            sp.tokens_after_decode)
-        with sp.retire:
-            if obs:
-                # _sample_batch pulled the tokens to host, so t1 covers
-                # the step's real wall time without a sync of our own
-                t1 = obs.now()
-                obs.on_decode(decode, t0, t1)
-            for row, req in decode:
-                req.generated.append(int(nxt[row]))
+        toks = _sample_batch(obs, logits, decode, self.max_batch, sp.sample)
+        return _Decoded(decode, toks, t0)
+
+    def _land(self, landing: Optional[_Decoded],
+              first: Optional[_FirstToken], finished: List[Request]) -> None:
+        """Read the tokens sampled before this step's decode, in the
+        device's order — the ``landing`` decode of the previous step,
+        then this step's ``first`` token — and retire: append each token,
+        run the hooks, and retire the requests that are done."""
+        obs, sp = self.obs, self.spans
+        if landing is not None:
+            with sp.tokens_after_decode:
+                decoded = np.asarray(landing.tokens)
+            t1 = obs.now() if obs else 0.0
+        if first is not None:
+            with sp.tokens_after_prefill:
+                first_tok = int(np.asarray(first.tokens)[0])
+        if landing is not None or first is not None or obs:
+            with sp.retire:
+                if landing is not None:
+                    self._retire_decode(landing, decoded, t1, finished)
+                if first is not None:
+                    self._retire_first(first, first_tok, finished)
                 if obs:
-                    obs.on_tokens(req, 1, t1)
-                if req.budget_reached(self.max_len):
-                    self.sched.retire(req)
-                    finished.append(req)
+                    obs.sample_pool(self.kv.allocator)
+                    obs.poll_jit()
+
+    def _retire_decode(self, landing: _Decoded, decoded: np.ndarray,
+                       t1: float, finished: List[Request]) -> None:
+        """Append a landed decode's tokens (read by the host at ``t1``)."""
+        obs = self.obs
+        if obs:
+            # the device ran this decode after the previous one landed,
+            # so its window starts no earlier than that
+            obs.on_decode(landing.rows, max(landing.t0, self._landed_at),
+                          t1)
+            self._landed_at = t1
+        discarded = {"eos": 0, "cancel": 0}
+        for row, req in landing.rows:
+            req.inflight -= 1
+            if req.done:
+                # retired on an EOS, or cancelled, after this decode was
+                # queued: the row-step is wasted and its token dropped
+                discarded["cancel" if req.cancelled else "eos"] += 1
+                continue
+            req.generated.append(int(decoded[row]))
+            if obs:
+                obs.on_tokens(req, 1, t1)
+            if req.budget_reached(self.max_len):
+                self.sched.retire(req)
+                finished.append(req)
+        if obs:
+            for reason, n in discarded.items():
+                if n:
+                    obs.on_discard(reason, n)
+
+    def _retire_first(self, first: _FirstToken, tok: int,
+                      finished: List[Request]) -> None:
+        """Append a prompt's first token, close its prefill, and retire it
+        if that token ends it."""
+        chunk, obs = first.chunk, self.obs
+        req = chunk.req
+        req.generated.append(tok)
+        if obs:
+            t1 = obs.now()
+            obs.on_prefill(req, chunk.start // self.prefill_chunk,
+                           chunk.n_valid, first.t0, t1)
+            obs.on_tokens(req, 1, t1, source="prefill")
+        # prefill_finished first — it indexes the prompt pages for prefix
+        # reuse, which a budget-limited request still provides
+        self.sched.prefill_finished(req)
+        if req.budget_reached(self.max_len):
+            self.sched.retire(req)
+            finished.append(req)
 
 
 class FixedSlotEngine:
@@ -734,8 +839,9 @@ class FixedSlotEngine:
                     else full, self.cache, cache1)
                 spliced = True
                 logits = logits[0, -1:]
-            nxt = _sample_batch(obs, logits, [(0, req)], 1, sp.sample,
-                                sp.tokens_after_prefill)
+            nxt = _sample_batch(obs, logits, [(0, req)], 1, sp.sample)
+            with sp.tokens_after_prefill:
+                nxt = np.asarray(nxt)
             with sp.retire:
                 req.generated.append(int(nxt[0]))
                 if obs:
@@ -789,8 +895,9 @@ class FixedSlotEngine:
                 self.params, jnp.asarray(token),
                 jnp.asarray(self.pos, jnp.int32), self.cache)
             logits = logits[:, 0]
-        nxt = _sample_batch(obs, logits, rows, self.slots, sp.sample,
-                            sp.tokens_after_decode)
+        nxt = _sample_batch(obs, logits, rows, self.slots, sp.sample)
+        with sp.tokens_after_decode:
+            nxt = np.asarray(nxt)
         with sp.retire:
             if obs:
                 t1 = obs.now()

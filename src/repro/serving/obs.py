@@ -747,6 +747,14 @@ class Recorder:
         self._c_sample_sampled = r.counter(
             "serve_sample_calls_total", "Sampler dispatches by program",
             path="sampled")
+        self._c_discard_eos = r.counter(
+            "serve_tokens_discarded_total",
+            "Row-steps decoded after their request ended, tokens dropped",
+            reason="eos")
+        self._c_discard_cancel = r.counter(
+            "serve_tokens_discarded_total",
+            "Row-steps decoded after their request ended, tokens dropped",
+            reason="cancel")
         self._h_occupancy = r.histogram(
             "serve_batch_occupancy", "Decode rows active per batched step",
             buckets=OCCUPANCY_BUCKETS)
@@ -931,13 +939,25 @@ class Recorder:
             self.tracer.span(Tracer.ENGINE_TID, name, t0, t1,
                              rows=len(rows_reqs))
             for _row, req in rows_reqs:
-                self.tracer.span(req.uid + 1, name, t0, t1)
+                st = self._req.get(req.uid)
+                # swapped out while this decode was in flight: its lane
+                # turns to ``swapped`` there
+                end = (t1 if st is None or st.swap_open is None
+                       else max(t0, min(t1, st.swap_open)))
+                self.tracer.span(req.uid + 1, name, t0, end)
 
     def on_sample(self, path: str) -> None:
         """One sampler dispatch of a plain engine: ``path="greedy"`` for
         the argmax program of an all-greedy batch, else ``"sampled"``."""
         (self._c_sample_greedy if path == "greedy"
          else self._c_sample_sampled).inc()
+
+    def on_discard(self, reason: str, n: int) -> None:
+        """``n`` tokens sampled in flight for requests that had ended by
+        the time they landed: ``reason="eos"`` (an earlier token was EOS)
+        or ``"cancel"``."""
+        (self._c_discard_eos if reason == "eos"
+         else self._c_discard_cancel).inc(n)
 
     def on_tokens(self, req, n: int, ts: float, *,
                   source: str = "decode") -> None:

@@ -11,8 +11,8 @@ distributional harness in ``tests/dist_check.py``):
     role)`` — never a shared batch key, never engine state — so a
     request's stream depends only on its own :class:`SamplingParams`,
     not on batch composition, admission order, or page-fault
-    eviction/host-swap (the counter is just ``len(req.generated)``,
-    which swaps trivially);
+    eviction/host-swap (the counter is just ``req.emitted``, the tokens
+    sampled so far, which swaps trivially);
   * **greedy is the T=0 special case** — a ``temperature == 0`` row of
     :func:`sample_tokens` gets a one-hot distribution at
     ``argmax(logits)``, and the exact inverse-CDF sampler maps *any*
@@ -226,8 +226,9 @@ def batch_rows(rows_reqs: List[Tuple[int, object]], batch: int):
     """Assemble the per-row sampling arrays for a decode/verify batch
     from ``(row, request)`` pairs.  Inactive rows default to greedy
     (T=0), whose samples the engines discard.  ``t`` is the emission
-    index of the *next* token — ``len(req.generated)`` — which is what
-    makes streams batch-independent and swap/eviction-proof."""
+    index of the *next* token — ``req.emitted``, which counts a token
+    still in flight — which is what makes streams batch-independent and
+    swap/eviction-proof."""
     seed = np.zeros((batch,), np.uint32)
     t = np.zeros((batch,), np.int32)
     temp = np.zeros((batch,), np.float32)
@@ -236,7 +237,7 @@ def batch_rows(rows_reqs: List[Tuple[int, object]], batch: int):
     for row, req in rows_reqs:
         sp = req.sampling
         seed[row] = sp.seed
-        t[row] = len(req.generated)
+        t[row] = req.emitted
         temp[row] = sp.temperature
         top_k[row] = sp.top_k
         top_p[row] = sp.top_p

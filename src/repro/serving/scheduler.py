@@ -79,6 +79,10 @@ class Request:
     sampling: SamplingParams = dataclasses.field(default_factory=SamplingParams)
     # filled by the engine / scheduler
     generated: List[int] = dataclasses.field(default_factory=list)
+    # tokens sampled on the device that the host has not read yet (the
+    # paged engine keeps one decode in flight): they count toward
+    # positions, pages and budgets before they reach ``generated``
+    inflight: int = 0
     done: bool = False
     cancelled: bool = False
     state: str = WAITING
@@ -98,9 +102,14 @@ class Request:
     spec_accepted: int = 0   # draft tokens the target accepted
 
     @property
+    def emitted(self) -> int:
+        """Tokens sampled so far: read by the host or still in flight."""
+        return len(self.generated) + self.inflight
+
+    @property
     def next_pos(self) -> int:
         """Cache index the next decode step writes (= tokens written)."""
-        return len(self.prompt) + len(self.generated) - 1
+        return len(self.prompt) + self.emitted - 1
 
     @property
     def acceptance_rate(self) -> float:
@@ -108,10 +117,12 @@ class Request:
         return self.spec_accepted / max(1, self.spec_proposed)
 
     def budget_reached(self, max_len: int) -> bool:
+        """No further token may be sampled: the budgets count tokens in
+        flight; EOS is known once the host has read it."""
         last = self.generated[-1] if self.generated else None
-        return (len(self.generated) >= self.max_new_tokens
+        return (self.emitted >= self.max_new_tokens
                 or (self.eos_id is not None and last == self.eos_id)
-                or len(self.prompt) + len(self.generated) >= max_len)
+                or len(self.prompt) + self.emitted >= max_len)
 
 
 @dataclasses.dataclass
@@ -235,10 +246,12 @@ class Scheduler:
                 [r for r in self.rows.values() if r.state == RUNNING]):
             if req.state != RUNNING:
                 continue  # evicted by an earlier request's page fault
+            if req.budget_reached(self.max_len):
+                continue  # its last token is in flight; it retires on landing
             # mirrors the speculative engine's verify-window clamp (the
             # -1: emitted tokens keep prompt+generated <= max_len) so no
             # page is reserved that the window can never write
-            la = min(self.lookahead, req.max_new_tokens - len(req.generated),
+            la = min(self.lookahead, req.max_new_tokens - req.emitted,
                      self.max_len - req.next_pos - 1)
             if not self._ensure_pages(req, req.next_pos + max(la, 1), plan):
                 continue  # swapped itself out
@@ -266,6 +279,10 @@ class Scheduler:
         clone.req.cow = None
 
     def retire(self, req: Request) -> None:
+        if req.state == SWAPPED:
+            # its last token landed after a page fault swapped it out
+            self.swapped.remove(req)
+            req.host_kv = None
         self._release(req)
         req.state = DONE
         req.done = True
@@ -485,5 +502,6 @@ class Scheduler:
             assert req.row == row and req.state in (PREFILL, RUNNING)
         for req in self.waiting + self.swapped:
             assert req.row is None
+            assert not req.inflight, "queued request has a token in flight"
             assert not req.pages, "queued request still holds pages"
             assert req.cow is None, "queued request has a pending clone"

@@ -301,7 +301,10 @@ class SpeculativeEngine(ServeEngine):
     # -- internals ---------------------------------------------------------
     # ``ServeEngine.step`` drives a round: swaps and copy-on-write clones
     # (both caches), at most one prefill chunk (both models), and one
-    # speculative draft+verify round in place of the decode.
+    # speculative draft+verify round in place of the decode.  The round
+    # reads its accept counts on the host before it returns, so unlike
+    # the plain decode it leaves nothing in flight, and a prompt's first
+    # token lands at once, before the round (``_run_prefill_chunk``).
     def _swap_out(self, req: Request, old_pages: List[int]) -> None:
         super()._swap_out(req, old_pages)
         self._draft_host[req.uid] = self.kv_draft.gather_host(old_pages)
@@ -332,9 +335,22 @@ class SpeculativeEngine(ServeEngine):
             jnp.asarray(page_row), self.kv.buffers, self.kv_draft.buffers)
         return logits
 
-    def _run_decode(self, decode, finished: List[Request]) -> None:
+    def _run_prefill_chunk(self, chunk, finished: List[Request]) -> None:
+        """The plain engine's prefill chunk, whose first token is read and
+        retired at once: no decode of this engine stays in flight for the
+        read to wait behind."""
+        first = super()._run_prefill_chunk(chunk, finished)
+        if first is not None:
+            sp = self.spans
+            with sp.tokens_after_prefill:
+                tok = int(np.asarray(first.tokens)[0])
+            with sp.retire:
+                self._retire_first(first, tok, finished)
+
+    def _run_decode(self, decode, landing, finished: List[Request]) -> None:
         """Draft k proposals, verify k+1 positions, rejection-sample the
-        accepted prefix + correction/bonus token — all in one dispatch."""
+        accepted prefix + correction/bonus token — all in one dispatch.
+        The round leaves nothing in flight, so ``landing`` is ``None``."""
         obs, sp = self.obs, self.spans
         with sp.decode:
             k = self.spec_k

@@ -39,7 +39,8 @@ PROMPTS = [[1, 2, 3], [7, 5], [9, 9, 9, 2], [4, 4, 1, 1, 5, 6, 7],
 
 # the PR-4 eviction workload: a pool too small for the request set, so
 # recording must survive (and observe) host swap without changing streams
-EVICT_KWARGS = dict(max_batch=3, page_size=4, prefill_chunk=4, num_pages=9)
+# (7 pages: with one decode in flight, 9 no longer runs dry)
+EVICT_KWARGS = dict(max_batch=3, page_size=4, prefill_chunk=4, num_pages=7)
 
 
 def _tiny_cfg():
@@ -645,13 +646,12 @@ def test_sample_batch_dispatch(monkeypatch, temps):
             calls.append((_n, a)) or _f(*a)))
     logits = jax.random.normal(jax.random.PRNGKey(3), (4, 64))
     rows = [(row, SimpleNamespace(
-        generated=[1] * row,
+        emitted=row,
         sampling=SamplingParams(temperature=temp, top_k=row, top_p=0.5,
                                 seed=row + 9)))
         for row, temp in enumerate(temps)]
     rec = Recorder(trace=False)
-    toks = E._sample_batch(rec, logits, rows, 4, nullcontext(),
-                           nullcontext())
+    toks = E._sample_batch(rec, logits, rows, 4, nullcontext())
     (name, args), = calls
     if max(temps) == 0:
         assert name == "greedy_tokens_jit" and args == (logits,)
@@ -669,8 +669,9 @@ def test_sample_batch_dispatch(monkeypatch, temps):
 @pytest.mark.parametrize("kind", ENGINES)
 def test_step_records_are_well_formed(setup, kind):
     """Every step's phases are known, in order, inside their step; a
-    step's self time is never negative; a decode step waits for its
-    tokens exactly once after the decode; the ring keeps the newest."""
+    step's self time is never negative; a decode's tokens are waited for
+    exactly once after the decode (the paged engine: in the next step,
+    with its own decode queued behind); the ring keeps the newest."""
     cfg, params = setup
     rec = Recorder(trace=False)
     assert rec.steps.maxlen == STEP_RING
@@ -686,6 +687,7 @@ def test_step_records_are_well_formed(setup, kind):
     assert n > 6 and [s.num for s in rec.steps] == list(range(n - 5, n + 1))
     assert [s.num for s in records] == list(range(1, n + 1))
     seen = set()
+    in_flight = False  # the paged engine's decode lands a step later
     for s in records:
         names = [p[0] for p in s.phases]
         seen.update(names)
@@ -696,15 +698,19 @@ def test_step_records_are_well_formed(setup, kind):
             assert prev <= t0 <= t1 <= s.t1, (s.num, name)
             prev = t1
             if name == "serve.tokens":
-                # the wait follows the dispatch it waits for
-                assert names[i - 1] in ("serve.sample", f"serve.{after}")
+                if kind != "paged":
+                    # the wait follows the dispatch it waits for
+                    assert names[i - 1] in ("serve.sample",
+                                            f"serve.{after}")
             else:
                 assert after is None
         waits = [(t1 - t0, after) for name, t0, t1, after in s.phases
                  if name == "serve.tokens"]
         assert s.t1 - s.t0 - sum(w for w, _ in waits) >= 0
+        decoded = "serve.decode" in names
         assert [a for _, a in waits].count("decode") == (
-            "serve.decode" in names)
+            in_flight if kind == "paged" else decoded)
+        in_flight = decoded
     want = {"serve.schedule", "serve.prefill", "serve.decode",
             "serve.tokens", "serve.retire"}
     if kind != "speculative":
@@ -712,6 +718,121 @@ def test_step_records_are_well_formed(setup, kind):
     if kind == "paged":
         want.add("serve.kv_move")  # the eviction workload swaps
     assert want <= seen
+
+
+def test_speculative_first_token_lands_before_round(setup):
+    """The speculative engine keeps nothing in flight: a prompt's first
+    token is read, appended and stamped (TTFT) before the draft+verify
+    round of the step that finished its prefill."""
+    cfg, params = setup
+    rec = Recorder(trace=False)
+    eng = _engine_factory("speculative", params, cfg)(rec)
+    reqs = [eng.submit(p, max_new_tokens=8) for p in PROMPTS]
+    with_round = 0
+    while eng.has_work:
+        fresh = [r for r in reqs if not r.generated]
+        eng.step()
+        s = rec.steps[-1]
+        names = [p[0] for p in s.phases]
+        for req in fresh:
+            if not req.generated:
+                continue
+            ts = rec._req[req.uid].first_tok_ts
+            i = names.index("serve.retire")
+            assert names[i - 2:i] == ["serve.prefill", "serve.tokens"]
+            assert s.phases[i - 1][3] == "prefill"
+            assert s.phases[i][1] <= ts <= s.phases[i][2]
+            if "serve.decode" in names:
+                with_round += 1
+                assert i < names.index("serve.decode")
+                assert ts <= s.phases[names.index("serve.decode")][1]
+    assert with_round > 0
+
+
+# the phases of a paged step, each at most once, in this order: the
+# previous step's decode lands before this step's first token
+PAGED_ORDER = [("serve.schedule", None), ("serve.kv_move", None),
+               ("serve.prefill", None), ("serve.decode", None),
+               ("serve.sample", None), ("serve.tokens", "decode"),
+               ("serve.tokens", "prefill"), ("serve.retire", None)]
+
+
+@pytest.mark.parametrize("workload", ["greedy", "sampled", "evict"])
+def test_paged_step_phases_once_in_order(setup, workload):
+    """With one decode in flight, each phase of a paged step runs at
+    most once, in :data:`PAGED_ORDER`, and no two phases overlap."""
+    cfg, params = setup
+    rec = Recorder(trace=False)
+    kw = (EVICT_KWARGS if workload == "evict"
+          else dict(max_batch=3, page_size=16, prefill_chunk=4))
+    eng = ServeEngine(params, cfg, max_len=64, recorder=rec, **kw)
+    for i, p in enumerate(PROMPTS):
+        eng.submit(p, max_new_tokens=8, sampling=(
+            SAMPLED if workload == "sampled" and i % 2 else None))
+    eng.run_until_drained()
+    seen = set()
+    for s in rec.steps:
+        keys = [(name, after) for name, _, _, after in s.phases]
+        assert keys == [k for k in PAGED_ORDER if k in keys], (s.num, keys)
+        seen.update(keys)
+        end = s.t0
+        for _, t0, t1, _ in s.phases:
+            assert end <= t0 <= t1 <= s.t1, (s.num, keys)
+            end = t1
+    # every phase ran somewhere; the eviction workload moves KV
+    assert set(PAGED_ORDER) - seen <= {("serve.kv_move", None)}
+    assert (("serve.kv_move", None) in seen) >= (workload == "evict")
+
+
+def test_tokens_discarded_counter(setup):
+    """``serve_tokens_discarded_total`` counts each row-step decoded for
+    a request that had ended by the time its token landed, by reason: an
+    EOS that landed first, or a cancel; a run with neither counts none."""
+    cfg, params = setup
+    kw = dict(max_batch=3, max_len=64, page_size=16, prefill_chunk=4)
+    rec = Recorder(trace=False)
+    eng = ServeEngine(params, cfg, recorder=rec, **kw)
+    plain = [eng.submit(p, max_new_tokens=8) for p in PROMPTS[:3]]
+    eng.run_until_drained()
+    v = rec.registry.value
+    assert rec.registry.sum_values("serve_tokens_discarded_total") == 0
+    # the stream's first token that a decode emits and none before it
+    gen = plain[0].generated
+    i = next(i for i in range(1, 7) if gen[i] not in gen[:i])
+    ended = eng.submit(PROMPTS[0], max_new_tokens=8, eos_id=gen[i])
+    cancelled = eng.submit(PROMPTS[1], max_new_tokens=8)
+    while len(cancelled.generated) < 2 or not cancelled.inflight:
+        eng.step()
+    eng.cancel(cancelled.uid)
+    eng.run_until_drained()
+    assert ended.generated == gen[: i + 1]
+    assert v("serve_tokens_discarded_total", reason="eos") == 1
+    assert v("serve_tokens_discarded_total", reason="cancel") == 1
+    assert "serve_tokens_discarded_total" in rec.to_prometheus()
+
+
+def test_no_compiles_after_warm_up(setup):
+    """After a warm-up like the chip benchmark's (a two-chunk prefill,
+    first tokens, decodes at a full and a partial batch), new traffic
+    compiles nothing at any registered site, the merge of in-flight
+    tokens (``serve.decode_tokens``) included."""
+    cfg, params = setup
+    rec = Recorder(trace=False)
+    eng = ServeEngine(params, cfg, max_batch=3, max_len=64, page_size=16,
+                      prefill_chunk=4, recorder=rec)
+    rng = np.random.default_rng(0)
+    eng.submit(rng.integers(0, 64, 5).tolist(), max_new_tokens=3)
+    for _ in range(3):
+        eng.submit(rng.integers(0, 64, 2).tolist(), max_new_tokens=3)
+    eng.run_until_drained()
+    assert eng._merge_tokens._cache_size() == 1
+    rec.reset()
+    for p in PROMPTS:
+        eng.submit(p, max_new_tokens=8)
+    eng.run_until_drained()
+    v = rec.registry.value
+    assert v("jit_cache_misses_total", -1.0, site="serve.decode_tokens") == 0
+    assert rec.registry.sum_values("jit_cache_misses_total") == 0
 
 
 def test_profiler_trace_holds_step_spans(setup, tmp_path):

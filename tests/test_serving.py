@@ -99,12 +99,17 @@ PROMPTS = [[1, 2, 3], [7, 5], [9, 9, 9, 2], [4, 4, 1, 1, 5, 6, 7],
            [3, 1], list(range(1, 21))]  # mixed lengths incl. multi-chunk
 
 
-def _drain_both(params, cfg, *, paged_kwargs):
+def _drain_both(params, cfg, *, paged_kwargs, submit_kwargs=None):
+    """The PROMPTS through the fixed-slot oracle and the paged engine;
+    ``submit_kwargs`` holds each prompt's extra ``submit`` options."""
+    extra = submit_kwargs or [{}] * len(PROMPTS)
     fixed = FixedSlotEngine(params, cfg, slots=2, max_len=64)
-    rf = [fixed.submit(p, max_new_tokens=8) for p in PROMPTS]
+    rf = [fixed.submit(p, max_new_tokens=8, **kw)
+          for p, kw in zip(PROMPTS, extra)]
     fixed.run_until_drained()
     paged = ServeEngine(params, cfg, max_len=64, **paged_kwargs)
-    rp = [paged.submit(p, max_new_tokens=8) for p in PROMPTS]
+    rp = [paged.submit(p, max_new_tokens=8, **kw)
+          for p, kw in zip(PROMPTS, extra)]
     paged.run_until_drained()
     return rf, rp, paged
 
@@ -129,7 +134,7 @@ def test_paged_bitmatches_under_eviction(setup):
     cfg, params = setup
     rf, rp, paged = _drain_both(
         params, cfg, paged_kwargs=dict(max_batch=3, page_size=4,
-                                       prefill_chunk=4, num_pages=9))
+                                       prefill_chunk=4, num_pages=7))
     for f, p in zip(rf, rp):
         assert f.generated == p.generated, (f.prompt, f.generated, p.generated)
     # retired prompts stay referenced by the prefix index (that's the
@@ -139,6 +144,117 @@ def test_paged_bitmatches_under_eviction(setup):
     paged.sched.check_invariants()
     paged.sched.prefix.clear()
     assert paged.kv.allocator.in_use == 0  # every page returned
+
+
+# ---------------------------------------------------------------------------
+# One decode in flight: the paged engine reads a decode's tokens in the
+# next step, after that step's decode is queued on the device.
+# ---------------------------------------------------------------------------
+
+# three rows, pages larger than any prompt: no eviction
+IN_FLIGHT = dict(max_batch=3, page_size=16, prefill_chunk=4)
+
+
+def test_eos_in_flight_discards_one_row_step(setup):
+    """A request's EOS lands while its next decode is already queued: its
+    stream ends at the EOS, the same as the fixed-slot oracle's, and the
+    row-step decoded after it is dropped and counted as discarded."""
+    from repro.serving import Recorder
+
+    cfg, params = setup
+    ref = _reference_generate(params, cfg, PROMPTS[3], 8)
+    # a token a decode emits (not the prefill) for the first time
+    i = next(i for i in range(1, 7) if ref[i] not in ref[:i])
+    extra = [{}] * len(PROMPTS)
+    extra[3] = {"eos_id": ref[i]}
+    rec = Recorder(trace=False)
+    rf, rp, _ = _drain_both(params, cfg,
+                            paged_kwargs=dict(IN_FLIGHT, recorder=rec),
+                            submit_kwargs=extra)
+    assert [r.generated for r in rp] == [r.generated for r in rf]
+    assert rp[3].generated == ref[: i + 1]
+    v = rec.registry.value
+    assert v("serve_tokens_discarded_total", reason="eos") == 1
+    assert v("serve_tokens_discarded_total", reason="cancel") == 0
+
+
+def test_cancel_with_its_decode_in_flight(setup):
+    """Cancelling a request whose decode is in flight drops that token
+    (counted as discarded): the request keeps the tokens that had
+    landed, a prefix of its stream, and every other stream bit-matches
+    the fixed-slot oracle."""
+    from repro.serving import Recorder
+
+    cfg, params = setup
+    fixed = FixedSlotEngine(params, cfg, slots=2, max_len=64)
+    rf = [fixed.submit(p, max_new_tokens=8) for p in PROMPTS]
+    fixed.run_until_drained()
+    rec = Recorder(trace=False)
+    paged = ServeEngine(params, cfg, max_len=64, recorder=rec, **IN_FLIGHT)
+    rp = [paged.submit(p, max_new_tokens=8) for p in PROMPTS]
+    victim = rp[0]
+    while len(victim.generated) < 2 or not victim.inflight:
+        paged.step()
+    landed = list(victim.generated)
+    assert paged.cancel(victim.uid)
+    paged.run_until_drained()
+    assert victim.cancelled and victim.generated == landed
+    assert landed == rf[0].generated[: len(landed)]
+    for f, p in zip(rf[1:], rp[1:]):
+        assert f.generated == p.generated, (f.prompt, f.generated,
+                                            p.generated)
+    v = rec.registry.value
+    assert v("serve_tokens_discarded_total", reason="cancel") == 1
+    assert v("serve_tokens_discarded_total", reason="eos") == 0
+    assert not paged.has_work
+
+
+def test_paged_bitmatches_fixed_slot_mixed_sampling(setup):
+    """A batch of greedy and sampled rows: with each sampled row's input
+    token taken from the decode in flight and its step counter counting
+    that token, every stream matches the fixed-slot oracle for the same
+    seeds."""
+    from repro.serving import SamplingParams
+
+    cfg, params = setup
+    extra = [{"sampling": SamplingParams(temperature=0.8, top_k=8,
+                                         top_p=0.9, seed=10 + i)}
+             if i % 2 else {} for i in range(len(PROMPTS))]
+    rf, rp, _ = _drain_both(params, cfg, paged_kwargs=IN_FLIGHT,
+                            submit_kwargs=extra)
+    for f, p in zip(rf, rp):
+        assert f.generated == p.generated, (f.prompt, f.generated,
+                                            p.generated)
+        assert len(p.generated) == 8
+
+
+def test_paged_bitmatches_with_swaps_in_flight(setup):
+    """A pool too small for the workload swaps requests out while their
+    token is in flight (the swap-out copy waits for that decode's cache
+    writes) and back in later: streams still bit-match the fixed-slot
+    oracle."""
+    from repro.serving import Recorder
+
+    swaps = []
+
+    class Rec(Recorder):
+        def on_evict(self, req, kind):
+            super().on_evict(req, kind)
+            if kind == "swap":
+                swaps.append(req.inflight)
+
+    cfg, params = setup
+    rec = Rec(trace=False)
+    rf, rp, paged = _drain_both(
+        params, cfg, paged_kwargs=dict(max_batch=3, page_size=4,
+                                       prefill_chunk=4, num_pages=7,
+                                       recorder=rec))
+    for f, p in zip(rf, rp):
+        assert f.generated == p.generated, (f.prompt, f.generated,
+                                            p.generated)
+    assert any(swaps), "no request was swapped out with a token in flight"
+    assert rec.registry.value("serve_resumed_total") == len(swaps)
+    paged.sched.check_invariants()
 
 
 def test_cancellation(setup):

@@ -146,3 +146,38 @@ def test_golden_streams_through_both_sampler_programs(tmp_path, kind):
         assert v("serve_sample_calls_total", path="greedy") > 0
         assert (v("serve_sample_calls_total", path="sampled") > 0) == \
             with_sampled
+
+
+@pytest.mark.parametrize("opts", [
+    dict(max_batch=1, page_size=16),
+    dict(max_batch=3, page_size=4, num_pages=7),
+], ids=["one_row", "tight_pool"])
+def test_golden_streams_with_one_decode_in_flight(tmp_path, opts):
+    """``ServeEngine`` keeps one decode in flight: a decode takes its
+    rows' input tokens from the previous decode on the device, and the
+    host reads them a step later.  The checked-in greedy streams come
+    out unchanged with one row (every decode continues the last) and
+    with a pool small enough that requests are swapped out while their
+    token is in flight."""
+    from repro.serving import Recorder, ServeEngine
+
+    if not GOLDEN_PATH.is_file():
+        pytest.skip("golden file not generated yet")
+    golden = json.loads(GOLDEN_PATH.read_text())
+    out = tmp_path / "lm_art"
+    params, cfg, _ = _tiny_artifact(out)
+    swapped_in_flight = []
+
+    class Rec(Recorder):
+        def on_evict(self, req, kind):
+            super().on_evict(req, kind)
+            swapped_in_flight.append(kind == "swap" and req.inflight > 0)
+
+    eng = ServeEngine.from_artifact(out, params, cfg, max_len=64,
+                                    prefill_chunk=4, recorder=Rec(),
+                                    **opts)
+    reqs = [eng.submit(p, max_new_tokens=MAX_NEW) for p in PROMPTS]
+    eng.run_until_drained()
+    streams = {",".join(map(str, r.prompt)): r.generated for r in reqs}
+    assert streams == golden
+    assert any(swapped_in_flight) == ("num_pages" in opts)
