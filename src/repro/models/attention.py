@@ -273,19 +273,23 @@ def _decode_attend(qg: Array, cache_k: Array, cache_v: Array, pos_b: Array,
     return FV.decode_attend(qg, cache_k, cache_v, pos_b, window)
 
 
-def _paged_view(k_pages: Array, v_pages: Array, page_table: Array,
-                nkv: int, hd: int) -> Tuple[Array, Array]:
-    """Gather the logical ``(B, S, n_kv, hd)`` view of the physical pages.
+def _paged_view(k_pool: Array, v_pool: Array, layer: Array,
+                page_table: Array, nkv: int, hd: int) -> Tuple[Array, Array]:
+    """Gather layer ``layer``'s logical ``(B, S, n_kv, hd)`` view of the
+    physical pages.
 
     THE paged-cache read: decode, chunked prefill and the fused verify
     window all gather through this one helper, so "each step reads its
-    pages exactly once" is structural.  Under a mesh the pages shard over
-    the DP axis and XLA inserts the cross-shard collective; the gather is
-    donation-safe under jit.
+    pages exactly once" is structural.  ``k_pool``/``v_pool`` are the whole
+    ``(L, P, page_size, n_kv, hd)`` pools the layer scan carries; the read
+    is one gather at ``[layer, page_table]`` straight out of the pool (no
+    ``pool[layer]`` slice first, which XLA may materialise as a copy of
+    the layer's pages).  Under a mesh the pages shard over the DP axis and
+    XLA inserts the cross-shard collective.
     """
     b = page_table.shape[0]
-    k_view = k_pages[page_table].reshape(b, -1, nkv, hd)
-    v_view = v_pages[page_table].reshape(b, -1, nkv, hd)
+    k_view = k_pool[layer, page_table].reshape(b, -1, nkv, hd)
+    v_view = v_pool[layer, page_table].reshape(b, -1, nkv, hd)
     return k_view, v_view
 
 
@@ -318,17 +322,18 @@ def decode_step(params: dict, x: Array, cfg: ModelConfig,
 
 
 def paged_decode_step(params: dict, x: Array, cfg: ModelConfig,
-                      k_pages: Array, v_pages: Array, page_table: Array,
-                      pos: Array, window: Optional[Array],
+                      k_pool: Array, v_pool: Array, layer: Array,
+                      page_table: Array, pos: Array, window: Optional[Array],
                       write_ok: Optional[Array] = None,
                       ) -> Tuple[Array, Tuple[Array, Array]]:
-    """One-token decode against one layer's **paged** KV cache.
+    """One-token decode of layer ``layer`` against the **paged** KV cache.
 
-    x: (B, 1, D); k_pages/v_pages: (P, page_size, n_kv, hd) physical pages
-    (last page is the engine's trash page); page_table: (B, max_pages)
-    int32 logical→physical map, trash-padded; pos: (B,) int32 write index
-    per row.  Rows without an active request point their whole page-table
-    row at the trash page.
+    x: (B, 1, D); k_pool/v_pool: the whole (L, P, page_size, n_kv, hd)
+    physical page pools (last page is the engine's trash page); layer:
+    traced int32 layer index; page_table: (B, max_pages) int32
+    logical→physical map, trash-padded; pos: (B,) int32 write index per
+    row.  Rows without an active request point their whole page-table row
+    at the trash page.  Returns the updated pools.
 
     ``write_ok`` ((B,) bool, optional) redirects a row's K/V write to the
     trash page — the speculative draft/verify loops use it to mask steps
@@ -337,12 +342,12 @@ def paged_decode_step(params: dict, x: Array, cfg: ModelConfig,
     otherwise *clamp* onto the row's last real page and corrupt it).
     ``None`` preserves the historical always-write behaviour bit-exactly.
 
-    The new token's K/V is scattered into its physical page, then the
-    logical view is gathered (``pages[page_table]`` — a donation-safe jitted
-    gather: under a mesh the pages shard over the DP axis and XLA inserts
-    the cross-shard collective) and handed to the *same* masked read used
-    by the slot cache, so valid positions see bit-identical values and the
-    trash/garbage rows are masked to exact zeros.
+    The new token's K/V is scattered in place into the pool at ``[layer,
+    phys, off]`` (one scatter, no copy of the pool), then the layer's
+    logical view is gathered (:func:`_paged_view`) and handed to the *same*
+    masked read used by the slot cache, so valid positions see
+    bit-identical values and the trash/garbage rows are masked to exact
+    zeros.
     """
     b = x.shape[0]
     hd = cfg.resolved_head_dim
@@ -350,35 +355,37 @@ def paged_decode_step(params: dict, x: Array, cfg: ModelConfig,
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     positions = pos_b[:, None]
     q, k, v = _project_qkv(params, x, cfg, positions)
-    if k_pages.dtype == jnp.int8:
+    if k_pool.dtype == jnp.int8:
         k, v = _quantize_kv_int8(k, v)
-    ps = k_pages.shape[1]
-    trash = k_pages.shape[0] - 1
+    ps = k_pool.shape[2]
+    trash = k_pool.shape[1] - 1
     rows = jnp.arange(b)
     phys = page_table[rows, pos_b // ps]  # (B,) physical page per row
     if write_ok is not None:
         phys = jnp.where(write_ok, phys, trash)
     off = pos_b % ps
-    k_pages = k_pages.at[phys, off].set(k[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[phys, off].set(v[:, 0].astype(v_pages.dtype))
-    k_view, v_view = _paged_view(k_pages, v_pages, page_table, nkv, hd)
+    k_pool = k_pool.at[layer, phys, off].set(k[:, 0].astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, phys, off].set(v[:, 0].astype(v_pool.dtype))
+    k_view, v_view = _paged_view(k_pool, v_pool, layer, page_table, nkv, hd)
     qg = _grouped(q, nkv)
     out = _decode_attend(qg, k_view, v_view, pos_b, window)
     out = out.reshape(b, 1, nq * hd).astype(x.dtype)
-    return out @ params["wo"].astype(x.dtype), (k_pages, v_pages)
+    return out @ params["wo"].astype(x.dtype), (k_pool, v_pool)
 
 
 def paged_verify_window(params: dict, x: Array, cfg: ModelConfig,
-                        k_pages: Array, v_pages: Array, page_table: Array,
-                        pos: Array, n_valid: Array, window: Optional[Array],
-                        attend_impl: str = "auto",
+                        k_pool: Array, v_pool: Array, layer: Array,
+                        page_table: Array, pos: Array, n_valid: Array,
+                        window: Optional[Array], attend_impl: str = "auto",
                         ) -> Tuple[Array, Tuple[Array, Array]]:
-    """One layer's attention over the whole speculative-verify window.
+    """Layer ``layer``'s attention over the whole speculative-verify window.
 
     x: (B, W, D) — the (already ln1-normalised) hidden states of the
-    ``W = k+1`` window tokens; pos: (B,) first window position per row;
-    n_valid: (B,) real tokens in each row's window (the rest scatter to
-    the trash page, exactly like ``paged_decode_step``'s ``write_ok``).
+    ``W = k+1`` window tokens; k_pool/v_pool: the whole (L, P, page_size,
+    n_kv, hd) pools, written in place and returned; layer: traced int32
+    layer index; pos: (B,) first window position per row; n_valid: (B,)
+    real tokens in each row's window (the rest scatter to the trash page,
+    exactly like ``paged_decode_step``'s ``write_ok``).
 
     Bit-identical to W successive ``paged_decode_step`` attention blocks
     while gathering the page view **once** instead of W times:
@@ -396,7 +403,8 @@ def paged_verify_window(params: dict, x: Array, cfg: ModelConfig,
 
     ``attend_impl``: ``auto`` → the Pallas kernel on TPU (pages staged
     through VMEM, never materialising the view in HBM), the portable XLA
-    lowering elsewhere or when no staging fits the VMEM budget.
+    lowering elsewhere or when no staging fits the VMEM budget.  The kernel
+    takes one layer's pages, so that path slices ``pool[layer]`` out.
     """
     b, w, d = x.shape
     hd = cfg.resolved_head_dim
@@ -407,24 +415,24 @@ def paged_verify_window(params: dict, x: Array, cfg: ModelConfig,
     def proj(_, xs):
         xj, off = xs  # (B, D), scalar window offset
         q, k, v = _project_qkv(params, xj[:, None], cfg, (pos_b + off)[:, None])
-        if k_pages.dtype == jnp.int8:
+        if k_pool.dtype == jnp.int8:
             k, v = _quantize_kv_int8(k, v)
         return None, (q[:, 0], k[:, 0], v[:, 0])
 
     _, (qs, ks, vs) = jax.lax.scan(proj, None, (jnp.swapaxes(x, 0, 1), offs))
     q = jnp.swapaxes(qs, 0, 1)                       # (B, W, nq, hd)
-    k = jnp.swapaxes(ks, 0, 1).astype(k_pages.dtype)
-    v = jnp.swapaxes(vs, 0, 1).astype(v_pages.dtype)
+    k = jnp.swapaxes(ks, 0, 1).astype(k_pool.dtype)
+    v = jnp.swapaxes(vs, 0, 1).astype(v_pool.dtype)
 
-    ps = k_pages.shape[1]
-    trash = k_pages.shape[0] - 1
+    ps = k_pool.shape[2]
+    trash = k_pool.shape[1] - 1
     rows = jnp.arange(b)
     wpos = pos_b[:, None] + offs[None, :]            # (B, W) logical pos
     phys = jnp.where(offs[None, :] < n_valid[:, None],
                      page_table[rows[:, None], wpos // ps], trash)
     off = wpos % ps
-    k_pages = k_pages.at[phys, off].set(k)
-    v_pages = v_pages.at[phys, off].set(v)
+    k_pool = k_pool.at[layer, phys, off].set(k)
+    v_pool = v_pool.at[layer, phys, off].set(v)
 
     qg = _grouped(q, nkv)                            # (B, W, n_kv, g, hd)
     impl = FV.resolve_impl(attend_impl)
@@ -432,17 +440,18 @@ def paged_verify_window(params: dict, x: Array, cfg: ModelConfig,
     if impl == "pallas":
         s_len = page_table.shape[1] * ps
         tiles = AT.get_verify_tiles(s_len, w, nkv, nq // nkv, hd,
-                                    k_pages.dtype, page_size=ps)
+                                    k_pool.dtype, page_size=ps)
         if tiles is None:
             _note_verify_fallback(s_len, w, nkv, nq // nkv, hd,
-                                  jnp.dtype(k_pages.dtype).name)
+                                  jnp.dtype(k_pool.dtype).name)
     if tiles is not None:
         win = jnp.asarray(2**30, jnp.int32) if window is None else window
         out = FV.verify_window_attend_pallas(
-            qg, k_pages, v_pages, page_table, pos_b, win,
+            qg, k_pool[layer], v_pool[layer], page_table, pos_b, win,
             block_s=tiles.block_s, interpret=AT.default_interpret())
     else:
-        k_view, v_view = _paged_view(k_pages, v_pages, page_table, nkv, hd)
+        k_view, v_view = _paged_view(k_pool, v_pool, layer, page_table,
+                                     nkv, hd)
         out = FV.verify_window_attend(qg, k_view, v_view, pos_b, window)
 
     def proj_o(_, oj):  # (B, n_kv, g, hd) — the oracle's (B, 1, ·) @ wo
@@ -450,25 +459,28 @@ def paged_verify_window(params: dict, x: Array, cfg: ModelConfig,
         return None, (o @ params["wo"].astype(x.dtype))[:, 0]
 
     _, outs = jax.lax.scan(proj_o, None, jnp.swapaxes(out, 0, 1))
-    return jnp.swapaxes(outs, 0, 1), (k_pages, v_pages)
+    return jnp.swapaxes(outs, 0, 1), (k_pool, v_pool)
 
 
 def paged_prefill_chunk(params: dict, x: Array, cfg: ModelConfig,
                         start: Array, n_valid: Array,
-                        k_pages: Array, v_pages: Array, page_row: Array,
-                        window: Optional[Array],
+                        k_pool: Array, v_pool: Array, layer: Array,
+                        page_row: Array, window: Optional[Array],
                         ) -> Tuple[Array, Tuple[Array, Array]]:
-    """Chunked-prefill attention for ONE request against the paged cache.
+    """Chunked-prefill attention of layer ``layer`` for ONE request
+    against the paged cache.
 
     x: (1, cs, D) — the chunk's hidden states, right-padded to the engine's
     fixed ``prefill_chunk`` width (one compiled program for every prompt
     length); ``start``: tokens already prefilled (traced scalar);
-    ``n_valid`` ≤ cs: real tokens in this chunk; page_row: (max_pages,)
-    int32, trash-padded.
+    ``n_valid`` ≤ cs: real tokens in this chunk; k_pool/v_pool: the whole
+    (L, P, page_size, n_kv, hd) pools; layer: traced int32 layer index;
+    page_row: (max_pages,) int32, trash-padded.
 
-    Writes the chunk's K/V into the pages (padding rows scatter to the
-    trash page), then attends the chunk queries against the gathered
-    logical view under the standard causal(+window) mask.  Because masked
+    Writes the chunk's K/V in place into the pool at ``[layer, phys, off]``
+    (padding rows scatter to the trash page) and returns the updated
+    pools.  It attends the chunk queries against the gathered logical
+    view under the standard causal(+window) mask.  Because masked
     positions contribute exact zeros, every valid row's output is
     bit-identical to the full-sequence prefill's corresponding row — which
     is what lets the differential tests demand exact token equality with
@@ -480,19 +492,20 @@ def paged_prefill_chunk(params: dict, x: Array, cfg: ModelConfig,
     idx = start + jnp.arange(cs)      # logical positions of the chunk
     positions = idx[None]             # (1, cs)
     q, k, v = _project_qkv(params, x, cfg, positions)
-    if k_pages.dtype == jnp.int8:
+    if k_pool.dtype == jnp.int8:
         k, v = _quantize_kv_int8(k, v)
-    ps = k_pages.shape[1]
-    trash = k_pages.shape[0] - 1
+    ps = k_pool.shape[2]
+    trash = k_pool.shape[1] - 1
     valid_tok = jnp.arange(cs) < n_valid
     phys = jnp.where(valid_tok, page_row[idx // ps], trash)
     off = idx % ps
-    k_pages = k_pages.at[phys, off].set(k[0].astype(k_pages.dtype))
-    v_pages = v_pages.at[phys, off].set(v[0].astype(v_pages.dtype))
+    k_pool = k_pool.at[layer, phys, off].set(k[0].astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, phys, off].set(v[0].astype(v_pool.dtype))
     # the chunk reads its pages exactly once, through the same gather the
     # decode step and the fused verify window use
-    k_view, v_view = _paged_view(k_pages, v_pages, page_row[None], nkv, hd)
-    if k_pages.dtype == jnp.int8:
+    k_view, v_view = _paged_view(k_pool, v_pool, layer, page_row[None],
+                                 nkv, hd)
+    if k_pool.dtype == jnp.int8:
         # int8 pages: prefill reads the dequantised view in float (mirrors
         # the fixed-slot engine, whose prefill is float regardless)
         k_view = k_view.astype(jnp.float32) * KV_INT8_SCALE
@@ -506,7 +519,7 @@ def paged_prefill_chunk(params: dict, x: Array, cfg: ModelConfig,
     out = _direct_attention(qg, k_view.astype(x.dtype),
                             v_view.astype(x.dtype), mask)
     out = out.reshape(b, cs, nq * hd).astype(x.dtype)
-    return out @ params["wo"].astype(x.dtype), (k_pages, v_pages)
+    return out @ params["wo"].astype(x.dtype), (k_pool, v_pool)
 
 
 # ---------------------------------------------------------------------------

@@ -381,13 +381,44 @@ def supports_paged(cfg: ModelConfig) -> bool:
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                      dtype=jnp.bfloat16) -> dict:
     """Physical page pool: ``(L, P, page_size, n_kv, hd)`` per k/v.  The
-    caller (``serving/kv_cache.py``) includes its trash page in ``P``."""
+    caller (``serving/kv_cache.py``) includes its trash page in ``P``.
+    Every paged program carries the whole pool through its layer scan and
+    writes each token in place (:func:`_scan_paged_layers`), so a donated
+    pool is never copied."""
     if not supports_paged(cfg):
         raise ValueError(f"family {cfg.family!r} has no paged KV layout")
     hd = cfg.resolved_head_dim
     nkv = cfg.num_kv_heads
     shape = (cfg.num_layers, num_pages, page_size, nkv, hd)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def _scan_paged_layers(layer_fn, h: Array, params: dict, cache: dict,
+                       cfg: ModelConfig) -> Tuple[Array, dict]:
+    """Run ``layer_fn(hh, lp, k_pool, v_pool, layer, win) -> (hh, (k_pool,
+    v_pool))`` over the layer stack, with the K and V pools in the scan's
+    carry; returns the last hidden state and the updated cache.
+
+    The pools are carried, not scanned as ``xs``/``ys``: with ``ys`` XLA
+    builds a fresh output pool (a broadcast of zeros and a copy), writes
+    each layer's whole slice into it and copies the result out of the
+    loop — three passes over the whole pool in every program, even with
+    the pool donated.  Carried, each layer writes only its new tokens'
+    rows in place (``pool.at[layer, phys, off]``) and gathers its view
+    straight out of the pool (``attention._paged_view``).
+    """
+    layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+
+    def body(carry, xs):
+        hh, kp, vp = carry
+        lp, win, layer = xs
+        hh, (kp, vp) = layer_fn(hh, lp, kp, vp, layer, win)
+        return (hh, kp, vp), None
+
+    (h, kp, vp), _ = jax.lax.scan(
+        body, (h, cache["k"], cache["v"]),
+        (params["layers"], window_flags(cfg), layers))
+    return h, dict(cache, k=kp, v=vp)
 
 
 def _mlp_out(lp: dict, mlp_in: Array, cfg: ModelConfig, constrain: Constrain,
@@ -586,31 +617,29 @@ def paged_decode_step(params: dict, token: Array, pos: Array,
     The per-block math is the same ``rms → attn → rms → mlp`` pipeline as
     :func:`decode_step`'s uniform branch (attention reads through the
     shared ``_decode_attend``), so token streams are bit-identical to the
-    slot cache — the contract the differential tests pin down.
+    slot cache — the contract the differential tests pin down.  The pools
+    ride the layer scan's carry and each layer writes its new rows in
+    place (:func:`_scan_paged_layers`).
     """
     if not supports_paged(cfg):
         raise ValueError(f"family {cfg.family!r} has no paged decode path")
     cd = compute_dtype
     h = params["embed"].astype(cd)[token]  # (B, 1, D)
-    windows = window_flags(cfg)
 
-    def body(carry, xs):
-        hh = carry
-        lp, ck, cv, win = xs
-        out, (nk, nv) = A.paged_decode_step(
+    def layer(hh, lp, kp, vp, i, win):
+        out, pools = A.paged_decode_step(
             lp["attn"], L.rms_norm(hh, lp["ln1"], cfg.norm_eps), cfg,
-            ck, cv, page_table, pos, win, write_ok=write_ok)
+            kp, vp, i, page_table, pos, win, write_ok=write_ok)
         hh = constrain(hh + out, "activation")
         mlp_in = L.rms_norm(hh, lp["ln2"], cfg.norm_eps)
         hh = constrain(hh + _mlp_out(lp, mlp_in, cfg, constrain, cd),
                        "activation")
-        return hh, (nk, nv)
+        return hh, pools
 
-    h, (nk, nv) = jax.lax.scan(
-        body, h, (params["layers"], cache["k"], cache["v"], windows))
+    h, cache = _scan_paged_layers(layer, h, params, cache, cfg)
     h = L.rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits = (h @ params["lm_head"].astype(cd)).astype(jnp.float32)
-    return constrain(logits, "logits"), dict(cache, k=nk, v=nv)
+    return constrain(logits, "logits"), cache
 
 
 def paged_verify_step(params: dict, tokens: Array, pos: Array,
@@ -681,8 +710,9 @@ def _paged_verify_step_fused(params: dict, tokens: Array, pos: Array,
                              compute_dtype=jnp.bfloat16) -> Tuple[Array, dict]:
     """Layer-major fused verify window (see :func:`paged_verify_step`).
 
-    Outer scan over layers, ``attention.paged_verify_window`` per layer
-    (one page gather, W masked attends, per-token projections), then
+    Outer scan over layers (the pools carried and written in place, see
+    :func:`_scan_paged_layers`), ``attention.paged_verify_window`` per
+    layer (one page gather, W masked attends, per-token projections), then
     per-token head matmuls — bit-identical to the ``scan`` oracle at
     every in-window position.
     """
@@ -691,14 +721,11 @@ def _paged_verify_step_fused(params: dict, tokens: Array, pos: Array,
     cd = compute_dtype
     b, w = tokens.shape
     h = params["embed"].astype(cd)[tokens]  # (B, W, D)
-    windows = window_flags(cfg)
 
-    def body(carry, xs):
-        hh = carry
-        lp, ck, cv, win = xs
-        out, (nk, nv) = A.paged_verify_window(
+    def layer(hh, lp, kp, vp, i, win):
+        out, pools = A.paged_verify_window(
             lp["attn"], L.rms_norm(hh, lp["ln1"], cfg.norm_eps), cfg,
-            ck, cv, page_table, pos, n_valid, win)
+            kp, vp, i, page_table, pos, n_valid, win)
         hh = constrain(hh + out, "activation")
         mlp_in = L.rms_norm(hh, lp["ln2"], cfg.norm_eps)
 
@@ -707,10 +734,9 @@ def _paged_verify_step_fused(params: dict, tokens: Array, pos: Array,
 
         _, mo = jax.lax.scan(mlp_tok, None, jnp.swapaxes(mlp_in, 0, 1))
         hh = constrain(hh + jnp.swapaxes(mo, 0, 1), "activation")
-        return hh, (nk, nv)
+        return hh, pools
 
-    h, (nk, nv) = jax.lax.scan(
-        body, h, (params["layers"], cache["k"], cache["v"], windows))
+    h, cache = _scan_paged_layers(layer, h, params, cache, cfg)
     hn = L.rms_norm(h, params["final_norm"], cfg.norm_eps)
 
     def head_tok(_, hj):  # (B, D) — the oracle's (B, 1, D) head matmul
@@ -719,7 +745,7 @@ def _paged_verify_step_fused(params: dict, tokens: Array, pos: Array,
         return None, constrain(logits, "logits")[:, 0]
 
     _, logits = jax.lax.scan(head_tok, None, jnp.swapaxes(hn, 0, 1))
-    return jnp.swapaxes(logits, 0, 1), dict(cache, k=nk, v=nv)  # (B, W, V)
+    return jnp.swapaxes(logits, 0, 1), cache  # (B, W, V)
 
 
 def paged_draft_loop(params: dict, token: Array, pos: Array, n_valid: Array,
@@ -788,33 +814,31 @@ def paged_prefill_chunk(params: dict, tokens: Array, start: Array,
     Returns ``(logits (1, 1, V) f32 at the chunk's last valid position,
     updated cache)`` — the logits only mean anything on the final chunk,
     where they sample the request's first token exactly as the
-    full-sequence prefill would.
+    full-sequence prefill would.  The pools ride the layer scan's carry
+    and each layer writes the chunk's rows in place
+    (:func:`_scan_paged_layers`).
     """
     if not supports_paged(cfg):
         raise ValueError(f"family {cfg.family!r} has no paged prefill path")
     cd = compute_dtype
     h = params["embed"].astype(cd)[tokens]
     h = constrain(h, "activation")
-    windows = window_flags(cfg)
 
-    def body(carry, xs):
-        hh = carry
-        lp, ck, cv, win = xs
-        out, (nk, nv) = A.paged_prefill_chunk(
+    def layer(hh, lp, kp, vp, i, win):
+        out, pools = A.paged_prefill_chunk(
             lp["attn"], L.rms_norm(hh, lp["ln1"], cfg.norm_eps), cfg,
-            start, n_valid, ck, cv, page_row, win)
+            start, n_valid, kp, vp, i, page_row, win)
         hh = constrain(hh + out, "activation")
         mlp_in = L.rms_norm(hh, lp["ln2"], cfg.norm_eps)
         hh = constrain(hh + _mlp_out(lp, mlp_in, cfg, constrain, cd),
                        "activation")
-        return hh, (nk, nv)
+        return hh, pools
 
-    h, (nk, nv) = jax.lax.scan(
-        body, h, (params["layers"], cache["k"], cache["v"], windows))
+    h, cache = _scan_paged_layers(layer, h, params, cache, cfg)
     last = jax.lax.dynamic_slice_in_dim(h, n_valid - 1, 1, axis=1)
     last = L.rms_norm(last, params["final_norm"], cfg.norm_eps)
     logits = (last @ params["lm_head"].astype(cd)).astype(jnp.float32)
-    return constrain(logits, "logits"), dict(cache, k=nk, v=nv)
+    return constrain(logits, "logits"), cache
 
 
 def prefill(params: dict, tokens: Array, cfg: ModelConfig, max_len: int, *,

@@ -232,7 +232,7 @@ def test_paged_verify_window_impl_validation():
     lp = jax.tree.map(lambda a: a[0], params["layers"])  # layer-0 slice
     with pytest.raises(ValueError, match="verify attend impl"):
         A.paged_verify_window(lp["attn"], x, cfg,
-                              cache["k"][0], cache["v"][0], pt, pos,
+                              cache["k"], cache["v"], jnp.int32(0), pt, pos,
                               n_valid, None, attend_impl="tpu")
 
 
