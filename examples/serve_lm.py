@@ -33,7 +33,7 @@ print(f"loss: {out['losses'][0]:.3f} → {out['losses'][-1]:.3f}")
 params = tr.state.params
 
 print("\nserving 6 batched requests (exact matmuls) …")
-eng = ServeEngine(params, cfg, slots=3, max_len=128)
+eng = ServeEngine(params, cfg, max_batch=3, max_len=128)
 prompts = [list(ts.batch(100 + i)["tokens"][0][:8]) for i in range(6)]
 reqs = [eng.submit([int(t) for t in p], max_new_tokens=12) for p in prompts]
 t0 = time.time()
@@ -65,7 +65,7 @@ amm_params["layers"] = {k: v for k, v in params["layers"].items()
 amm_params["layers"]["amm_mlp"] = amm_stacked
 
 print("serving the same requests through the LUT-MU path …")
-eng2 = ServeEngine(amm_params, amm_cfg, slots=3, max_len=128)
+eng2 = ServeEngine(amm_params, amm_cfg, max_batch=3, max_len=128)
 reqs2 = [eng2.submit([int(t) for t in p], max_new_tokens=12) for p in prompts]
 done2 = eng2.run_until_drained()
 agree = np.mean([

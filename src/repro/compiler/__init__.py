@@ -254,7 +254,7 @@ def compile_lm_amm(
     pruned to the down-encode's split dims per ``cfg.amm``), quantises
     them at ``resolution`` (``float32`` / ``int8`` / ``int4``; default:
     ``cfg.amm.quantize_int8``'s historical meaning), and packs them.
-    Load side: ``ServeEngine.from_artifact`` /
+    Load side: ``repro.serving.load_engine`` /
     ``Artifact.splice_lm_params``.
 
     ``mesh_shape`` (e.g. ``{"data": 2, "model": 4}``) records the serving
@@ -309,7 +309,7 @@ def compile_lm_bundle(
     what keeps greedy agreement (and so acceptance rates) high.
 
     Load side: :func:`repro.compiler.artifact.load_bundle` /
-    ``SpeculativeEngine.from_bundle``.
+    ``repro.serving.load_engine``.
     """
     from repro.models.amm_mlp import AMM_RESOLUTIONS, quantize_amm_layer
 

@@ -220,8 +220,8 @@ def load_artifact(directory) -> Artifact:
     if manifest.get("kind") == "bundle":
         raise ArtifactError(
             f"{path} is a target+draft bundle — load it with load_bundle() "
-            "(or serve it with SpeculativeEngine.from_bundle / its target/ "
-            "sub-artifact with ServeEngine.from_artifact)")
+            "(or serve it with repro.serving.load_engine, which serves its "
+            "target/ half alone with speculative=False)")
     if manifest.get("version") != ARTIFACT_VERSION:
         raise ArtifactError(
             f"artifact version {manifest.get('version')!r} != supported "

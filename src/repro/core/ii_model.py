@@ -2,8 +2,8 @@
 
 The FPGA hardware quantities (clock-level II, ROM count, adder trees, power)
 do not transfer to TPU, but the paper's design-space trade-off — partition
-factors ``(S, E)`` against II and resources — is reproduced here as the
-analytic model used by ``benchmarks/bench_fig13_pareto.py``.
+factors ``(S, E)`` against II and resources — is reproduced here as an
+analytic model.
 
 Model (Section V-C2):
   * allocate+encode bottleneck:    ``α · I_i``            (per input vector)

@@ -110,8 +110,8 @@ def select_backend(
 ) -> str:
     """Shape/dtype/platform → backend name (the ``"auto"`` policy).
 
-    Rules (measured by ``benchmarks/bench_fig11_scalability.py``, documented
-    in ``docs/kernels.md``):
+    Rules (documented in ``docs/kernels.md``; the thresholds come from
+    XLA:CPU sweeps of the three backends and are unverified on the chip):
 
       1. off-TPU → ``ref``: interpret-mode Pallas exists for correctness,
          never for speed;

@@ -13,12 +13,10 @@ attends are independent.
 
 Two lowerings, selected by :func:`resolve_impl`:
 
-* ``xla`` (portable, every backend): :func:`verify_window_attend` — a
-  ``lax.scan`` over window positions of literally the same
-  :func:`decode_attend` the oracle uses, against the hoisted view.  Every
-  reduction therefore has the oracle's exact shape and order, which is what
-  lets greedy speculative streams stay *bit-identical* while reading the
-  pages once.
+* ``xla`` (portable, every backend): :func:`verify_window_attend` — every
+  window position runs the same :func:`decode_attend` the oracle uses,
+  against the hoisted view, which is what lets greedy speculative streams
+  stay *bit-identical* while reading the pages once.
 * ``pallas`` (TPU): :func:`verify_window_attend_pallas` — one kernel
   instance per batch row DMAs the row's pages into VMEM ``block_s``
   positions at a time and computes all W masked attends from the staged
@@ -36,7 +34,7 @@ scan oracle and the fused window cannot drift.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -134,21 +132,28 @@ def verify_window_attend(qg: Array, k_view: Array, v_view: Array,
     """All W window positions attend against one ``(B, S, n_kv, hd)`` view.
 
     qg: (B, W, n_kv, g, hd); ``pos``: (B,) first window position per row.
-    Position ``j`` reads with the mask ``kv_pos <= pos + j`` — a scan over
-    positions of the exact :func:`decode_attend` call the oracle makes, so
-    the result is bitwise the oracle's for every dtype.  The view is read
-    W times but *gathered* zero times here: hoisting the gather out of the
+    Position ``j`` reads with the mask ``kv_pos <= pos + j`` — the exact
+    :func:`decode_attend` call the oracle makes, so, compiled, the result
+    is bitwise the compiled oracle's for every dtype.  The view is read W
+    times but *gathered* zero times here: hoisting the gather out of the
     per-token loop is the whole point.
+
+    A float view is read by a ``lax.scan`` over positions, which keeps
+    every reduction at the oracle's shape and order.  An int8 view is read
+    by a ``vmap``: inside a loop body XLA:CPU emits the small ``s8 x s8 ->
+    s32`` dots inline and fails to compile some shapes (``g=2, hd=4`` over
+    8 positions), while integer dots are exact at any batching.
     """
-    w = qg.shape[1]
+    offs = jnp.arange(qg.shape[1], dtype=jnp.int32)
 
-    def one(_, xs):
-        qj, off = xs  # (B, n_kv, g, hd), scalar offset
-        out = decode_attend(qj[:, None], k_view, v_view, pos + off, window)
-        return None, out[:, 0]
+    def one(qj, off):  # (B, n_kv, g, hd), scalar offset
+        return decode_attend(qj[:, None], k_view, v_view, pos + off,
+                             window)[:, 0]
 
-    _, out = jax.lax.scan(
-        one, None, (jnp.swapaxes(qg, 0, 1), jnp.arange(w, dtype=jnp.int32)))
+    if k_view.dtype == jnp.int8:
+        return jax.vmap(one, in_axes=(1, 0), out_axes=1)(qg, offs)
+    _, out = jax.lax.scan(lambda _, xs: (None, one(*xs)), None,
+                          (jnp.swapaxes(qg, 0, 1), offs))
     return jnp.swapaxes(out, 0, 1)
 
 

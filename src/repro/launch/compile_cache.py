@@ -1,9 +1,9 @@
 """JAX's persistent compilation cache, placed for the entry points.
 
 Each entry point (``launch/serve.py``, ``launch/train.py``,
-``python -m repro.compiler``, ``benchmarks/run.py``, ``chip_smoke.py``)
-calls :func:`enable_compile_cache` once at start, under its ``__main__``
-guard — never at import, and never from tests.
+``python -m repro.compiler``, ``benchmarks/chip/run.py``,
+``chip_smoke.py``) calls :func:`enable_compile_cache` once at start,
+under its ``__main__`` guard — never at import, and never from tests.
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and this
 sets no other directory.  Otherwise the cache lives at the fixed

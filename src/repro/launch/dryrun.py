@@ -18,7 +18,7 @@ TPU v5e chips.  For each cell we
      from the optimized HLO.
 
 Results are cached incrementally as JSON under ``dryrun_results/`` so reruns
-only compile missing cells.  ``benchmarks/roofline.py`` consumes the JSON.
+only compile missing cells.  ``repro.analysis.roofline`` reads the JSON.
 
 Usage:
   python -m repro.launch.dryrun --arch gemma3-27b --shape train_4k

@@ -171,11 +171,9 @@ def main() -> None:
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--requests", type=int, default=4)
-    ap.add_argument("--max-batch", type=int, default=None,
+    ap.add_argument("--max-batch", type=int, default=2,
                     help="decode batch rows (continuous-batching engine); "
                          "also the slot count of the fixed-slot engine")
-    ap.add_argument("--slots", type=int, default=2,
-                    help="deprecated alias of --max-batch")
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--page-size", type=int, default=16,
@@ -187,10 +185,6 @@ def main() -> None:
                     help="KV page-pool size; smaller than "
                          "max_batch*ceil(max_len/page_size) turns on "
                          "eviction (host swap) under pressure")
-    ap.add_argument("--engine", choices=("paged", "fixed"), default=None,
-                    help="force an engine; default: paged (continuous "
-                         "batching) when the family supports it, else fixed "
-                         "slots")
     ap.add_argument("--no-prefix-cache", action="store_true",
                     help="disable radix prefix reuse (paged engine): every "
                          "request prefills from scratch")
@@ -313,9 +307,6 @@ def main() -> None:
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
         params = restore_into(template, Path(args.ckpt))
 
-    max_batch = args.max_batch or args.slots
-    use_paged = (args.engine or
-                 ("paged" if MD.supports_paged(cfg) else "fixed")) == "paged"
     art_kind = _artifact_kind(args.artifact) if args.artifact else None
     # one recorder feeds the summary table, the Prometheus snapshot, the
     # Chrome trace and GET /metrics; without the flags engines keep the
@@ -331,7 +322,7 @@ def main() -> None:
                                    dense_params=params)
     if rec is not None:
         attach_dispatch_hook(rec.registry)
-    kwargs = dict(max_batch=max_batch, max_len=args.max_len,
+    kwargs = dict(max_batch=args.max_batch, max_len=args.max_len,
                   page_size=args.page_size,
                   prefill_chunk=args.prefill_chunk,
                   num_pages=args.num_pages,
@@ -340,9 +331,9 @@ def main() -> None:
                   compute_dtype=dtype, mesh=mesh, recorder=rec)
 
     if args.speculative:
-        if not use_paged:
-            raise SystemExit("--speculative needs the paged engine (family "
-                             "with paged KV, --engine paged)")
+        if not MD.supports_paged(cfg):
+            raise SystemExit("--speculative needs the paged engine (a "
+                             "family with paged KV)")
         if mesh is not None:
             raise SystemExit("--speculative serving is single-device for "
                              "now (mesh support is a ROADMAP open item)")
@@ -378,9 +369,8 @@ def main() -> None:
         # load_engine sniffs artifact vs bundle (a bundle without
         # --speculative serves its full-resolution target half — the
         # stream-defining model and the speculative differential oracle)
-        engine = load_engine(args.artifact, params, cfg,
-                             engine=args.engine or "auto",
-                             speculative=False, **kwargs)
+        engine = load_engine(args.artifact, params, cfg, speculative=False,
+                             **kwargs)
 
     if args.http:
         _serve_http(engine, args, rec)

@@ -9,7 +9,7 @@ a deprecation cycle.
 """
 from repro.kernels.dispatch import attach_dispatch_hook  # noqa: F401
 from repro.serving.engine import (FixedSlotEngine, Request,  # noqa: F401
-                                  ServeEngine, make_engine)
+                                  ServeEngine)
 from repro.serving.handle import RequestHandle  # noqa: F401
 from repro.serving.http import AsyncServer  # noqa: F401
 from repro.serving.kv_cache import (PageAllocator, PagedKVCache,  # noqa: F401
@@ -61,6 +61,4 @@ __all__ = [
     "SloTracker",
     "SloThresholds",
     "slo_report",
-    # deprecated (one release; use load_engine)
-    "make_engine",
 ]

@@ -44,7 +44,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import itertools
-import warnings
 from collections import deque
 from typing import Deque, Dict, List, Optional
 
@@ -70,30 +69,6 @@ from repro.serving.scheduler import Request, Scheduler
 Array = jax.Array
 _NO_SPAN = contextlib.nullcontext()
 
-# loose sampling kwargs `submit` still accepts one release behind a
-# DeprecationWarning (pass a frozen SamplingParams instead)
-_LEGACY_SAMPLING_KW = ("temperature", "top_k", "top_p", "seed")
-
-
-def _resolve_sampling(sampling: Optional[SamplingParams],
-                      legacy: Dict) -> SamplingParams:
-    """Merge the deprecated loose sampling kwargs into a SamplingParams."""
-    unknown = sorted(set(legacy) - set(_LEGACY_SAMPLING_KW))
-    if unknown:
-        raise TypeError(
-            f"submit() got unexpected keyword argument(s) {unknown}")
-    if legacy:
-        warnings.warn(
-            f"submit(**{sorted(legacy)}) loose sampling kwargs are "
-            "deprecated; pass sampling=SamplingParams(...) instead",
-            DeprecationWarning, stacklevel=3)
-        if sampling is not None:
-            raise TypeError(
-                "pass either sampling=SamplingParams(...) or loose "
-                "sampling kwargs, not both")
-        return SamplingParams(**legacy)
-    return sampling if sampling is not None else SamplingParams()
-
 
 def _shape_tree(tree):
     return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
@@ -103,7 +78,14 @@ def _splice_artifact(art, params, cfg: ModelConfig, mesh):
     """Validate a loaded ``amm_lm`` artifact against ``cfg``, splice its
     LUT-MU tables into the dense params tree, and enable the AMM path with
     the artifact's recorded settings (shared by every engine — the
-    speculative engine calls it once per bundle half)."""
+    speculative engine calls it once per bundle half).
+
+    ``params`` is the dense-model tree the artifact was compiled against
+    (e.g. a restored checkpoint); the arch name must match.  When the
+    manifest records an intended mesh (``python -m repro.compiler lm
+    --mesh DxM``), a different serving ``mesh`` is reported but not
+    rejected — the sharding rules re-derive a valid placement for any
+    mesh."""
     from repro.compiler.artifact import ArtifactError
 
     if art.kind != "amm_lm":
@@ -135,14 +117,6 @@ def _splice_artifact(art, params, cfg: ModelConfig, mesh):
             log("serve", f"note: artifact was compiled for mesh {want}, "
                 f"serving on {have}")
     return art.splice_lm_params(params), cfg
-
-
-def _artifact_params_cfg(artifact_path, params, cfg: ModelConfig, mesh):
-    """Load an ``amm_lm`` artifact from disk and splice it (see
-    :func:`_splice_artifact`)."""
-    from repro.compiler.artifact import load_artifact
-
-    return _splice_artifact(load_artifact(artifact_path), params, cfg, mesh)
 
 
 class _Phase:
@@ -302,7 +276,7 @@ class ServeEngine:
     """Continuous-batching serving over a paged KV cache."""
 
     def __init__(self, params, cfg: ModelConfig, *, max_batch: int = None,
-                 slots: int = None, max_len: int = 256, page_size: int = 16,
+                 max_len: int = 256, page_size: int = 16,
                  prefill_chunk: int = 32, num_pages: int = None,
                  prefix_cache: bool = True, compute_dtype=jnp.float32,
                  mesh=None, recorder=None, verify_backend: str = "auto"):
@@ -324,9 +298,7 @@ class ServeEngine:
         # disabled cost is one host truthiness check and no device syncs.
         self.obs = recorder if recorder is not None else NULL_RECORDER
         self.spans = StepSpans(self.obs)
-        # ``slots`` is the fixed-slot engine's name for the same knob; keep
-        # it as an alias so call sites migrate freely.
-        self.max_batch = int(max_batch or slots or 4)
+        self.max_batch = int(max_batch or 4)
         self.max_len = max_len
         self.page_size = ps = int(page_size)
         self.prefill_chunk = int(prefill_chunk)
@@ -410,53 +382,21 @@ class ServeEngine:
                                        S.greedy_tokens_jit)
             _bind_quality(self.obs, self.params, self.cfg)
 
-    # -- construction ------------------------------------------------------
-    @classmethod
-    def from_artifact(cls, artifact_path, params, cfg: ModelConfig,
-                      **kwargs) -> "ServeEngine":
-        """Deprecated: use :func:`repro.serving.load_engine` (it sniffs
-        the artifact kind and picks the engine).  Kept one release as a
-        thin shim with identical behaviour."""
-        warnings.warn(
-            "ServeEngine.from_artifact is deprecated; use "
-            "repro.serving.load_engine(artifact_path, params, cfg, "
-            "engine='paged', ...)", DeprecationWarning, stacklevel=2)
-        return cls._from_artifact(artifact_path, params, cfg, **kwargs)
-
-    @classmethod
-    def _from_artifact(cls, artifact_path, params, cfg: ModelConfig,
-                       **kwargs) -> "ServeEngine":
-        """Serve a compiled ``amm_lm`` artifact: splice its LUT-MU tables
-        into ``params`` (replacing the dense MLPs) and enable the AMM path
-        with the artifact's recorded settings.
-
-        ``params`` is the dense-model params tree the artifact was compiled
-        against (e.g. a restored checkpoint); the arch name must match.
-        Pass ``mesh=`` to serve sharded; when the manifest records an
-        intended mesh (``python -m repro.compiler lm --mesh DxM``) a
-        mismatching engine mesh is reported but not rejected — the sharding
-        rules re-derive a valid placement for any mesh.
-        """
-        params, cfg = _artifact_params_cfg(artifact_path, params, cfg,
-                                           kwargs.get("mesh"))
-        return cls(params, cfg, **kwargs)
-
     # -- API -------------------------------------------------------------
     def submit(self, prompt: List[int],
                sampling: Optional[SamplingParams] = None, *,
                max_new_tokens: int = 16, eos_id: Optional[int] = None,
-               priority: int = 0, **legacy) -> RequestHandle:
+               priority: int = 0) -> RequestHandle:
         """Queue a request; returns a :class:`RequestHandle`.
 
         ``sampling`` is a frozen :class:`SamplingParams` (default greedy);
-        all other options are keyword-only.  Loose ``temperature=`` /
-        ``top_k=`` / ``top_p=`` / ``seed=`` kwargs still work one release
-        behind a ``DeprecationWarning``.
+        all other options are keyword-only.
         """
         req = Request(uid=next(self._uid), prompt=list(prompt),
                       max_new_tokens=max_new_tokens, eos_id=eos_id,
                       priority=priority,
-                      sampling=_resolve_sampling(sampling, legacy))
+                      sampling=(sampling if sampling is not None
+                                else SamplingParams()))
         self.sched.submit(req)
         return RequestHandle(self, req)
 
@@ -694,11 +634,11 @@ class FixedSlotEngine:
     eager prefill on admission.  The paged engine's differential-test
     oracle, and the serving path for SSM / hybrid / enc-dec families."""
 
-    def __init__(self, params, cfg: ModelConfig, *, slots: int = 4,
+    def __init__(self, params, cfg: ModelConfig, *, max_batch: int = None,
                  max_len: int = 256, compute_dtype=jnp.float32, mesh=None,
                  recorder=None):
         self.cfg = cfg
-        self.slots = slots
+        self.slots = slots = int(max_batch or 4)
         self.max_len = max_len
         self.cd = compute_dtype
         self.mesh = mesh
@@ -752,38 +692,18 @@ class FixedSlotEngine:
                                        S.greedy_tokens_jit)
             _bind_quality(self.obs, self.params, self.cfg)
 
-    # -- construction ------------------------------------------------------
-    @classmethod
-    def from_artifact(cls, artifact_path, params, cfg: ModelConfig,
-                      **kwargs) -> "FixedSlotEngine":
-        """Deprecated: use :func:`repro.serving.load_engine` with
-        ``engine='fixed'``.  Kept one release as a thin shim."""
-        warnings.warn(
-            "FixedSlotEngine.from_artifact is deprecated; use "
-            "repro.serving.load_engine(artifact_path, params, cfg, "
-            "engine='fixed', ...)", DeprecationWarning, stacklevel=2)
-        return cls._from_artifact(artifact_path, params, cfg, **kwargs)
-
-    @classmethod
-    def _from_artifact(cls, artifact_path, params, cfg: ModelConfig,
-                       **kwargs) -> "FixedSlotEngine":
-        """Serve a compiled ``amm_lm`` artifact through fixed slots (see
-        :meth:`ServeEngine._from_artifact`)."""
-        params, cfg = _artifact_params_cfg(artifact_path, params, cfg,
-                                           kwargs.get("mesh"))
-        return cls(params, cfg, **kwargs)
-
     # -- API -------------------------------------------------------------
     def submit(self, prompt: List[int],
                sampling: Optional[SamplingParams] = None, *,
                max_new_tokens: int = 16, eos_id: Optional[int] = None,
-               priority: int = 0, **legacy) -> RequestHandle:
+               priority: int = 0) -> RequestHandle:
         """Queue a request; returns a :class:`RequestHandle` (same
         contract as :meth:`ServeEngine.submit`)."""
         del priority  # fixed-slot admission is strictly FIFO
         req = Request(uid=next(self._uid), prompt=list(prompt),
                       max_new_tokens=max_new_tokens, eos_id=eos_id,
-                      sampling=_resolve_sampling(sampling, legacy))
+                      sampling=(sampling if sampling is not None
+                                else SamplingParams()))
         self.queue.append(req)
         if self.obs:
             self.obs.on_submit(req)
@@ -921,26 +841,3 @@ class FixedSlotEngine:
     def run_until_drained(self, max_steps: int = 10000) -> List[Request]:
         return _drain(self, max_steps)
 
-
-def _family_engine(params, cfg: ModelConfig, **kwargs):
-    """Pick the continuous-batching engine when the family supports paged
-    KV, else fall back to fixed slots (mapping ``max_batch`` to ``slots``
-    and dropping the paged-only kwargs)."""
-    if MD.supports_paged(cfg):
-        return ServeEngine(params, cfg, **kwargs)
-    max_batch = kwargs.pop("max_batch", None)
-    if max_batch is not None:
-        kwargs.setdefault("slots", max_batch)
-    for k in ("page_size", "prefill_chunk", "num_pages", "prefix_cache",
-              "verify_backend"):
-        kwargs.pop(k, None)
-    return FixedSlotEngine(params, cfg, **kwargs)
-
-
-def make_engine(params, cfg: ModelConfig, **kwargs):
-    """Deprecated: use :func:`repro.serving.load_engine` (``source=None``
-    gives the same family dispatch).  Kept one release as a thin shim."""
-    warnings.warn(
-        "make_engine is deprecated; use repro.serving.load_engine(None, "
-        "params, cfg, ...)", DeprecationWarning, stacklevel=2)
-    return _family_engine(params, cfg, **kwargs)

@@ -1,9 +1,6 @@
 """``load_engine`` — the one serving factory.
 
-Collapses the historical construction paths (``ServeEngine.from_artifact``,
-``SpeculativeEngine.from_artifacts`` / ``from_bundle``, ``make_engine``)
-into a single entry point that sniffs what ``source`` is and picks the
-right engine:
+Sniffs what ``source`` is and picks the engine:
 
 ====================================  =====================================
 ``source``                            engine
@@ -22,25 +19,25 @@ a loaded ``Artifact`` object          same as an ``amm_lm`` path
                                       in-memory artifacts
 ====================================  =====================================
 
-``engine=`` overrides the paged/fixed choice (``"auto"`` | ``"paged"`` |
-``"fixed"``); every other keyword is forwarded to the engine constructor
+Every other keyword is forwarded to the engine constructor
 (``max_batch``, ``max_len``, ``page_size``, ``prefill_chunk``,
-``num_pages``, ``prefix_cache``, ``spec_k``, ``mesh``, ``recorder``, ...).
-The old entry points remain one release as thin ``DeprecationWarning``
-shims; ``tests/test_api.py`` pins their equivalence to this factory.
+``num_pages``, ``prefix_cache``, ``spec_k``, ``mesh``, ``recorder``, ...);
+the fixed-slot engine drops the paged-only ones.
 """
 from __future__ import annotations
 
 import os
+from pathlib import Path
 from typing import Optional
 
 from repro.models import model as MD
 from repro.models.config import ModelConfig
-from repro.serving.engine import (FixedSlotEngine, ServeEngine,
-                                  _family_engine, _splice_artifact)
+from repro.serving.engine import FixedSlotEngine, ServeEngine, _splice_artifact
 from repro.serving.speculative import SpeculativeEngine
 
-_ENGINE_CHOICES = ("auto", "paged", "fixed")
+# ServeEngine options the fixed-slot engine has no use for
+_PAGED_ONLY = ("page_size", "prefill_chunk", "num_pages", "prefix_cache",
+               "verify_backend")
 
 
 def _is_pathlike(source) -> bool:
@@ -53,80 +50,61 @@ def _is_artifact(source) -> bool:
     return hasattr(source, "kind") and hasattr(source, "manifest")
 
 
-def _fixed_kwargs(kwargs):
-    # FixedSlotEngine calls the batch knob ``slots`` and has no paged knobs
-    slots = kwargs.pop("max_batch", None)
-    if slots is not None:
-        kwargs.setdefault("slots", slots)
-    for k in ("page_size", "prefill_chunk", "num_pages", "prefix_cache",
-              "verify_backend"):
-        kwargs.pop(k, None)
-    return kwargs
-
-
-def _paged_or_fixed(engine: str, params, cfg: ModelConfig, kwargs):
-    if engine == "fixed":
-        return FixedSlotEngine(params, cfg, **_fixed_kwargs(kwargs))
-    if engine == "paged":
-        return ServeEngine(params, cfg, **kwargs)
-    return _family_engine(params, cfg, **kwargs)
+def _engine_for_family(params, cfg: ModelConfig, opts):
+    """The paged engine when the family has a paged KV layout, else fixed
+    slots without the paged-only options."""
+    if MD.supports_paged(cfg):
+        return ServeEngine(params, cfg, **opts)
+    return FixedSlotEngine(params, cfg, **{
+        k: v for k, v in opts.items() if k not in _PAGED_ONLY})
 
 
 def load_engine(source, params, cfg: ModelConfig, *,
-                engine: str = "auto", speculative: Optional[bool] = None,
-                **opts):
+                speculative: Optional[bool] = None, **opts):
     """Build a serving engine from ``source`` (see module docstring).
 
-    ``engine`` forces paged/fixed dispatch; ``speculative`` controls what
-    a bundle becomes (default True → :class:`SpeculativeEngine`; False →
-    the bundle's target half through the paged/fixed engine).  ``params``
-    is always the dense-model tree artifacts were compiled against.
+    ``speculative`` controls what a bundle becomes (default True →
+    :class:`SpeculativeEngine`; False → the bundle's target half through
+    the paged/fixed engine).  ``params`` is always the dense-model tree
+    artifacts were compiled against.
     """
-    if engine not in _ENGINE_CHOICES:
-        raise ValueError(
-            f"engine must be one of {_ENGINE_CHOICES}, got {engine!r}")
-
     # (target, draft) in-memory artifact pair → speculative
     if isinstance(source, (tuple, list)):
         if len(source) != 2:
             raise ValueError(
                 f"artifact-pair source must be (target, draft), got "
                 f"{len(source)} elements")
-        if speculative is False:
-            t_params, t_cfg = _splice_artifact(source[0], params, cfg,
-                                               opts.get("mesh"))
-            return _paged_or_fixed(engine, t_params, t_cfg, opts)
-        return SpeculativeEngine._from_artifacts(source[0], source[1],
-                                                 params, cfg, **opts)
+        if speculative is not False:
+            return SpeculativeEngine._from_artifacts(source[0], source[1],
+                                                     params, cfg, **opts)
+        source = source[0]
 
-    # a single loaded artifact object → splice and dispatch
-    if _is_artifact(source):
-        s_params, s_cfg = _splice_artifact(source, params, cfg,
-                                           opts.get("mesh"))
-        return _paged_or_fixed(engine, s_params, s_cfg, opts)
-
-    # a path → sniff the manifest kind
+    # a path → sniff the manifest kind, then load it
     if _is_pathlike(source):
-        from pathlib import Path
-
-        from repro.compiler.artifact import peek_manifest
+        from repro.compiler.artifact import load_artifact, peek_manifest
 
         kind = peek_manifest(source).get("kind")
         if kind == "bundle":
-            if speculative is False:
-                return _load_artifact_path(
-                    Path(source) / "target", params, cfg, engine, opts)
-            return SpeculativeEngine._from_bundle(source, params, cfg,
-                                                  **opts)
-        if kind == "amm_lm":
+            if speculative is not False:
+                return SpeculativeEngine._from_bundle(source, params, cfg,
+                                                      **opts)
+            source = load_artifact(Path(source) / "target")
+        elif kind == "amm_lm":
             if speculative:
                 raise ValueError(
                     "speculative=True needs a target+draft bundle source, "
                     f"got an {kind!r} artifact — compile one with "
                     "`python -m repro.compiler bundle`")
-            return _load_artifact_path(source, params, cfg, engine, opts)
-        raise ValueError(
-            f"cannot serve artifact kind {kind!r} from {source!r}")
+            source = load_artifact(source)
+        else:
+            raise ValueError(
+                f"cannot serve artifact kind {kind!r} from {source!r}")
+
+    # a single loaded artifact → splice its tables into the dense params
+    if _is_artifact(source):
+        params, cfg = _splice_artifact(source, params, cfg,
+                                       opts.get("mesh"))
+        return _engine_for_family(params, cfg, opts)
 
     # no source → plain dense (or amm-enabled cfg) serving
     if source is None:
@@ -134,19 +112,8 @@ def load_engine(source, params, cfg: ModelConfig, *,
             raise ValueError(
                 "speculative=True needs a bundle path or an artifact pair "
                 "as source")
-        return _paged_or_fixed(engine, params, cfg, opts)
+        return _engine_for_family(params, cfg, opts)
 
     raise TypeError(
         f"unsupported source {type(source).__name__!r}: expected None, a "
         "path, a loaded Artifact, or a (target, draft) pair")
-
-
-def _load_artifact_path(path, params, cfg: ModelConfig, engine: str, opts):
-    # auto resolves via the family (splicing only toggles AMM settings, so
-    # paged support is decided by the family as usual)
-    if engine == "auto":
-        engine = "paged" if MD.supports_paged(cfg) else "fixed"
-    if engine == "paged":
-        return ServeEngine._from_artifact(path, params, cfg, **opts)
-    return FixedSlotEngine._from_artifact(path, params, cfg,
-                                          **_fixed_kwargs(opts))

@@ -69,7 +69,6 @@ regime distributionally (see docs/sampling.md for the proof sketch).
 """
 from __future__ import annotations
 
-import warnings
 from typing import Dict, List, Optional
 
 import jax
@@ -211,18 +210,6 @@ class SpeculativeEngine(ServeEngine):
 
     # -- construction ------------------------------------------------------
     @classmethod
-    def from_artifacts(cls, target_art, draft_art, params,
-                       cfg: ModelConfig, **kwargs) -> "SpeculativeEngine":
-        """Deprecated: use :func:`repro.serving.load_engine` with a
-        ``(target_art, draft_art)`` source.  Kept one release as a shim."""
-        warnings.warn(
-            "SpeculativeEngine.from_artifacts is deprecated; use "
-            "repro.serving.load_engine((target_art, draft_art), params, "
-            "cfg, ...)", DeprecationWarning, stacklevel=2)
-        return cls._from_artifacts(target_art, draft_art, params, cfg,
-                                   **kwargs)
-
-    @classmethod
     def _from_artifacts(cls, target_art, draft_art, params,
                         cfg: ModelConfig, **kwargs) -> "SpeculativeEngine":
         """Build from two loaded/in-memory ``amm_lm`` artifacts: both are
@@ -232,17 +219,6 @@ class SpeculativeEngine(ServeEngine):
         params_t, cfg_t = _splice_artifact(target_art, params, cfg, mesh)
         params_d, cfg_d = _splice_artifact(draft_art, params, cfg, mesh)
         return cls(params_t, cfg_t, params_d, draft_cfg=cfg_d, **kwargs)
-
-    @classmethod
-    def from_bundle(cls, bundle_path, params, cfg: ModelConfig,
-                    **kwargs) -> "SpeculativeEngine":
-        """Deprecated: use :func:`repro.serving.load_engine` (a bundle
-        path is sniffed automatically).  Kept one release as a shim."""
-        warnings.warn(
-            "SpeculativeEngine.from_bundle is deprecated; use "
-            "repro.serving.load_engine(bundle_path, params, cfg, ...)",
-            DeprecationWarning, stacklevel=2)
-        return cls._from_bundle(bundle_path, params, cfg, **kwargs)
 
     @classmethod
     def _from_bundle(cls, bundle_path, params, cfg: ModelConfig,

@@ -3,13 +3,14 @@
 Usage in a test module::
 
     try:
-        from hypothesis import given, settings, strategies as st
+        from hypothesis import example, given, settings, strategies as st
     except ModuleNotFoundError:
-        from _hypothesis_stub import given, settings, st
+        from _hypothesis_stub import example, given, settings, st
 
 When hypothesis is absent, ``@given`` replaces the property test with a
 zero-arg skipped stand-in (so the rest of the module still collects and
-runs); ``@settings`` is a no-op and ``st.*`` returns inert placeholders.
+runs); ``@settings`` and ``@example`` are no-ops and ``st.*`` returns
+inert placeholders.
 Install the real thing with ``pip install -r requirements-dev.txt``.
 """
 import pytest
@@ -29,6 +30,12 @@ st = _AnyStrategy()
 
 
 def settings(*args, **kwargs):
+    def deco(fn):
+        return fn
+    return deco
+
+
+def example(*args, **kwargs):
     def deco(fn):
         return fn
     return deco

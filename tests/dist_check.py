@@ -198,7 +198,7 @@ def main(argv=None) -> int:
         return ServeEngine(params, cfg, max_batch=8, **kw)
 
     def fixed():
-        return FixedSlotEngine(params, cfg, slots=8, max_len=32)
+        return FixedSlotEngine(params, cfg, max_batch=8, max_len=32)
 
     def spec(draft):
         return lambda: SpeculativeEngine(params, cfg, draft, spec_k=3,

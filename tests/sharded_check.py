@@ -95,7 +95,7 @@ def check_engine_parity(amm):
     prompts = [[1, 2, 3], [7, 5], [9, 9, 9, 2], [4, 4]]
 
     def run(mesh):
-        eng = ServeEngine(params, cfg, slots=2, max_len=64, mesh=mesh)
+        eng = ServeEngine(params, cfg, max_batch=2, max_len=64, mesh=mesh)
         reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
         eng.run_until_drained()
         return [r.generated for r in reqs]

@@ -1,17 +1,14 @@
-"""API-redesign suite: the consolidated ``load_engine`` factory, the
-``RequestHandle`` contract, and the deprecation shims.
+"""API suite: the ``load_engine`` factory and the ``RequestHandle``
+contract.
 
-Pins the PR-8 satellite guarantees:
+Pins:
 
   * ``load_engine`` sniffs artifact vs bundle sources and picks the
-    paged / fixed-slot / speculative engine (with ``engine=`` overrides);
-  * the old entry points (``ServeEngine.from_artifact``,
-    ``SpeculativeEngine.from_artifacts`` / ``from_bundle``,
-    ``make_engine``) still work one release behind ``DeprecationWarning``
-    and produce engines equivalent to the factory's;
-  * ``submit()`` returns a :class:`RequestHandle` with the shared
-    lifecycle surface, and loose ``temperature=`` kwargs keep working
-    one release behind ``DeprecationWarning``;
+    paged / fixed-slot / speculative engine, the paged-or-fixed choice
+    made from the model family alone;
+  * ``submit()`` takes a frozen :class:`SamplingParams` and keyword-only
+    options, and returns a :class:`RequestHandle` with the shared
+    lifecycle surface;
   * ``repro.serving.__all__`` is the supported surface and imports clean.
 """
 import dataclasses
@@ -23,8 +20,7 @@ import pytest
 from repro.configs import get_config
 from repro.models import model as MD
 from repro.serving import (FixedSlotEngine, RequestHandle, SamplingParams,
-                           ServeEngine, SpeculativeEngine, load_engine,
-                           make_engine)
+                           ServeEngine, SpeculativeEngine, load_engine)
 
 
 def _tiny_cfg(amm=False):
@@ -59,29 +55,40 @@ def artifacts(tmp_path_factory):
 
 
 # ---------------------------------------------------------------------------
-# load_engine: source sniffing + engine overrides.
+# load_engine: source sniffing and the family's engine.
 # ---------------------------------------------------------------------------
 
-
-def test_load_engine_none_source_family_dispatch(setup):
-    cfg, params = setup
-    eng = load_engine(None, params, cfg, max_batch=2, max_len=64)
-    assert isinstance(eng, ServeEngine)
-    assert not isinstance(eng, SpeculativeEngine)
-    ssm = get_config("mamba2-370m", reduced=True)
-    eng = load_engine(None, MD.init_params(ssm, jax.random.PRNGKey(0)), ssm,
-                      max_batch=4, max_len=32, page_size=4)
-    assert isinstance(eng, FixedSlotEngine)
-    assert eng.slots == 4  # max_batch maps to slots on the fixed fallback
+# the paged engine's options the fixed-slot engine drops
+PAGED_ONLY = dict(page_size=4, prefill_chunk=4, num_pages=30,
+                  prefix_cache=False, verify_backend="scan")
 
 
-def test_load_engine_engine_override(setup):
-    cfg, params = setup
-    eng = load_engine(None, params, cfg, engine="fixed", max_batch=2,
-                      max_len=64, page_size=4)
-    assert isinstance(eng, FixedSlotEngine)
-    with pytest.raises(ValueError, match="engine must be one of"):
-        load_engine(None, params, cfg, engine="turbo")
+@pytest.mark.parametrize("arch,paged", [
+    ("qwen3-14b", True),              # dense
+    ("mamba2-370m", False),           # ssm
+    ("jamba-1.5-large-398b", False),  # hybrid
+    ("whisper-tiny", False),          # audio enc-dec
+])
+def test_load_engine_none_source_family_dispatch(arch, paged):
+    """``source=None`` serves a family with a paged KV layout through
+    :class:`ServeEngine` and every other family through
+    :class:`FixedSlotEngine`; ``max_batch`` reaches either engine, and
+    the fixed-slot path drops the paged-only options."""
+    cfg = get_config(arch, reduced=True)
+    assert MD.supports_paged(cfg) == paged
+    params = MD.init_params(cfg, jax.random.PRNGKey(0))
+    eng = load_engine(None, params, cfg, max_batch=3, max_len=32,
+                      **PAGED_ONLY)
+    if paged:
+        assert type(eng) is ServeEngine
+        assert eng.max_batch == 3
+        assert (eng.page_size, eng.prefill_chunk) == (4, 4)
+        assert eng.kv.num_pages == 30 and eng.verify_backend == "scan"
+    else:
+        assert type(eng) is FixedSlotEngine
+        assert eng.slots == 3
+        with pytest.raises(ValueError, match="FixedSlotEngine"):
+            ServeEngine(params, cfg)
     with pytest.raises(ValueError, match="bundle"):
         load_engine(None, params, cfg, speculative=True)
 
@@ -91,9 +98,6 @@ def test_load_engine_artifact_path(artifacts):
     eng = load_engine(root / "lm", params, cfg, max_batch=2, max_len=64)
     assert isinstance(eng, ServeEngine)
     assert eng.cfg.amm.enabled  # the artifact's LUT-MU path is spliced in
-    eng = load_engine(str(root / "lm"), params, cfg, engine="fixed",
-                      max_batch=2, max_len=64)
-    assert isinstance(eng, FixedSlotEngine)
     with pytest.raises(ValueError, match="bundle"):
         load_engine(root / "lm", params, cfg, speculative=True)
 
@@ -124,50 +128,6 @@ def test_load_engine_artifact_objects(artifacts):
         load_engine((target,), params, cfg)
     with pytest.raises(TypeError, match="unsupported source"):
         load_engine(42, params, cfg)
-
-
-# ---------------------------------------------------------------------------
-# Deprecation shims: warn, and stay stream-equivalent to the factory.
-# ---------------------------------------------------------------------------
-
-PROMPTS = [[1, 2, 3], [7, 5], [9, 9, 9, 2]]
-
-
-def _streams(eng):
-    handles = [eng.submit(p, max_new_tokens=6) for p in PROMPTS]
-    eng.run_until_drained()
-    return [h.tokens() for h in handles]
-
-
-def test_make_engine_shim_equivalent(setup):
-    cfg, params = setup
-    with pytest.warns(DeprecationWarning, match="load_engine"):
-        old = make_engine(params, cfg, max_batch=2, max_len=64)
-    new = load_engine(None, params, cfg, max_batch=2, max_len=64)
-    assert type(old) is type(new)
-    assert _streams(old) == _streams(new)
-
-
-def test_from_artifact_shim_equivalent(artifacts):
-    cfg, params, root, _ = artifacts
-    with pytest.warns(DeprecationWarning, match="load_engine"):
-        old = ServeEngine.from_artifact(root / "lm", params, cfg,
-                                        max_batch=2, max_len=64)
-    new = load_engine(root / "lm", params, cfg, max_batch=2, max_len=64)
-    assert _streams(old) == _streams(new)
-    with pytest.warns(DeprecationWarning, match="load_engine"):
-        FixedSlotEngine.from_artifact(root / "lm", params, cfg, slots=2,
-                                      max_len=64)
-
-
-def test_from_bundle_shim_equivalent(artifacts):
-    cfg, params, root, _ = artifacts
-    with pytest.warns(DeprecationWarning, match="load_engine"):
-        old = SpeculativeEngine.from_bundle(root / "bundle", params, cfg,
-                                            max_batch=2, max_len=64)
-    new = load_engine(root / "bundle", params, cfg, max_batch=2, max_len=64)
-    assert old.spec_k == new.spec_k
-    assert _streams(old) == _streams(new)
 
 
 # ---------------------------------------------------------------------------
@@ -211,7 +171,7 @@ def test_handle_lifecycle_fixed_slot(setup):
     cfg, params = setup
     ssm = get_config("mamba2-370m", reduced=True)
     eng = FixedSlotEngine(MD.init_params(ssm, jax.random.PRNGKey(0)), ssm,
-                          slots=1, max_len=32)
+                          max_batch=1, max_len=32)
     a = eng.submit([1, 2, 3], max_new_tokens=3)
     b = eng.submit([4, 5], max_new_tokens=3)
     assert a.status == "queued"
@@ -244,22 +204,8 @@ def test_handle_async_stream(setup):
 
 
 # ---------------------------------------------------------------------------
-# submit(): frozen SamplingParams + legacy loose kwargs.
+# submit(): frozen SamplingParams and keyword-only options.
 # ---------------------------------------------------------------------------
-
-
-def test_submit_legacy_sampling_kwargs(setup):
-    cfg, params = setup
-    eng = ServeEngine(params, cfg, max_batch=2, max_len=64)
-    with pytest.warns(DeprecationWarning, match="SamplingParams"):
-        old = eng.submit([1, 2, 3], max_new_tokens=6, temperature=0.9,
-                         top_k=4, seed=11)
-    new = eng.submit([1, 2, 3], max_new_tokens=6,
-                     sampling=SamplingParams(temperature=0.9, top_k=4,
-                                             seed=11))
-    eng.run_until_drained()
-    assert old.sampling == new.sampling
-    assert old.tokens() == new.tokens()
 
 
 def test_submit_rejects_bad_kwargs(setup):
@@ -267,7 +213,9 @@ def test_submit_rejects_bad_kwargs(setup):
     eng = ServeEngine(params, cfg, max_batch=1, max_len=64)
     with pytest.raises(TypeError, match="unexpected keyword"):
         eng.submit([1, 2, 3], temperatur=0.9)  # typo must not pass silently
-    with pytest.raises(TypeError, match="not both"):
+    with pytest.raises(TypeError, match="unexpected keyword"):
+        eng.submit([1, 2, 3], temperature=0.9)  # sampling=SamplingParams
+    with pytest.raises(TypeError, match="unexpected keyword"):
         eng.submit([1, 2, 3], sampling=SamplingParams(), temperature=0.9)
 
 

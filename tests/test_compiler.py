@@ -165,10 +165,10 @@ def test_planner_records_backend_and_pruning(toy):
 
 
 def test_lm_artifact_serves(tmp_path):
-    """compile_lm_amm → ServeEngine.from_artifact completes requests."""
+    """compile_lm_amm → load_engine(artifact dir) completes requests."""
     from repro.configs import get_config
     from repro.models import model as MD
-    from repro.serving import ServeEngine
+    from repro.serving import ServeEngine, load_engine
 
     cfg = get_config("qwen3-14b", reduced=True)
     cfg = dataclasses.replace(cfg, num_layers=2, d_model=64, d_ff=128,
@@ -182,7 +182,8 @@ def test_lm_artifact_serves(tmp_path):
     out = tmp_path / "lm_art"
     compile_lm_amm(params, cfg, tokens, out=str(out))
 
-    eng = ServeEngine.from_artifact(out, params, cfg, slots=2, max_len=64)
+    eng = load_engine(out, params, cfg, max_batch=2, max_len=64)
+    assert isinstance(eng, ServeEngine)
     reqs = [eng.submit([1, 2, 3], max_new_tokens=4) for _ in range(3)]
     done = eng.run_until_drained()
     assert len(done) == 3
@@ -191,11 +192,11 @@ def test_lm_artifact_serves(tmp_path):
     # arch mismatch is rejected
     other = dataclasses.replace(cfg, name="not-this-arch")
     with pytest.raises(ArtifactError, match="arch"):
-        ServeEngine.from_artifact(out, params, other)
+        load_engine(out, params, other)
     # same arch name but different geometry (reduced vs full) is rejected
     bigger = dataclasses.replace(cfg, num_layers=cfg.num_layers + 2)
     with pytest.raises(ArtifactError, match="layers"):
-        ServeEngine.from_artifact(out, params, bigger)
+        load_engine(out, params, bigger)
 
 
 def test_cli_compile_verify(tmp_path):

@@ -7,14 +7,15 @@ Runs under real hypothesis in CI (``requirements-dev.txt``); without it
 the ``@given`` tests skip via ``_hypothesis_stub`` and the fixed
 corner-grid test below still pins the same property on the edge shapes.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
 except ModuleNotFoundError:
-    from _hypothesis_stub import given, settings, st
+    from _hypothesis_stub import example, given, settings, st
 
 from repro.core import maddness as M
 from repro.kernels import dispatch as D
@@ -128,9 +129,10 @@ def test_corner_grid_backend_parity(shape, resolution):
 
 
 # ---------------------------------------------------------------------------
-# PR-9: the portable fused-verify lowering over a random shape grid.  The
-# property is *bitwise* for every dtype — verify_window_attend is a scan
-# of literally the oracle's decode_attend, so no tolerance is ever needed.
+# The portable fused-verify lowering over a random shape grid.  The
+# property is *bitwise* for every dtype: each window position of the
+# compiled ``verify_window_attend`` equals the compiled one-token
+# ``decode_attend`` read at that position, as the engine runs both.
 # ---------------------------------------------------------------------------
 
 
@@ -147,28 +149,31 @@ def _check_verify_window_bitwise(b, w, s, nkv, g, hd, int8, windowed, seed):
     q = jnp.asarray(rng.normal(size=(b, w, nkv, g, hd)), jnp.float32)
     pos = jnp.asarray(rng.integers(0, max(1, s - w), b), jnp.int32)
     win = jnp.asarray(rng.integers(1, s + 1), jnp.int32) if windowed else None
-    got = FV.verify_window_attend(q, kv, vv, pos, win)
+    got = jax.jit(FV.verify_window_attend)(q, kv, vv, pos, win)
+    oracle = jax.jit(FV.decode_attend)
     msg = (f"b={b} w={w} s={s} nkv={nkv} g={g} hd={hd} int8={int8} "
            f"windowed={windowed} seed={seed}")
     for j in range(w):
-        want = FV.decode_attend(q[:, j:j + 1], kv, vv, pos + j, win)
+        want = oracle(q[:, j:j + 1], kv, vv, pos + j, win)
         np.testing.assert_array_equal(np.asarray(got[:, j]),
                                       np.asarray(want[:, 0]),
                                       err_msg=f"{msg} j={j}")
 
 
 @settings(max_examples=25, deadline=None)
-@given(data=st.data())
-def test_random_verify_window_matches_oracle(data):
-    b = data.draw(st.integers(1, 4), label="b")
-    w = data.draw(st.integers(1, 6), label="w")
-    s = data.draw(st.sampled_from([8, 16, 24, 64]), label="s")
-    nkv = data.draw(st.sampled_from([1, 2]), label="nkv")
-    g = data.draw(st.sampled_from([1, 2, 4]), label="g")
-    hd = data.draw(st.sampled_from([4, 8, 32]), label="hd")
-    int8 = data.draw(st.booleans(), label="int8")
-    windowed = data.draw(st.booleans(), label="windowed")
-    seed = data.draw(st.integers(0, 2**20), label="seed")
+@given(b=st.integers(1, 4), w=st.integers(1, 6),
+       s=st.sampled_from([8, 16, 24, 64]), nkv=st.sampled_from([1, 2]),
+       g=st.sampled_from([1, 2, 4]), hd=st.sampled_from([4, 8, 32]),
+       int8=st.booleans(), windowed=st.booleans(),
+       seed=st.integers(0, 2**20))
+# one int8 step apart at j=5 when the oracle ran op by op, outside jit
+@example(b=4, w=6, s=16, nkv=2, g=4, hd=8, int8=True, windowed=True,
+         seed=738198)
+# the int8 window as a lax.scan did not compile on XLA:CPU at this shape
+@example(b=1, w=5, s=8, nkv=1, g=2, hd=4, int8=True, windowed=True,
+         seed=238267)
+def test_random_verify_window_matches_oracle(b, w, s, nkv, g, hd, int8,
+                                             windowed, seed):
     _check_verify_window_bitwise(b, w, s, nkv, g, hd, int8, windowed, seed)
 
 

@@ -3,8 +3,8 @@
 Three layers of pinning, from kernel to model:
 
 1. the portable XLA lowering (``verify_window_attend``) is **bitwise** the
-   per-token ``decode_attend`` oracle for every dtype — it is a scan of
-   literally that function against the hoisted view;
+   per-token ``decode_attend`` oracle for every dtype, both compiled — it
+   runs that function per position against the hoisted view;
 2. the Pallas kernel (interpret mode on CPU) matches the portable lowering
    bitwise on the int8 KV path at *every* staging size (int32 accumulation
    is order-independent) and ``allclose`` on the float path (blockwise f32
@@ -63,16 +63,18 @@ def _mk_paged(seed, *, b=2, max_pages=4, page_size=8, nkv=2, hd=8, w=3,
 @pytest.mark.parametrize("int8", [False, True])
 @pytest.mark.parametrize("window", [None, 7])
 def test_portable_lowering_is_bitwise_the_oracle(int8, window):
-    """Scan-of-decode_attend vs W independent decode_attend calls on the
-    gathered view: bitwise for every dtype and window flag."""
+    """The window's per-position decode_attend vs W independent
+    decode_attend calls on the gathered view, both compiled as the engine
+    runs them: bitwise for every dtype and window flag."""
     q, kp, vp, pt, pos = _mk_paged(0, int8=int8)
     nkv, hd = kp.shape[2], kp.shape[3]
     k_view = kp[pt].reshape(pt.shape[0], -1, nkv, hd)
     v_view = vp[pt].reshape(pt.shape[0], -1, nkv, hd)
     win = None if window is None else jnp.asarray(window, jnp.int32)
-    got = FV.verify_window_attend(q, k_view, v_view, pos, win)
+    got = jax.jit(FV.verify_window_attend)(q, k_view, v_view, pos, win)
+    oracle = jax.jit(FV.decode_attend)
     for j in range(q.shape[1]):
-        want = FV.decode_attend(q[:, j:j + 1], k_view, v_view, pos + j, win)
+        want = oracle(q[:, j:j + 1], k_view, v_view, pos + j, win)
         np.testing.assert_array_equal(
             np.asarray(got[:, j]), np.asarray(want[:, 0]),
             err_msg=f"int8={int8} window={window} j={j}")

@@ -177,7 +177,7 @@ def test_engines_default_to_null_recorder(setup):
         NULL_RECORDER
     ssm = get_config("mamba2-370m", reduced=True)
     fixed = FixedSlotEngine(MD.init_params(ssm, jax.random.PRNGKey(0)), ssm,
-                            slots=1, max_len=32)
+                            max_batch=1, max_len=32)
     assert fixed.obs is NULL_RECORDER
     # the speculative engine keeps telemetry always-on (PR-5 `stats`
     # back-compat): metrics-only recorder, no tracer
@@ -236,10 +236,10 @@ def test_paged_bitexact_with_recording_under_eviction(setup):
 
 def test_fixed_slot_bitexact_with_recording(setup):
     cfg, params = setup
-    off, _ = _streams(lambda: FixedSlotEngine(params, cfg, slots=2,
+    off, _ = _streams(lambda: FixedSlotEngine(params, cfg, max_batch=2,
                                               max_len=64))
     rec = Recorder()
-    on, _ = _streams(lambda: FixedSlotEngine(params, cfg, slots=2,
+    on, _ = _streams(lambda: FixedSlotEngine(params, cfg, max_batch=2,
                                              max_len=64, recorder=rec))
     assert on == off
     v = rec.registry.value
@@ -569,7 +569,7 @@ def _engine_factory(kind, params, cfg):
         if kind == "paged":
             return ServeEngine(params, cfg, max_len=64, **EVICT_KWARGS, **kw)
         if kind == "fixed":
-            return FixedSlotEngine(params, cfg, slots=2, max_len=64, **kw)
+            return FixedSlotEngine(params, cfg, max_batch=2, max_len=64, **kw)
         return SpeculativeEngine(params, cfg, params, spec_k=3, max_batch=3,
                                  max_len=64, page_size=16, prefill_chunk=4,
                                  **kw)

@@ -481,7 +481,7 @@ def test_same_seed_same_stream_across_batch_and_order(served):
         "paged rev": run(lambda: ServeEngine(params, cfg, max_batch=3,
                                              max_len=32, page_size=8,
                                              prefill_chunk=4), fwd[::-1]),
-        "fixed b=2": run(lambda: FixedSlotEngine(params, cfg, slots=2,
+        "fixed b=2": run(lambda: FixedSlotEngine(params, cfg, max_batch=2,
                                                  max_len=32), fwd),
     }
     want = runs["paged b=6"]

@@ -14,7 +14,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import model as MD
-from repro.serving import FixedSlotEngine, ServeEngine, make_engine
+from repro.serving import FixedSlotEngine, ServeEngine
 
 
 def _tiny_cfg(amm=False):
@@ -73,7 +73,7 @@ def test_engine_matches_sequential(setup):
 
 def test_engine_more_requests_than_slots(setup):
     cfg, params = setup
-    eng = ServeEngine(params, cfg, slots=2, max_len=64)  # slots alias
+    eng = ServeEngine(params, cfg, max_batch=2, max_len=64)
     reqs = [eng.submit([i + 1, i + 2], max_new_tokens=4) for i in range(5)]
     done = eng.run_until_drained()
     assert len(done) == 5
@@ -103,7 +103,7 @@ def _drain_both(params, cfg, *, paged_kwargs, submit_kwargs=None):
     """The PROMPTS through the fixed-slot oracle and the paged engine;
     ``submit_kwargs`` holds each prompt's extra ``submit`` options."""
     extra = submit_kwargs or [{}] * len(PROMPTS)
-    fixed = FixedSlotEngine(params, cfg, slots=2, max_len=64)
+    fixed = FixedSlotEngine(params, cfg, max_batch=2, max_len=64)
     rf = [fixed.submit(p, max_new_tokens=8, **kw)
           for p, kw in zip(PROMPTS, extra)]
     fixed.run_until_drained()
@@ -186,7 +186,7 @@ def test_cancel_with_its_decode_in_flight(setup):
     from repro.serving import Recorder
 
     cfg, params = setup
-    fixed = FixedSlotEngine(params, cfg, slots=2, max_len=64)
+    fixed = FixedSlotEngine(params, cfg, max_batch=2, max_len=64)
     rf = [fixed.submit(p, max_new_tokens=8) for p in PROMPTS]
     fixed.run_until_drained()
     rec = Recorder(trace=False)
@@ -299,21 +299,6 @@ def test_submit_validation(setup):
         eng.submit(list(range(20)))
     with pytest.raises(ValueError, match="never"):
         eng.submit([1, 2, 3], max_new_tokens=12)  # needs 4 pages, pool has 2
-
-
-def test_make_engine_family_fallback(setup):
-    cfg, params = setup
-    assert isinstance(make_engine(params, cfg, max_batch=2, max_len=64),
-                      ServeEngine)
-    ssm = get_config("mamba2-370m", reduced=True)
-    assert not MD.supports_paged(ssm)
-    with pytest.raises(ValueError, match="FixedSlotEngine"):
-        ServeEngine(params, ssm)
-    ssm_params = MD.init_params(ssm, jax.random.PRNGKey(0))
-    eng = make_engine(ssm_params, ssm, max_batch=8, max_len=32,
-                      page_size=4, prefill_chunk=4)
-    assert isinstance(eng, FixedSlotEngine)
-    assert eng.slots == 8  # max_batch maps to slots, not dropped
 
 
 # ---------------------------------------------------------------------------

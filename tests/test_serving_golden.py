@@ -47,14 +47,24 @@ def _tiny_artifact(out):
     return params, cfg, res
 
 
+def _fixed_slot_engine(out, params, cfg, **kw):
+    """The fixed-slot oracle serving the artifact at ``out`` (for this
+    family ``load_engine`` builds the paged engine)."""
+    from repro.compiler.artifact import load_artifact
+    from repro.serving import FixedSlotEngine
+    from repro.serving.engine import _splice_artifact
+
+    params, cfg = _splice_artifact(load_artifact(out), params, cfg, None)
+    return FixedSlotEngine(params, cfg, **kw)
+
+
 def _decode_streams(tmp_path):
-    from repro.serving import ServeEngine
+    from repro.serving import load_engine
 
     out = tmp_path / "lm_art"
     params, cfg, _ = _tiny_artifact(out)
-    eng = ServeEngine.from_artifact(out, params, cfg, max_batch=2,
-                                    max_len=64, page_size=16,
-                                    prefill_chunk=4)
+    eng = load_engine(out, params, cfg, max_batch=2, max_len=64,
+                      page_size=16, prefill_chunk=4)
     reqs = [eng.submit(p, max_new_tokens=MAX_NEW) for p in PROMPTS]
     eng.run_until_drained()
     assert all(r.done for r in reqs)
@@ -82,8 +92,7 @@ def test_golden_t0_bitexact_across_all_engines(tmp_path):
     active-looking top-k/top-p, all of which greedy must ignore) has to
     reproduce the golden streams bit-identically through ALL three
     engines: paged, fixed-slot, and speculative."""
-    from repro.serving import (FixedSlotEngine, SamplingParams, ServeEngine,
-                               SpeculativeEngine)
+    from repro.serving import SamplingParams, load_engine
 
     if not GOLDEN_PATH.is_file():
         pytest.skip("golden file not generated yet")
@@ -95,13 +104,12 @@ def test_golden_t0_bitexact_across_all_engines(tmp_path):
     # T=0 must make seed/top_k/top_p inert: give them loud values
     t0 = SamplingParams(temperature=0.0, top_k=3, top_p=0.5, seed=1234)
     engines = {
-        "paged": ServeEngine.from_artifact(out, params, cfg, max_batch=2,
-                                           max_len=64, page_size=16,
-                                           prefill_chunk=4),
-        "fixed": FixedSlotEngine.from_artifact(out, params, cfg, slots=2,
-                                               max_len=64),
-        "speculative": SpeculativeEngine.from_artifacts(
-            res.artifact, res.artifact, params, cfg, spec_k=3, max_batch=2,
+        "paged": load_engine(out, params, cfg, max_batch=2, max_len=64,
+                             page_size=16, prefill_chunk=4),
+        "fixed": _fixed_slot_engine(out, params, cfg, max_batch=2,
+                                    max_len=64),
+        "speculative": load_engine(
+            (res.artifact, res.artifact), params, cfg, spec_k=3, max_batch=2,
             max_len=64, page_size=16, prefill_chunk=4),
     }
     for name, eng in engines.items():
@@ -132,10 +140,12 @@ def test_golden_streams_through_both_sampler_programs(tmp_path, kind):
 
     for with_sampled in (False, True):
         rec = Recorder(trace=False)
-        opts = (dict(max_batch=2, page_size=16, prefill_chunk=4)
-                if kind == "paged" else dict(slots=2))
-        eng = load_engine(out, params, cfg, engine=kind, max_len=64,
-                          recorder=rec, **opts)
+        if kind == "paged":
+            eng = load_engine(out, params, cfg, max_batch=2, max_len=64,
+                              page_size=16, prefill_chunk=4, recorder=rec)
+        else:
+            eng = _fixed_slot_engine(out, params, cfg, max_batch=2,
+                                     max_len=64, recorder=rec)
         if with_sampled:
             eng.submit([3, 1, 4], max_new_tokens=MAX_NEW, sampling=sampled)
         reqs = [eng.submit(p, max_new_tokens=MAX_NEW) for p in PROMPTS]
@@ -159,7 +169,7 @@ def test_golden_streams_with_one_decode_in_flight(tmp_path, opts):
     out unchanged with one row (every decode continues the last) and
     with a pool small enough that requests are swapped out while their
     token is in flight."""
-    from repro.serving import Recorder, ServeEngine
+    from repro.serving import Recorder, load_engine
 
     if not GOLDEN_PATH.is_file():
         pytest.skip("golden file not generated yet")
@@ -173,9 +183,8 @@ def test_golden_streams_with_one_decode_in_flight(tmp_path, opts):
             super().on_evict(req, kind)
             swapped_in_flight.append(kind == "swap" and req.inflight > 0)
 
-    eng = ServeEngine.from_artifact(out, params, cfg, max_len=64,
-                                    prefill_chunk=4, recorder=Rec(),
-                                    **opts)
+    eng = load_engine(out, params, cfg, max_len=64, prefill_chunk=4,
+                      recorder=Rec(), **opts)
     reqs = [eng.submit(p, max_new_tokens=MAX_NEW) for p in PROMPTS]
     eng.run_until_drained()
     streams = {",".join(map(str, r.prompt)): r.generated for r in reqs}

@@ -488,7 +488,7 @@ def test_run_until_drained_exhaustion_raises(setup):
 
     ssm = get_config("mamba2-370m", reduced=True)
     fixed = FixedSlotEngine(MD.init_params(ssm, jax.random.PRNGKey(0)), ssm,
-                            slots=1, max_len=32)
+                            max_batch=1, max_len=32)
     fixed.submit([1, 2, 3], max_new_tokens=4)
     assert fixed.has_work
     with pytest.raises(RuntimeError, match="steps exhausted"):

@@ -66,7 +66,7 @@ def test_lm_train_then_serve_with_amm():
     params = tr.state.params
 
     # serve exact
-    eng = ServeEngine(params, cfg, slots=2, max_len=64)
+    eng = ServeEngine(params, cfg, max_batch=2, max_len=64)
     reqs = [eng.submit([1, 2, 3, 4], max_new_tokens=5) for _ in range(2)]
     done = eng.run_until_drained()
     assert len(done) == 2 and all(len(r.generated) == 5 for r in done)
